@@ -60,7 +60,7 @@ fleet:
 # rejects / residency swaps)
 multi-model:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_multimodel.py -q -m "not slow"
-	JAX_PLATFORMS=cpu python bench.py --serving --multi-model
+	JAX_PLATFORMS=cpu python bench.py --cpu --serving --multi-model
 
 # live continuous-learning suite (docs/SERVING.md "Continuous learning"):
 # Checkpoints reader API + writer-protocol contract, watcher torn-skip,
@@ -70,7 +70,7 @@ multi-model:
 # hot-swap tail-latency bench at the committed offered rate
 live:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_live.py -q -m "not slow"
-	JAX_PLATFORMS=cpu python bench.py --serving --swap
+	JAX_PLATFORMS=cpu python bench.py --cpu --serving --swap
 
 # asynchronous trainer fleet (docs/TUNING.md §19–20, RESILIENCE.md
 # "Trainer fleet crash semantics"): ownership/wire/quorum/staleness
@@ -86,8 +86,8 @@ live:
 train-fleet:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_training_fleet.py tests/test_fleet_wire.py -q -m "not slow"
 	JAX_PLATFORMS=cpu python -m pytest tests/test_training_fleet.py -q -m slow
-	JAX_PLATFORMS=cpu python bench.py --training-fleet
-	JAX_PLATFORMS=cpu python bench.py --fleet-wire-ab
+	JAX_PLATFORMS=cpu python bench.py --cpu --training-fleet
+	JAX_PLATFORMS=cpu python bench.py --cpu --fleet-wire-ab
 
 # trainer-fleet observability plane (docs/OBSERVABILITY.md "Training
 # fleet"): srt_training_* dynamics-histogram golden grammar +
@@ -128,7 +128,7 @@ bench:
 # "untrusted", never red. The JSON verdict is the CI artifact.
 bench-gate:
 	rm -f .bench-gate-fresh.jsonl
-	SRT_BENCH_SESSION=.bench-gate-fresh.jsonl JAX_PLATFORMS=cpu python bench.py --configs cnn_tagger
+	SRT_BENCH_SESSION=.bench-gate-fresh.jsonl JAX_PLATFORMS=cpu python bench.py --cpu --configs cnn_tagger
 	JAX_PLATFORMS=cpu python -m spacy_ray_tpu telemetry ledger regress \
 		--record .bench-gate-fresh.jsonl --session BENCH_SESSION.jsonl \
 		--json-out bench-gate-verdict.json
@@ -143,7 +143,7 @@ profile:
 # (naive vs fused) + the MFU-vs-shape profile sweep. Compare two --trace
 # runs with: python bin/profile_trf.py --compare before.json after.json
 step-perf:
-	JAX_PLATFORMS=cpu python bench.py --update-only
+	JAX_PLATFORMS=cpu python bench.py --cpu --update-only
 	JAX_PLATFORMS=cpu python bin/profile_trf.py --sweep
 
 # per-replica serving speed A/Bs (PERF.md rounds 9 + 13): window vs
@@ -158,9 +158,9 @@ step-perf:
 # tests/test_serving.py; interpret-mode int8 kernel tests run in tier-1
 # (tests/test_int8.py, CPU-only, fast) like the other pallas suites.
 serve-perf:
-	JAX_PLATFORMS=cpu python bench.py --serving-ab
-	JAX_PLATFORMS=cpu python bench.py --serving
-	JAX_PLATFORMS=cpu python bench.py --serving --zipfian
+	JAX_PLATFORMS=cpu python bench.py --cpu --serving-ab
+	JAX_PLATFORMS=cpu python bench.py --cpu --serving
+	JAX_PLATFORMS=cpu python bench.py --cpu --serving --zipfian
 
 # serving data plane (PR 20, docs/SERVING.md "Data plane"): the fast-tier
 # data-plane tests (conditional 304s + ETag/generation interaction,
@@ -174,9 +174,9 @@ serve-perf3:
 		-k "conditional or suppressed or passthrough or length_ or stale_pooled or aux_conns"
 	JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py -q -m 'not slow' \
 		-k "etag or conditional or pad or batch_span"
-	JAX_PLATFORMS=cpu python bench.py --serving --length-mix
-	JAX_PLATFORMS=cpu python bench.py --serving --zipfian
-	JAX_PLATFORMS=cpu python bench.py --serving --router-ceiling
+	JAX_PLATFORMS=cpu python bench.py --cpu --serving --length-mix
+	JAX_PLATFORMS=cpu python bench.py --cpu --serving --zipfian
+	JAX_PLATFORMS=cpu python bench.py --cpu --serving --router-ceiling
 
 # cross-replica update sharding (PERF.md "Update sharding (round 11)"):
 # the full==replicated equality suite + v2 owner-shard checkpoint format +

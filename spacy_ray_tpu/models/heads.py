@@ -29,7 +29,7 @@ def Tok2VecListener(width: int, upstream: str = "*") -> Model:
     any head whose model tree contains a listener (pipeline/language.py wires
     this; gradient flows back into the shared trunk because the whole
     pipeline loss is one jitted function — the functional equivalent of
-    spaCy's listener backprop relay).
+    spaCy's listener backprop hand-off).
     """
 
     def init_fn(rng):

@@ -448,26 +448,13 @@ def _pipelined_layers(
     with a leading [depth] dim (sharded over 'pipe' by the pipeline), and
     splits the batch into microbatches along dim 0.
 
-    With partial-manual shard_map (jax >= 0.7) the stage body keeps its
-    automatic axes, so TP constraints compose with PP — and ring attention
-    nests as a second partial-manual region (manual over `context` only,
-    parallel/ring_attention.py), so PP x CP works too. On older jax (fully
-    manual fallback) the context axis cannot join a pipe mesh.
+    The pipeline region is partial-manual (manual over `pipe` only), so the
+    stage body keeps its automatic axes and TP constraints compose with PP
+    — and ring attention nests as a second partial-manual region (manual
+    over `context` only, parallel/ring_attention.py), so PP x CP works too.
     """
     from ..parallel import pipeline as ppl
-    from ..parallel.smap import PARTIAL_MANUAL
 
-    if pctx.context_parallel_active() and not PARTIAL_MANUAL:
-        raise ValueError(
-            "pipe x context needs partial-manual shard_map (newer jax) so "
-            "the ring-attention region can nest inside the pipeline region "
-            "— use pipe x data (x model) on this jax"
-        )
-    if pctx.tp_active() and not PARTIAL_MANUAL:
-        raise ValueError(
-            "pipe x model needs partial-manual shard_map (newer jax); "
-            "this jax only supports pipe x data"
-        )
     mesh = pctx.current_mesh()
     S = int(mesh.shape["pipe"])
     if depth % S != 0:
@@ -497,20 +484,16 @@ def _pipelined_layers(
     rng = sub.rng if sub.rng is not None else jax.random.PRNGKey(0)
     layers_per_stage = depth // S
 
-    # with partial-manual shard_map the body keeps automatic data/model
-    # axes, so TP constraints inside the layers still apply — keep the
-    # mesh active; the fully-manual fallback must disable constraints
-    keep_mesh = PARTIAL_MANUAL
-
     def stage_fn(local_params, x, m, key):
         # this stage's layers, sequentially. Fold the stage index into the
         # key: without it every stage would reuse the same per-tick
         # dropout masks on different microbatches
         key = jax.random.fold_in(key, jax.lax.axis_index("pipe"))
-        with pctx.use_mesh(mesh if keep_mesh else None):
-            return _scan_layer_stack(
-                layer_fn, local_params, x, m, key, layers_per_stage
-            )
+        # the body keeps automatic data/model axes, so TP constraints
+        # inside the layers still apply: the mesh stays active
+        return _scan_layer_stack(
+            layer_fn, local_params, x, m, key, layers_per_stage
+        )
 
     out, aux_total = ppl.spmd_pipeline(stage_fn, stacked, mb, mb_mask, rng)
     return out.reshape(B, *X.shape[1:]), aux_total

@@ -1,15 +1,18 @@
-"""Native extension loader: builds murmur.cpp with g++ on first import.
+"""Native extension loader: builds murmur.cpp with g++ on first use.
 
 Binding is ctypes (no pybind11 in the image); a pure-Python fallback keeps
-every feature working when no compiler is available. The .so is cached next
-to the source and rebuilt when the source is newer.
+every feature working when no compiler is available — an order of
+magnitude slower, so a failed build is reported, not swallowed. The .so is
+cached next to the source and rebuilt when the source is newer.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
+import tempfile
 import threading
 from pathlib import Path
 from typing import List, Optional, Sequence
@@ -23,18 +26,37 @@ _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 
+logger = logging.getLogger("spacy_ray_tpu.native")
+
 
 def _build() -> bool:
+    """Compile to a temporary name in the same directory, then rename onto
+    the final path: several processes import at once (collate workers,
+    fleet children, replicas), and none may load a half-written file."""
+    fd, tmp = tempfile.mkstemp(prefix=".libsrt_native.", suffix=".so", dir=_HERE)
+    os.close(fd)
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-o", str(_SO), str(_SRC)],
+            ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, str(_SRC)],
             check=True,
             capture_output=True,
             timeout=120,
         )
+        os.replace(tmp, _SO)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        stderr = getattr(e, "stderr", b"") or b""
+        logger.warning(
+            "native hash library did not build (%s: %s)%s — falling back to "
+            "the pure-Python murmur hash, about 10x slower on the collate "
+            "path",
+            type(e).__name__, e,
+            ": " + stderr.decode("utf8", "replace").strip() if stderr else "",
+        )
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -68,7 +90,8 @@ def load() -> Optional[ctypes.CDLL]:
                 ctypes.POINTER(ctypes.c_uint64),
             ]
             _LIB = lib
-        except Exception:
+        except OSError as e:
+            logger.warning("native hash library did not load: %s", e)
             _LIB = None
         return _LIB
 

@@ -26,24 +26,20 @@ inside its transformer dependency); the implementation is TPU-first.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import probe as _probe
 
 BQ = 128  # query block (MXU-aligned)
 NEG = -1e30
-# VMEM budget for one (b, h) slice of K + V + score block before fallback
-VMEM_ATTN_BUDGET = 10 * 1024 * 1024
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS_IMPORTED = True
-except Exception:  # pragma: no cover
-    _PALLAS_IMPORTED = False
+# The TPU compiler's scoped-VMEM limit for one kernel ("limit 16.00M" in
+# its refusal, v5e, libtpu 0.0.34) — what attention_vmem_ok budgets against
+VMEM_ATTN_BUDGET = 16 * 1024 * 1024
 
 
 def reference_attention(
@@ -61,22 +57,25 @@ def reference_attention(
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref, *, scale):
-    # q [1,1,BQ,DP]  k/v [1,1,T,DP]  bias [1,T]  -> o [1,1,BQ,DP], lse [1,1,BQ]
+    # q [1,1,BQ,DP]  k/v [1,1,T,DP]  bias [1,1,T]  -> o [1,1,BQ,DP],
+    # lse [1,1,1,BQ]. Per-row statistics are [BQ, 1] columns (keepdims) in
+    # the kernel — the TPU lowering has no layout for a rank-1 vector — and
+    # cross HBM as lane-dense [1, BQ] rows.
     q = q_ref[0, 0]
     k = k_ref[0, 0]
     v = v_ref[0, 0]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # [BQ, T]
-    s = s * scale + bias_ref[0][None, :]
-    m = jnp.max(s, axis=-1)  # [BQ]
-    p = jnp.exp(s - m[:, None])
-    l = jnp.sum(p, axis=-1)  # [BQ]
+    s = s * scale + bias_ref[0]
+    m = jnp.max(s, axis=-1, keepdims=True)  # [BQ, 1]
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1, keepdims=True)  # [BQ, 1]
     o = jnp.dot(
         p.astype(v.dtype), v, preferred_element_type=jnp.float32
-    ) / l[:, None]
+    ) / l
     o_ref[0, 0] = o.astype(o_ref.dtype)
-    lse_ref[0, 0] = m + jnp.log(l)
+    lse_ref[0, 0] = (m + jnp.log(l)).T
 
 
 def _bwd_kernel(
@@ -94,16 +93,16 @@ def _bwd_kernel(
     v = v_ref[0, 0]
     do = do_ref[0, 0].astype(jnp.float32)
     o = o_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]  # [BQ]
-    dlse = dlse_ref[0, 0]  # [BQ] cotangent of the logsumexp output
+    lse = lse_ref[0, 0].T  # [BQ, 1]
+    dlse = dlse_ref[0, 0].T  # [BQ, 1] cotangent of the logsumexp output
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    s = s * scale + bias_ref[0][None, :]
-    p = jnp.exp(s - lse[:, None])  # [BQ, T] softmax probs (recomputed)
+    s = s * scale + bias_ref[0]
+    p = jnp.exp(s - lse)  # [BQ, T] softmax probs (recomputed)
 
-    delta = jnp.sum(do * o, axis=-1)  # [BQ]
+    delta = jnp.sum(do * o, axis=-1, keepdims=True)  # [BQ, 1]
     dp = jax.lax.dot_general(
         do.astype(v.dtype), v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -111,7 +110,7 @@ def _bwd_kernel(
     # d(lse)/d(s_j) = p_j, so the lse cotangent folds straight into ds —
     # this is what lets the ring-attention block merge differentiate
     # through each block's logsumexp
-    ds = p * (dp - delta[:, None] + dlse[:, None]) * scale  # [BQ, T] fp32
+    ds = p * (dp - delta + dlse) * scale  # [BQ, T] fp32
     ds16 = ds.astype(q.dtype)
 
     dq_ref[0, 0] = jnp.dot(
@@ -132,60 +131,59 @@ def _bwd_kernel(
 _INTERPRET = False  # tests flip this to run the kernels on CPU
 
 
+def _block_specs(T: int, DP: int):
+    """(q, k/v, bias, lse) BlockSpecs for grid (B, H, nq). The TPU lowering
+    wants each block's last two dims to equal the array's or be multiples
+    of (8, 128), so the bias rides as [B, 1, T] and the per-query logsumexp
+    as [B, H, 1, T] — a unit second-to-last axis under each vector."""
+    vmem = pltpu.VMEM
+    return (
+        pl.BlockSpec((1, 1, BQ, DP), lambda b, h, i: (b, h, i, 0),
+                     memory_space=vmem),
+        pl.BlockSpec((1, 1, T, DP), lambda b, h, i: (b, h, 0, 0),
+                     memory_space=vmem),
+        pl.BlockSpec((1, 1, T), lambda b, h, i: (b, 0, 0), memory_space=vmem),
+        pl.BlockSpec((1, 1, 1, BQ), lambda b, h, i: (b, h, 0, i),
+                     memory_space=vmem),
+    )
+
+
 def _fwd_raw(q, k, v, bias, *, scale, interpret=None):
     # q/k/v [B, H, T, DP], bias [B, T]; T % BQ == 0, DP % 128 == 0
+    # -> o [B, H, T, DP], lse [B, H, T]
     interpret = _INTERPRET if interpret is None else interpret
     B, H, T, DP = q.shape
-    nq = T // BQ
-    kernel = functools.partial(_fwd_kernel, scale=scale)
-    qspec = pl.BlockSpec((1, 1, BQ, DP), lambda b, h, i: (b, h, i, 0),
-                         memory_space=pltpu.VMEM)
-    kvspec = pl.BlockSpec((1, 1, T, DP), lambda b, h, i: (b, h, 0, 0),
-                          memory_space=pltpu.VMEM)
-    bspec = pl.BlockSpec((1, T), lambda b, h, i: (b, 0),
-                         memory_space=pltpu.VMEM)
-    return pl.pallas_call(
-        kernel,
+    qspec, kvspec, bspec, lspec = _block_specs(T, DP)
+    o, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale),
         out_shape=(
             jax.ShapeDtypeStruct((B, H, T, DP), q.dtype),
-            jax.ShapeDtypeStruct((B, H, T), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, T), jnp.float32),
         ),
-        grid=(B, H, nq),
+        grid=(B, H, T // BQ),
         in_specs=[qspec, kvspec, kvspec, bspec],
-        out_specs=(
-            qspec,
-            pl.BlockSpec((1, 1, BQ), lambda b, h, i: (b, h, i),
-                         memory_space=pltpu.VMEM),
-        ),
+        out_specs=(qspec, lspec),
         interpret=interpret,
-    )(q, k, v, bias)
+    )(q, k, v, bias[:, None, :])
+    return o, lse[:, :, 0, :]
 
 
 def _bwd_raw(q, k, v, bias, do, o, lse, dlse, *, scale, interpret=None):
     interpret = _INTERPRET if interpret is None else interpret
     B, H, T, DP = q.shape
-    nq = T // BQ
-    kernel = functools.partial(_bwd_kernel, scale=scale)
-    qspec = pl.BlockSpec((1, 1, BQ, DP), lambda b, h, i: (b, h, i, 0),
-                         memory_space=pltpu.VMEM)
-    kvspec = pl.BlockSpec((1, 1, T, DP), lambda b, h, i: (b, h, 0, 0),
-                          memory_space=pltpu.VMEM)
-    bspec = pl.BlockSpec((1, T), lambda b, h, i: (b, 0),
-                         memory_space=pltpu.VMEM)
-    lspec = pl.BlockSpec((1, 1, BQ), lambda b, h, i: (b, h, i),
-                         memory_space=pltpu.VMEM)
+    qspec, kvspec, bspec, lspec = _block_specs(T, DP)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_bwd_kernel, scale=scale),
         out_shape=(
             jax.ShapeDtypeStruct((B, H, T, DP), q.dtype),   # dq
             jax.ShapeDtypeStruct((B, H, T, DP), jnp.float32),  # dk (accum)
             jax.ShapeDtypeStruct((B, H, T, DP), jnp.float32),  # dv (accum)
         ),
-        grid=(B, H, nq),
+        grid=(B, H, T // BQ),
         in_specs=[qspec, kvspec, kvspec, bspec, qspec, qspec, lspec, lspec],
         out_specs=(qspec, kvspec, kvspec),
         interpret=interpret,
-    )(q, k, v, bias, do, o, lse, dlse)
+    )(q, k, v, bias[:, None, :], do, o, lse[:, :, None, :], dlse[:, :, None, :])
 
 
 def _make_flash(scale: float):
@@ -267,112 +265,109 @@ def flash_attention(
 
 
 def attention_vmem_ok(T: int, DP: int, dtype_bytes: int = 2) -> bool:
-    """Whether one (b, h) slice (K + V + fp32 score block) fits the budget."""
+    """Whether one (b, h) grid step fits the compiler's scoped VMEM. Sized
+    for the BACKWARD kernel, the larger of the two, because one gate serves
+    training and inference: K/V and the f32 dK/dV accumulators span the
+    whole sequence and are double-buffered as pipelined windows, next to
+    two live f32 [BQ, T] score blocks and the q/do/o/dq blocks. Compiled
+    for v5e the backward is accepted at T=4096 and refused at T=4608
+    (16.84M against the 16.00M limit); this arithmetic stops at T=3968."""
     Tp = ((T + BQ - 1) // BQ) * BQ
-    kv = 2 * Tp * DP * dtype_bytes
-    scores = BQ * Tp * 4
-    return kv + scores + 2 * BQ * DP * 4 <= VMEM_ATTN_BUDGET
+    kv = 2 * 2 * Tp * DP * dtype_bytes
+    dkv = 2 * 2 * Tp * DP * 4
+    scores = 2 * BQ * Tp * 4
+    qblocks = 2 * 4 * BQ * DP * dtype_bytes
+    return kv + dkv + scores + qblocks <= VMEM_ATTN_BUDGET
 
 
 _PROBED: Optional[bool] = None
+_STATUS = "not probed (no attention ran in this process)"
+
+
+def _probe_check() -> Optional[str]:
+    """Forward AND gradients against the dense reference, on a shape with
+    a ragged key mask and a T that needs padding to the query block."""
+    r = jax.random.split(jax.random.PRNGKey(0), 4)
+    B, T, H, Dh = 2, 192, 2, 64
+    q = jax.random.normal(r[0], (B, T, H, Dh), jnp.bfloat16)
+    k = jax.random.normal(r[1], (B, T, H, Dh), jnp.bfloat16)
+    v = jax.random.normal(r[2], (B, T, H, Dh), jnp.bfloat16)
+    mask = jnp.arange(T)[None, :] < jnp.array([T, T - 57])[:, None]
+    m = mask[:, :, None, None]
+
+    got = jax.jit(flash_attention)(q, k, v, mask)
+    want = reference_attention(q, k, v, mask)
+    bad = _probe.mismatch(
+        "forward", jnp.where(m, got, 0), jnp.where(m, want, 0), atol=2e-2
+    )
+    if bad:
+        return bad
+
+    def loss(fn, q, k, v):
+        out = fn(q, k, v, mask).astype(jnp.float32)
+        return jnp.sum(jnp.where(m, out, 0.0) ** 2)
+
+    g_got = jax.grad(functools.partial(loss, flash_attention), (0, 1, 2))(q, k, v)
+    g_want = jax.grad(functools.partial(loss, reference_attention), (0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), g_got, g_want):
+        bad = _probe.mismatch(name, a, b, atol=5e-2, rtol=5e-2)
+        if bad:
+            return bad
+    return None
 
 
 def flash_attention_enabled() -> bool:
-    """One-time probe: compile + validate forward AND gradients vs the dense
-    reference on the current backend; cache the verdict. SRT_PALLAS_ATTN=1
-    forces on (any backend), =0 forces off; default auto-enables on TPU only.
-    """
-    global _PROBED
-    if _PROBED is not None:
-        return _PROBED
-    env = os.environ.get("SRT_PALLAS_ATTN")
-    if env == "0" or not _PALLAS_IMPORTED:
-        _PROBED = False
-        return False
-    if env != "1" and jax.default_backend() != "tpu":
-        _PROBED = False
-        return False
-    try:
-        r = jax.random.split(jax.random.PRNGKey(0), 4)
-        B, T, H, Dh = 2, 192, 2, 64
-        q = jax.random.normal(r[0], (B, T, H, Dh), jnp.bfloat16)
-        k = jax.random.normal(r[1], (B, T, H, Dh), jnp.bfloat16)
-        v = jax.random.normal(r[2], (B, T, H, Dh), jnp.bfloat16)
-        mask = jnp.arange(T)[None, :] < jnp.array([T, T - 57])[:, None]
-
-        got = jax.jit(flash_attention)(q, k, v, mask)
-        want = reference_attention(q, k, v, mask)
-        m = mask[:, :, None, None]
-        fwd_ok = bool(
-            jnp.allclose(
-                jnp.where(m, got.astype(jnp.float32), 0),
-                jnp.where(m, want.astype(jnp.float32), 0),
-                atol=2e-2,
-            )
+    """One-time probe (ops/probe.py): compile + validate forward AND
+    gradients vs the dense reference on the current backend; cache the
+    verdict. SRT_PALLAS_ATTN=1 forces the probe on any backend, =0 forces
+    off; default arms on TPU only, where a failed probe raises."""
+    global _PROBED, _STATUS
+    if _PROBED is None:
+        _PROBED, _STATUS = _probe.probe(
+            "flash attention", "SRT_PALLAS_ATTN", _probe_check, _INTERPRET
         )
-
-        def loss(fn, q, k, v):
-            out = fn(q, k, v, mask).astype(jnp.float32)
-            return jnp.sum(jnp.where(m, out, 0.0) ** 2)
-
-        g_got = jax.grad(functools.partial(loss, flash_attention), (0, 1, 2))(q, k, v)
-        g_want = jax.grad(functools.partial(loss, reference_attention), (0, 1, 2))(q, k, v)
-        grad_ok = all(
-            bool(jnp.allclose(a.astype(jnp.float32), b.astype(jnp.float32),
-                              atol=5e-2, rtol=5e-2))
-            for a, b in zip(g_got, g_want)
-        )
-        _PROBED = fwd_ok and grad_ok
-    except Exception:
-        _PROBED = False
     return _PROBED
 
 
+def flash_attention_status() -> str:
+    """What the attention path resolved to in this process, in words."""
+    return _STATUS
+
+
 def _sharded_flash_attention(q, k, v, mask, mesh):
-    """Run the pallas kernel per device shard via partial-manual shard_map.
+    """Run the pallas kernel per device shard inside a shard_map.
 
     A pallas_call has no GSPMD partitioning rule, so under an automatically-
     partitioned jit it would force replication of the global q/k/v. But
-    attention is INDEPENDENT per (batch row, head): manual over the data
-    and model axes, each device runs the kernel on its own [B/d, T, H/m, Dh]
-    shard with zero communication — exact. Returns None when the layout
-    doesn't divide (caller falls back to XLA attention)."""
+    attention is INDEPENDENT per (batch row, head): each device runs the
+    kernel on its own [B/d, T, H/m, Dh] shard with zero communication —
+    exact. The region is manual over EVERY mesh axis, size-1 ones included:
+    the TPU lowering refuses a kernel while any axis of the mesh is still
+    automatic ("Mosaic kernels cannot be automatically partitioned").
+    Returns None when the layout doesn't divide (caller falls back to XLA
+    attention)."""
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.smap import CHECK_KW, PARTIAL_MANUAL, shard_map
+    from ..parallel.smap import manual_region, shard_map
 
-    if not PARTIAL_MANUAL:
-        return None
     B, T, H, _ = q.shape
-    axes = [a for a in ("data", "model") if int(mesh.shape.get(a, 1)) > 1]
-    if not axes:
-        return None
     d = int(mesh.shape.get("data", 1))
     m = int(mesh.shape.get("model", 1))
-    if B % d or H % m:
+    if d * m == 1 or B % d or H % m:
         return None
     data_ax = "data" if d > 1 else None
     model_ax = "model" if m > 1 else None
     qkv_spec = P(data_ax, None, model_ax, None)
     mask_spec = P(data_ax, None)
-    sm_mesh = mesh
-    try:  # inside another partial-manual region, use the ambient mesh
-        from jax.sharding import get_abstract_mesh
-
-        am = get_abstract_mesh()
-        if am is not None and all(a in (am.shape or {}) for a in axes):
-            sm_mesh = am
-    except Exception:  # pragma: no cover - API drift
-        pass
-
-    fn = functools.partial(
-        shard_map,
+    sm_mesh, axis_names = manual_region(mesh, mesh.axis_names)
+    fn = shard_map(
+        flash_attention,
         mesh=sm_mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
         out_specs=qkv_spec,
-        axis_names=frozenset(axes),
-        **{CHECK_KW: False},
-    )(flash_attention)
+        axis_names=axis_names,
+        check_vma=False,
+    )
     return fn(q, k, v, mask)
 
 
@@ -387,11 +382,12 @@ def attention(
     to XLA attention, which partitions cleanly."""
     from ..parallel import context as pctx
 
-    mesh = pctx.current_mesh()
-    if flash_attention_enabled() and attention_vmem_ok(q.shape[1], _dp(q.shape[-1])):
-        if mesh is None or mesh.size == 1:
+    if flash_attention_enabled() and attention_vmem_ok(
+        q.shape[1], _dp(q.shape[-1]), q.dtype.itemsize
+    ):
+        if pctx.single_device():
             return flash_attention(q, k, v, mask)
-        out = _sharded_flash_attention(q, k, v, mask, mesh)
+        out = _sharded_flash_attention(q, k, v, mask, pctx.current_mesh())
         if out is not None:
             return out
     return jax.nn.dot_product_attention(q, k, v, mask=mask[:, None, None, :])

@@ -31,20 +31,15 @@ reference chain to 1 ulp — asserted by tests/test_fused_update.py.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS_IMPORTED = True
-except Exception:  # pragma: no cover
-    _PALLAS_IMPORTED = False
+from . import probe as _probe
 
 # kernel block: BR rows x 128 lanes of f32 per grid step (1 MB/operand —
 # well under VMEM with 5 inputs + 3 outputs resident)
@@ -199,31 +194,30 @@ def _kernel_leaf(p, g, m, v, scal, hyper: FusedHyper, interpret=None):
 # ------------------------------------------------------------------- probe
 
 _PROBED: Optional[bool] = None
+_STATUS = "not probed (no fused update ran in this process)"
 
 
 def fused_kernel_enabled() -> bool:
-    """One-time probe: compile the kernel and validate it against the XLA
-    leaf math on the current backend; cache the verdict. SRT_PALLAS_FUSED=1
-    forces on (any backend), =0 forces off; default auto-enables on TPU
-    only — the same discipline as the flash-attention probe."""
-    global _PROBED
-    if _PROBED is not None:
-        return _PROBED
-    env = os.environ.get("SRT_PALLAS_FUSED")
-    if env == "0" or not _PALLAS_IMPORTED:
-        _PROBED = False
-        return False
-    if env != "1" and jax.default_backend() != "tpu":
-        _PROBED = False
-        return False
-    try:
-        _PROBED = _probe_kernel()
-    except Exception:
-        _PROBED = False
+    """One-time probe (ops/probe.py): compile the kernel and validate it
+    against the XLA leaf math on the current backend; cache the verdict.
+    SRT_PALLAS_FUSED=1 forces the probe on any backend, =0 forces off;
+    default arms on TPU only, where a failed probe raises — the same
+    discipline as the flash-attention probe."""
+    global _PROBED, _STATUS
+    if _PROBED is None:
+        _PROBED, _STATUS = _probe.probe(
+            "fused update", "SRT_PALLAS_FUSED", _probe_kernel, _INTERPRET
+        )
     return _PROBED
 
 
-def _probe_kernel(interpret=None) -> bool:
+def fused_kernel_status() -> str:
+    """What the fused-update kernel resolved to in this process, in words."""
+    return _STATUS
+
+
+def _probe_kernel(interpret=None) -> Optional[str]:
+    """None when the kernel matches the XLA leaf math, else the mismatch."""
     hyper = FusedHyper(
         kind="adam", b1=0.9, b2=0.999, eps=1e-8, grad_clip=1.0,
         l2_grad=0.0, l2_decay=0.01,
@@ -239,10 +233,11 @@ def _probe_kernel(interpret=None) -> bool:
         lambda *a: _kernel_leaf(*a, hyper=hyper, interpret=interpret)
     )(p, g, m, v, scal)
     want = _leaf_math(p, g, m, v, *scal, hyper)
-    return all(
-        bool(jnp.allclose(a, b, atol=1e-6, rtol=1e-6))
-        for a, b in zip(got, want)
-    )
+    for name, a, b in zip(("params", "mu", "nu"), got, want):
+        bad = _probe.mismatch(name, a, b, atol=1e-6, rtol=1e-6)
+        if bad:
+            return bad
+    return None
 
 
 def fused_status(tx: Any, mesh: Any = None) -> str:
@@ -250,7 +245,7 @@ def fused_status(tx: Any, mesh: Any = None) -> str:
     path ACTUALLY is (a CPU fallback must not masquerade as the kernel).
 
     ``mesh`` is the mesh the update was compiled under: the kernel gate
-    (:func:`_single_mesh`) keeps pallas off multi-device meshes, and the
+    (``context.single_device``) keeps pallas off multi-device meshes, and the
     label must agree with the gate — the record's mesh, not the contextvar
     at record time (unset outside the traced update)."""
     if not getattr(tx, "applies_updates", False):
@@ -258,10 +253,9 @@ def fused_status(tx: Any, mesh: Any = None) -> str:
     multi = mesh is not None and int(mesh.size) > 1
     if _PROBED is True and not multi:
         return "active (pallas)"
-    probe = "multi-device mesh" if multi and _PROBED is True else (
-        f"kernel probe: {jax.default_backend()}"
-    )
-    return f"active (xla, {probe})"
+    if multi:
+        return "active (xla; kernel gated off a multi-device mesh)"
+    return f"active (xla; kernel {_STATUS})"
 
 
 # ------------------------------------------------- fused transformation
@@ -292,8 +286,9 @@ def stable_global_norm(tree: Any) -> "jnp.ndarray":
     mesh = pctx.current_mesh()
     if mesh is None or int(mesh.size) == 1:
         return optax.global_norm(tree)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
+
+    from ..parallel.smap import shard_map
 
     leaves = jax.tree_util.tree_leaves(tree)
     fn = shard_map(
@@ -301,19 +296,9 @@ def stable_global_norm(tree: Any) -> "jnp.ndarray":
         mesh=mesh,
         in_specs=tuple(P() for _ in leaves),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(*leaves)
-
-
-def _single_mesh() -> bool:
-    """Kernel gate: a pallas_call has no GSPMD partitioning rule, so under
-    a multi-device mesh (replicated params / ZeRO-1 sharded moments) the
-    update stays on the XLA path, which GSPMD partitions cleanly."""
-    from ..parallel import context as pctx
-
-    mesh = pctx.current_mesh()
-    return mesh is None or int(mesh.size) == 1
 
 
 class FusedTransformation:
@@ -381,7 +366,12 @@ class FusedTransformation:
             ro = jnp.float32(0.0)
             rect = jnp.float32(0.0)
 
-        use_kernel = fused_kernel_enabled() and _single_mesh()
+        # kernel gate: under a multi-device mesh (replicated params /
+        # ZeRO-1 sharded moments) the update stays on the XLA path, which
+        # GSPMD partitions cleanly
+        from ..parallel import context as pctx
+
+        use_kernel = pctx.single_device() and fused_kernel_enabled()
         scal = None
         if use_kernel:
             scal = jnp.stack(

@@ -43,6 +43,10 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import probe as _probe
 
 __all__ = [
     "quantize_int8",
@@ -58,17 +62,10 @@ __all__ = [
 BM = 128   # activation rows per grid step (MXU-aligned)
 BN = 128   # output-channel block (lane-aligned)
 KP = 128   # contraction dim padded to a lane multiple
-# VMEM budget for one grid step: x block (f32) + w block (int8) + out +
-# scale. K stays fully resident per step (encoder trunk K <= ~4k).
-VMEM_INT8_BUDGET = 10 * 1024 * 1024
-
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS_IMPORTED = True
-except Exception:  # pragma: no cover
-    _PALLAS_IMPORTED = False
+# The TPU compiler's scoped-VMEM limit for one kernel ("limit 16.00M" in
+# its refusal, v5e, libtpu 0.0.34). K stays fully resident per grid step
+# (encoder trunk K <= ~4k).
+VMEM_INT8_BUDGET = 16 * 1024 * 1024
 
 
 # ------------------------------------------------------------ quantization
@@ -138,8 +135,14 @@ def reference_int8_matmul(
     x: jnp.ndarray, q8: jnp.ndarray, scale: jnp.ndarray
 ) -> jnp.ndarray:
     """jnp fallback/reference: ``x [..., K] @ dequant(q8 [K, N]) -> [..., N]``
-    in f32 — what the pallas kernel is validated against."""
-    return x.astype(jnp.float32) @ dequantize_int8(q8, scale)
+    in f32 — what the pallas kernel is validated against. HIGHEST precision:
+    the kernel's f32 dot is a true f32 contraction, while XLA's default on
+    a TPU rounds f32 operands to bfloat16 first — measured on a v5e, the
+    default-precision product sits 3.6e-3 away from the kernel."""
+    return jnp.matmul(
+        x.astype(jnp.float32), dequantize_int8(q8, scale),
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
 
 # ----------------------------------------------------------------- kernel
@@ -209,11 +212,14 @@ def _int8_matmul_raw(
 
 
 def int8_vmem_ok(K: int) -> bool:
-    """Whether one grid step's working set (x block f32 + w block int8 +
-    out block f32 + scale row) fits the VMEM budget for contraction dim
-    ``K`` (kept fully resident per step)."""
+    """Whether one grid step's windows (x block f32 + w block int8 + out
+    block f32 + scale row, each double-buffered by the pipeline) fit the
+    compiler's scoped VMEM for contraction dim ``K`` (kept fully resident
+    per step). Compiled for v5e the kernel is accepted at K=12288 and
+    refused at K=16384 (20.12M against the 16.00M limit); this arithmetic
+    stops at K=12928."""
     Kp = ((K + KP - 1) // KP) * KP
-    need = BM * Kp * 4 + Kp * BN * 1 + BM * BN * 4 + BN * 4
+    need = 2 * (BM * Kp * 4 + Kp * BN * 1 + BM * BN * 4 + BN * 4)
     return need <= VMEM_INT8_BUDGET
 
 
@@ -245,21 +251,27 @@ def int8_matmul(
 _PROBE_CACHE: dict = {}
 
 
-def _numeric_probe(interpret: bool) -> bool:
+def _numeric_probe(interpret: bool) -> Optional[str]:
     """Compile (interpret=False) or interpret (True) + validate the
-    kernel against the dequant reference. The flag is EXPLICIT: the
-    unforced TPU gate must prove the COMPILED kernel — letting the
-    interpret fallback answer for it would pass the probe on hosts
-    where the real kernel cannot lower."""
+    kernel against the dequant reference; None when they agree. The flag
+    is EXPLICIT: the unforced TPU gate must prove the COMPILED kernel —
+    letting the interpret fallback answer for it would pass the probe on
+    hosts where the real kernel cannot lower."""
     r = jax.random.split(jax.random.PRNGKey(0), 2)
     w = jax.random.normal(r[0], (96, 160), jnp.float32) * 0.05
-    x = jax.random.normal(r[1], (33, 96), jnp.float32)
+    # activations as serving hands them over on a TPU: bfloat16 values
+    # (the trunk's compute dtype) widened to f32. The MXU takes the
+    # kernel's f32 operands in one bfloat16 pass, which is exact for these
+    # and for the int8 weights; arbitrary f32 activations would be rounded
+    # (3.2e-3 from the f32 product, measured on a v5e), as XLA's own
+    # default-precision matmuls round them
+    x = jax.random.normal(r[1], (33, 96), jnp.bfloat16).astype(jnp.float32)
     q8, scale = quantize_int8(w)
     got = jax.jit(
         lambda x_, q_, s_: _int8_matmul_raw(x_, q_, s_, interpret=interpret)
     )(x, q8, scale)
     want = reference_int8_matmul(x, q8, scale)
-    return bool(jnp.allclose(got, want, atol=1e-4, rtol=1e-4))
+    return _probe.mismatch("x @ dequant(w)", got, want, atol=1e-4, rtol=1e-4)
 
 
 def int8_probe(backend: Optional[str] = None) -> Tuple[bool, str]:
@@ -272,36 +284,44 @@ def int8_probe(backend: Optional[str] = None) -> Tuple[bool, str]:
     * ``SRT_PALLAS_INT8=0`` — refused everywhere.
     * ``SRT_PALLAS_INT8=1`` — probe runs anywhere; non-TPU backends run
       the kernel interpret-mode (the forced label says so).
-    * unset — TPU only: the compiled kernel is probed and must validate;
-      any other backend refuses (the CPU auto-OFF rule, test-enforced
-      like bf16's).
+    * unset — TPU only: the compiled kernel is probed and must validate
+      (a failure there raises, ops/probe.py); any other backend refuses
+      (the CPU auto-OFF rule, test-enforced like bf16's).
+
+    ``backend`` names the backend the answer is for. A compiled kernel can
+    only be proven by the process that holds that backend: asked about
+    another one, the probe refuses instead of compiling for the wrong chip.
     """
+    here = jax.default_backend()
     if backend is None:
-        backend = jax.default_backend()
+        backend = here
     env = os.environ.get("SRT_PALLAS_INT8")
     key = (env, backend)
     if key in _PROBE_CACHE:
         return _PROBE_CACHE[key]
+    forced = env == "1"
+    interpret = _INTERPRET or (forced and here != "tpu")
     if env == "0":
         ok, why = False, "SRT_PALLAS_INT8=0 — probe refused"
-    elif not _PALLAS_IMPORTED:
-        ok, why = False, f"pallas unavailable on {backend} — probe refused"
-    elif env != "1" and backend != "tpu":
+    elif not forced and backend != "tpu":
         ok, why = False, (
             f"int8 overlay OFF on {backend} unless forced "
             "(SRT_PALLAS_INT8=1 runs the interpret-mode kernel) — "
             "probe refused"
         )
+    elif backend != here and not interpret:
+        ok, why = False, (
+            f"the compiled int8 kernel can only be probed on {backend} "
+            f"itself (this process runs on {here}) — probe refused"
+        )
     else:
-        forced = env == "1"
-        interpret = _INTERPRET or (forced and jax.default_backend() != "tpu")
-        try:
-            numerics_ok = _numeric_probe(interpret)
-        except Exception:
-            numerics_ok = False
-        if not numerics_ok:
+        problem = _probe.checked(
+            "int8 matmul", lambda: _numeric_probe(interpret)
+        )
+        if problem is not None:
             ok, why = False, (
-                f"int8 kernel probe failed on {backend} — probe refused"
+                f"int8 kernel probe failed on {backend} "
+                f"({problem.strip().splitlines()[0]}) — probe refused"
             )
         elif interpret:
             ok, why = True, (

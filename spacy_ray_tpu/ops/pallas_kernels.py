@@ -15,21 +15,27 @@ cotangent into the table rows (a jnp ``.at[ids].add`` — XLA lowers this
 well); the probe validates BOTH forward and gradient numerics before
 enabling.
 
-Safety: enabled only by a one-time startup probe (compile + numeric check on
-the current backend), silently falling back to the jnp path otherwise.
-Force with SRT_PALLAS=1/0.
+Safety: enabled only by a one-time startup probe (ops/probe.py: compile +
+numeric check on the current backend). Off a TPU the jnp path is the
+default; on a TPU a probe that fails raises. Force with SRT_PALLAS=1/0.
 """
 
 from __future__ import annotations
 
-import os
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import probe as _probe
 
 TOKEN_BLOCK = 256
-VMEM_TABLE_BUDGET = 8 * 1024 * 1024  # bytes of VMEM we allow the table
+# bytes of VMEM we allow the resident table. Compiled for v5e (libtpu
+# 0.0.34) the kernel is accepted with 8 MiB tables at widths 64/96/128/256
+# (lane padding included) and well beyond; the cap keeps room for the rest
+VMEM_TABLE_BUDGET = 8 * 1024 * 1024
 
 
 def _reference_lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
@@ -45,15 +51,6 @@ def _table_grad(ids: jnp.ndarray, ct: jnp.ndarray, rows: int) -> jnp.ndarray:
     updates = jnp.broadcast_to(ct[:, None, :], (ct.shape[0], 4, ct.shape[1]))
     zeros = jnp.zeros((rows, ct.shape[1]), ct.dtype)
     return zeros.at[ids].add(updates)
-
-
-try:  # pallas imports can fail on exotic builds; treat as "unavailable"
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS_IMPORTED = True
-except Exception:  # pragma: no cover
-    _PALLAS_IMPORTED = False
 
 
 def _kernel(ids_ref, table_ref, out_ref):
@@ -113,36 +110,41 @@ _pallas_lookup.defvjp(_pallas_lookup_fwd, _pallas_lookup_bwd)
 
 
 _PROBED: Optional[bool] = None
+_STATUS = "not probed (no hash-embed lookup ran in this process)"
+
+
+def _probe_check() -> Optional[str]:
+    """Forward and table gradient against the jnp gather-sum, at the sm
+    pipeline's table shape (2000 x 96) with repeated ids in play."""
+    table = jax.random.normal(jax.random.PRNGKey(0), (2000, 96), jnp.float32)
+    ids = jax.random.randint(
+        jax.random.PRNGKey(1), (2 * TOKEN_BLOCK, 4), 0, 2000
+    ).astype(jnp.int32)
+    got = jax.jit(_pallas_lookup)(table, ids)
+    bad = _probe.mismatch(
+        "forward", got, _reference_lookup(table, ids), atol=1e-5
+    )
+    if bad:
+        return bad
+    g_got = jax.grad(lambda t: jnp.sum(jnp.sin(_pallas_lookup(t, ids))))(table)
+    g_want = jax.grad(lambda t: jnp.sum(jnp.sin(_reference_lookup(t, ids))))(table)
+    return _probe.mismatch("table grad", g_got, g_want, atol=1e-4)
 
 
 def pallas_enabled() -> bool:
     """One-time probe: compile + numerically validate forward AND gradient
     on the default backend; cache the verdict."""
-    global _PROBED
-    if _PROBED is not None:
-        return _PROBED
-    env = os.environ.get("SRT_PALLAS")
-    if env == "0" or not _PALLAS_IMPORTED:
-        _PROBED = False
-        return False
-    if env != "1" and jax.default_backend() != "tpu":
-        _PROBED = False  # default: only auto-enable on real TPU
-        return False
-    try:
-        table = jax.random.normal(jax.random.PRNGKey(0), (64, 96), jnp.float32)
-        ids = jax.random.randint(
-            jax.random.PRNGKey(1), (2 * TOKEN_BLOCK, 4), 0, 64
-        ).astype(jnp.int32)
-        got = jax.jit(_pallas_lookup)(table, ids)
-        want = _reference_lookup(table, ids)
-        fwd_ok = bool(jnp.allclose(got, want, atol=1e-5))
-        g_got = jax.grad(lambda t: jnp.sum(jnp.sin(_pallas_lookup(t, ids))))(table)
-        g_want = jax.grad(lambda t: jnp.sum(jnp.sin(_reference_lookup(t, ids))))(table)
-        grad_ok = bool(jnp.allclose(g_got, g_want, atol=1e-4))
-        _PROBED = fwd_ok and grad_ok
-    except Exception:
-        _PROBED = False
+    global _PROBED, _STATUS
+    if _PROBED is None:
+        _PROBED, _STATUS = _probe.probe(
+            "hash-embed lookup", "SRT_PALLAS", _probe_check
+        )
     return _PROBED
+
+
+def hash_embed_status() -> str:
+    """What the hash-embed lookup resolved to in this process, in words."""
+    return _STATUS
 
 
 # HBM budget for the one-hot counts operand ([tokens, rows] elements) —
@@ -153,16 +155,21 @@ ONEHOT_LOOKUP_MAX_BYTES = 64 * 1024 * 1024
 def hash_embed_lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
     """Gather-sum 4 rows per key: table [rows, D], ids [..., 4] -> [..., D].
 
-    Uses the pallas kernel when the startup probe enabled it and the table
-    fits the VMEM budget. On TPU without the kernel (probe failed/forced
-    off), small tables use a one-hot count-matrix matmul instead of the
-    gather (TPU gathers serialize; summing the 4 one-hots gives a count
-    row, and counts @ table == the multiplicity-weighted row sum). Plain
-    jnp gather otherwise (CPU, big tables).
+    Uses the pallas kernel when the startup probe enabled it, the table
+    fits the VMEM budget and the program runs on one device (a kernel has
+    no partitioning rule: under a multi-device mesh the jnp paths below
+    partition cleanly). On TPU without the kernel, small tables use a
+    one-hot count-matrix matmul instead of the gather (TPU gathers
+    serialize; summing the 4 one-hots gives a count row, and counts @
+    table == the multiplicity-weighted row sum). Plain jnp gather
+    otherwise (CPU, big tables).
     """
+    from ..parallel import context as pctx
+
     lead_shape = ids.shape[:-1]
     if (
-        pallas_enabled()
+        pctx.single_device()
+        and pallas_enabled()
         and table.dtype == jnp.float32
         and table.nbytes <= VMEM_TABLE_BUDGET
     ):

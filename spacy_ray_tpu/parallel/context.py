@@ -45,6 +45,15 @@ def _axis_size(name: str) -> int:
     return int(mesh.shape.get(name, 1))
 
 
+def single_device() -> bool:
+    """No mesh, or a mesh of one device: the only place a bare pallas_call
+    may sit. A kernel has no GSPMD partitioning rule (the TPU lowering
+    refuses it under a multi-device jit), so under a larger mesh a kernel
+    either runs per shard inside a shard_map or yields to its XLA path."""
+    mesh = _MESH.get()
+    return mesh is None or int(mesh.size) == 1
+
+
 def tp_active() -> bool:
     return _axis_size("model") > 1
 

@@ -14,14 +14,13 @@ ticks for M microbatches:
 
 The bubble fraction is (S-1)/(M+S-1) — pick M >= S. Everything is
 differentiable (ppermute/psum transpose), so the same schedule runs the
-backward pass in reverse. Composes with the ``data`` axis and — on jax
-with partial-manual shard_map (``axis_names``) — with the ``model`` axis
-(the stage body stays automatic over data/model, so TP sharding
-constraints inside the layers apply) AND with the ``context`` axis: ring
-attention nests inside the stage body as a second partial-manual region,
-manual over ``context`` only (parallel/ring_attention.py). Older jax
-without ``axis_names`` falls back to a fully manual region with
-constraints disabled (pipe x data only, no context).
+backward pass in reverse. The region is partial-manual (``axis_names``:
+manual over ``pipe`` only), so it composes with the ``data`` axis, with
+the ``model`` axis (the stage body stays automatic over data/model, so TP
+sharding constraints inside the layers apply) AND with the ``context``
+axis: ring attention nests inside the stage body as a second
+partial-manual region, manual over ``context`` only
+(parallel/ring_attention.py).
 """
 
 from __future__ import annotations
@@ -36,11 +35,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import context as pctx
 
-# partial-manual shard_map (manual over `pipe` only, other axes stay
-# automatic) lets sharding constraints inside the stage body keep working,
-# so PP composes with tensor parallelism — and with ring attention's
-# nested `context` region (smap.py holds the shared capability probe)
-from .smap import CHECK_KW as _CHECK_KW, PARTIAL_MANUAL, shard_map
+from .smap import shard_map
 
 AXIS = "pipe"
 
@@ -77,31 +72,16 @@ def spmd_pipeline(
     M = int(microbatches.shape[0])
     param_spec = P(AXIS)  # leading (stacked-depth) dim -> stages
 
-    if PARTIAL_MANUAL:
-        # manual over `pipe` only: activations keep their global (auto)
-        # batch semantics, so data/model constraints inside stage_fn apply
-        x_spec = P()
-        mask_spec = P()
-        aux_spec = P()  # aux is global under automatic data semantics
-        sm_kwargs: dict = {"axis_names": frozenset({AXIS})}
-    else:  # older jax: fully manual fallback
-        data = "data" if "data" in mesh.shape and mesh.shape["data"] > 1 else None
-        x_spec = P(None, data, None, None)  # [M, mb/data, T, D]
-        mask_spec = P(None, data, None)
-        # the region returns aux as a [1] vector (a bare scalar cannot be
-        # concatenated across shards); each data shard contributes its own
-        # value, averaged outside — standard data-parallel aggregation of
-        # the (already approximate, see docstring) pipelined aux
-        aux_spec = P(data)
-        sm_kwargs = {}
-
+    # manual over `pipe` only: activations keep their global (auto) batch
+    # semantics, so data/model constraints inside stage_fn apply, and aux
+    # is global under automatic data semantics
     @partial(
         shard_map,
         mesh=mesh,
-        in_specs=(param_spec, x_spec, mask_spec, P()),
-        out_specs=(x_spec, aux_spec),
-        **{_CHECK_KW: False},
-        **sm_kwargs,
+        in_specs=(param_spec, P(), P(), P()),
+        out_specs=(P(), P()),
+        axis_names=frozenset({AXIS}),
+        check_vma=False,
     )
     def run(local_params, xs, ms, key):
         stage = jax.lax.axis_index(AXIS)
@@ -147,6 +127,4 @@ def spmd_pipeline(
         return outputs, aux_total.reshape(1)
 
     outputs, aux_vec = run(stacked_params, microbatches, masks, rng)
-    # [1] under partial-manual (global aux); [n_data] under the fully
-    # manual fallback (one value per data shard) — mean restores a scalar
-    return outputs, jnp.mean(aux_vec)
+    return outputs, aux_vec[0]

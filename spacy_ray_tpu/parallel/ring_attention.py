@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from . import context as pctx
-from .smap import CHECK_KW as _CHECK_KW, PARTIAL_MANUAL, shard_map
+from .smap import manual_region, shard_map
 
 AXIS = "context"
 
@@ -124,62 +124,35 @@ def ring_attention(
     n_data = int(mesh.shape.get("data", 1))
     n_model = int(mesh.shape.get("model", 1))
     # flash blocks run a pallas_call per device shard; the gate is decided
-    # HERE because under partial-manual the region's manual axis set depends
-    # on it (pallas_call has no GSPMD partitioning rule, so every mesh axis
-    # its operands are sharded over must be manual — see
+    # HERE because the region's manual axis set depends on it (see
     # flash_attention._sharded_flash_attention for the single-chip analogue)
     flash = _use_flash_blocks(T_g // n_shards, Dh)
 
-    sm_mesh = mesh
-    if PARTIAL_MANUAL:
-        # manual over `context` ONLY by default: data/model dims keep their
-        # automatic (GSPMD) semantics, so the dense body's einsums still
-        # partition over them — and the whole region can nest inside another
-        # partial-manual shard_map (the pipeline's `pipe` region). The flash
-        # path instead goes manual over data/model TOO (its kernel covers
-        # the whole per-device computation; nothing is left to partition),
-        # falling back to dense when the layout doesn't divide. When already
-        # inside such a region, shard_map must receive the AMBIENT abstract
-        # mesh (whose enclosing axes are marked Manual), not the concrete
-        # mesh it was built from.
-        manual = {AXIS}
-        if flash and (n_data > 1 or n_model > 1):
-            if B_g % max(n_data, 1) or H_g % max(n_model, 1):
-                flash = False  # indivisible layout: dense partitions cleanly
-            else:
-                manual |= {a for a, n in (("data", n_data), ("model", n_model)) if n > 1}
-        data_ax = "data" if "data" in manual else None
-        model_ax = "model" if "model" in manual else None
-        qkv_spec = P(data_ax, AXIS, model_ax, None)
-        mask_spec = P(data_ax, AXIS)
-        sm_kwargs: dict = {"axis_names": frozenset(manual)}
-        try:
-            from jax.sharding import get_abstract_mesh
-
-            am = get_abstract_mesh()
-            if am is not None and all(a in (am.shape or {}) for a in manual):
-                sm_mesh = am
-        except Exception:  # pragma: no cover - API drift: concrete mesh
-            pass
-    else:  # older jax: fully manual over the whole mesh
-        # the manual region shards B over `data` (and H over `model`) only
-        # when the dims actually divide — an indivisible layout falls back
-        # to replicating that dim on every shard (each device computes the
-        # full extent; wasteful but exact), instead of tripping shard_map's
-        # divisibility check
-        data = "data" if n_data > 1 and B_g % n_data == 0 else None
-        model = "model" if n_model > 1 and H_g % n_model == 0 else None
-        qkv_spec = P(data, AXIS, model, None)
-        mask_spec = P(data, AXIS)
-        sm_kwargs = {}
+    # manual over `context` ONLY by default: data/model dims keep their
+    # automatic (GSPMD) semantics, so the dense body's einsums still
+    # partition over them — and the whole region can nest inside another
+    # partial-manual shard_map (the pipeline's `pipe` region). The flash
+    # path instead goes manual over EVERY axis: its kernel covers the whole
+    # per-device computation, nothing is left to partition, and the TPU
+    # lowering refuses a kernel while any mesh axis is still automatic. It
+    # falls back to dense when the layout doesn't divide.
+    if flash and (B_g % n_data or H_g % n_model):
+        flash = False  # indivisible layout: dense partitions cleanly
+    sm_mesh, manual = manual_region(
+        mesh, mesh.axis_names if flash else (AXIS,)
+    )
+    data_ax = "data" if flash and n_data > 1 else None
+    model_ax = "model" if flash and n_model > 1 else None
+    qkv_spec = P(data_ax, AXIS, model_ax, None)
+    mask_spec = P(data_ax, AXIS)
 
     @partial(
         shard_map,
         mesh=sm_mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
         out_specs=qkv_spec,
-        **{_CHECK_KW: False},
-        **sm_kwargs,
+        axis_names=manual,
+        check_vma=False,
     )
     def inner(q, k, v, kmask):
         B, Tq, H, _ = q.shape

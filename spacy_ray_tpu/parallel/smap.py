@@ -1,29 +1,30 @@
-"""Shared shard_map import + capability probe.
+"""shard_map, and the one rule for where a manual region sits.
 
-One place answers "which shard_map does this jax have, and does it support
-partial-manual regions?" so the pipeline (`pipe` axis) and ring attention
-(`context` axis) can't drift apart on the answer — PP x CP works only when
-BOTH regions can be partial-manual (nested), and both modules gate on the
-same flag.
+Partial-manual shard_map (manual over only the axes in ``axis_names``,
+every other mesh axis left to GSPMD) is what lets sharding constraints
+keep working inside a manual region and lets regions nest over disjoint
+axis sets: the pipeline (`pipe`), ring attention (`context`) and the
+per-shard flash kernel (every axis) all build their region through
+:func:`manual_region`, so they cannot drift apart on how nesting works.
 """
 
 from __future__ import annotations
 
-import inspect
+from typing import Iterable, Tuple
 
-try:
-    from jax import shard_map  # jax >= 0.7 (replication check kwarg: check_vma)
+from jax import shard_map
+from jax.sharding import Mesh, get_abstract_mesh
 
-    CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore[no-redef]
+__all__ = ["shard_map", "manual_region"]
 
-    CHECK_KW = "check_rep"
 
-# partial-manual shard_map: manual over only the axes named in
-# ``axis_names``, every other mesh axis stays automatic (GSPMD) — the
-# mechanism that lets sharding constraints keep working inside a manual
-# region and lets manual regions nest over disjoint axis sets
-PARTIAL_MANUAL = "axis_names" in inspect.signature(shard_map).parameters
-
-__all__ = ["shard_map", "CHECK_KW", "PARTIAL_MANUAL"]
+def manual_region(mesh: Mesh, axes: Iterable[str]) -> Tuple[object, frozenset]:
+    """``(mesh, axis_names)`` to hand ``shard_map`` for a region that must
+    end up manual over ``axes``. At top level that is the concrete mesh and
+    the axes as given. Inside an enclosing manual region, shard_map must
+    receive the AMBIENT abstract mesh (whose enclosing axes are marked
+    Manual), and only the axes that region left automatic."""
+    ambient = get_abstract_mesh()
+    if set(mesh.axis_names) <= set(ambient.axis_names):
+        return ambient, frozenset(axes) - frozenset(ambient.manual_axes)
+    return mesh, frozenset(axes)

@@ -3,7 +3,7 @@
 Capability parity with spaCy's ``tok2vec`` pipe: one trunk feeding every
 listener-equipped head, gradients summed into the trunk because the whole
 pipeline loss is a single differentiable function (the functional version of
-the listener backprop relay; SURVEY.md §7 "Transformer sharing across
+the listener backprop hand-off; SURVEY.md §7 "Transformer sharing across
 components" — the same wiring serves the transformer trunk).
 """
 

@@ -7,7 +7,7 @@ TPU-first differences:
 
 * The whole multi-component forward+loss is ONE pure function
   (``make_loss_fn``) so jit compiles tok2vec trunk + every head + their
-  gradient sum into a single XLA program — the listener gradient relay and
+  gradient sum into a single XLA program — the listener gradient hand-off and
   "summed gradients into shared trunk" fall out of autodiff for free.
 * Collation lowers ragged Example batches into bucketed, statically-shaped
   padded arrays (SURVEY.md §7 "Ragged/variable-length batching").
@@ -530,14 +530,17 @@ class Pipeline:
             and jax.process_count() == 1  # multi-host gather not worth it
         )
         n_data = int(mesh.shape["data"]) if shard_eval else 1
+        # Model code consults the active mesh at trace time (TP/CP
+        # constraints, and the pallas kernels: per-shard under a mesh, bare
+        # on one device), so the forward is traced under the same mesh the
+        # train step installs — params replicated over several devices make
+        # this a multi-device program even where the batch is not sharded.
+        trace_mesh = mesh if mesh is not None and int(mesh.size) > 1 else None
         # cache keyed on decode-affecting component settings, so e.g.
         # changing parser.beam_width or ner.decode takes effect immediately,
         # plus the ``annotate`` restriction (the annotating pass compiles a
         # trunk+annotators-only program; interleaving it with full eval must
-        # not retrace either one). The mesh is NOT part of the key: the same
-        # jitted callable serves sharded and unsharded inputs (jax keeps one
-        # executable per input sharding internally), so eval/inference
-        # interleaving never rebuilds the trace
+        # not retrace either one) and the mesh the program is traced under
         decode_sig = (
             tuple(
                 (name, getattr(self.components[name], "beam_width", None),
@@ -545,6 +548,7 @@ class Pipeline:
                 for name in self.pipe_names
             ),
             tuple(sorted(annotate)) if annotate is not None else None,
+            trace_mesh,
         )
         if self._jit_forward is None:
             self._jit_forward = {}
@@ -559,16 +563,19 @@ class Pipeline:
                 self.make_forward_fn(only=decode_sig[1])
             )
         forward = self._jit_forward[decode_sig]
-        for chunk, lengths, outputs in self._forward_chunks(
-            docs, params, forward, batch_size, shard_eval, n_data, mesh,
-            pad_batch_to=pad_batch_to, pad_len_to=pad_len_to,
-        ):
-            for name in self.head_names():
-                if annotate is not None and name not in annotate:
-                    continue
-                self.components[name].set_annotations(
-                    chunk, outputs.get(name), lengths
-                )
+        from ..parallel import context as pctx
+
+        with pctx.use_mesh(trace_mesh):
+            for chunk, lengths, outputs in self._forward_chunks(
+                docs, params, forward, batch_size, shard_eval, n_data, mesh,
+                pad_batch_to=pad_batch_to, pad_len_to=pad_len_to,
+            ):
+                for name in self.head_names():
+                    if annotate is not None and name not in annotate:
+                        continue
+                    self.components[name].set_annotations(
+                        chunk, outputs.get(name), lengths
+                    )
         return docs
 
     def _forward_chunks(

@@ -768,12 +768,7 @@ class InferenceEngine:
                 f"generation {self.serving_generation}"
             )
         overlay = build_params_overlay(params, self.precision)
-        try:
-            jax.block_until_ready(jax.device_put(overlay.params))
-        except Exception:  # older jax without pytree support here:
-            # arrays will transfer lazily on the first post-flip
-            # dispatch instead — correct, just less instant
-            pass
+        jax.block_until_ready(jax.device_put(overlay.params))
         return overlay
 
     def swap_params(

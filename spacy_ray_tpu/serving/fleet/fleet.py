@@ -211,9 +211,8 @@ class FleetConfig:
     def build_env(self, slot: int) -> Dict[str, str]:
         env: Dict[str, str] = {}
         if self.device == "cpu":
-            # pin the platform in the child's env too: images whose
-            # sitecustomize imports jax at boot lock the platform before
-            # the child's _setup_device runs
+            # pin the platform in the child's env too, so it holds for
+            # whatever the replica itself starts
             env["JAX_PLATFORMS"] = "cpu"
         if self.visible_devices:
             mask = self.visible_devices[slot % len(self.visible_devices)]

@@ -154,10 +154,10 @@ class TrainAndServe:
             env=env,
         )
         threading.Thread(
-            target=self._relay_train_output, daemon=True, name="train-stdout"
+            target=self._forward_train_output, daemon=True, name="train-stdout"
         ).start()
 
-    def _relay_train_output(self) -> None:
+    def _forward_train_output(self) -> None:
         proc = self.train_proc
         assert proc is not None and proc.stdout is not None
         try:

@@ -428,6 +428,9 @@ class TrainResult:
         # resilience.RC_PREEMPTED so supervisors can tell "resume me"
         # from "done"
         self.interrupted: bool = False
+        # what the platform-dependent [training] switches resolved to on
+        # this run's mesh and backend (honest labels, by name)
+        self.resolved: Dict[str, str] = {}
 
     @property
     def wps(self) -> float:
@@ -1686,6 +1689,14 @@ def train(
     # as of the last CONSUMED group (matching the no-prefetch behavior of
     # "completed epochs" when the stream ran dry, else the current epoch)
     result.epoch = epoch if not stop else last_consumed_epoch
+    from ..ops.fused_update import fused_status
+
+    result.resolved = {
+        "update_sharding": update_sharding_status(update_sharding, mesh),
+        # read after the steps ran: the kernel probe fires in the first trace
+        "fused_update": fused_status(tx, mesh),
+        "bf16_shadow": "on" if shadow is not None else "off",
+    }
     nlp.params = jax.device_get(params)
     if output_path is not None and jax.process_index() == 0:
         nlp.to_disk(Path(output_path) / "last-model")

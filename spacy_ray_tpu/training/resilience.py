@@ -240,8 +240,8 @@ class Watchdog:
     """Daemon thread that hard-exits the process when no heartbeat arrives
     within ``timeout_s``.
 
-    A desynced multi-host collective (one rank crashed mid-allgather, a
-    wedged relay tunnel) blocks inside compiled code with no exception to
+    A desynced multi-host collective (one rank crashed mid-allgather)
+    blocks inside compiled code with no exception to
     catch — the process sits forever and the whole pod's allocation burns.
     The watchdog's only job is to turn "wedged forever" into "dump
     diagnostics, exit :data:`RC_WATCHDOG`, let the supervisor resume from
@@ -756,8 +756,7 @@ def terminate_with_grace(
     SIGTERM-only shutdown hangs forever on a child that ignores or can't
     service the signal (wedged in a collective, masked handlers); a bare
     SIGKILL gives a healthy child no chance to finish its checkpoint. This
-    is the one escalation sequence the relay probe and the supervisor
-    share. Returns the child's returncode (None if it survived even
+    is the one escalation sequence every launcher here shares. Returns the child's returncode (None if it survived even
     SIGKILL, which means an unkillable D-state process).
     """
     if proc.poll() is not None:

@@ -798,17 +798,19 @@ class TraceBuffer:
 # Device-side sampling
 # ----------------------------------------------------------------------
 
-# Dense bf16 peak per chip from public datasheets, substring-matched
-# against device_kind (order matters: v5p before v5). The single source —
-# bench.py imports this table for its MFU denominators.
-TPU_PEAK_BF16 = [
-    ("v6", 918e12),  # Trillium / v6e
-    ("v5p", 459e12),
-    ("v5", 197e12),  # v5e reports device_kind "TPU v5 lite"
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 46e12),
-]
+# Dense bf16 peak per chip from public datasheets (Google Cloud TPU
+# documentation), keyed by the EXACT device_kind the installed runtime
+# (libtpu 0.0.34) reports — a substring match would hand any future kind
+# containing "v5" the v5e's number. A kind that is not here has no peak:
+# None, never a default. Only generations whose JAX device is a whole chip
+# are listed (a v2/v3 device is one of a chip's two cores). The single
+# source — bench.py reads this table for its MFU denominators.
+TPU_PEAK_BF16 = {
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v5": 459e12,  # v5p
+    "TPU v6 lite": 918e12,  # v6e / Trillium
+    "TPU v4": 275e12,
+}
 
 _COMPILE_LOCK = threading.Lock()
 _COMPILE_COUNT = 0
@@ -928,10 +930,9 @@ def device_peak_flops() -> Tuple[Optional[float], str]:
         dev = jax.devices()[0]
         if dev.platform != "tpu":
             return None, f"no datasheet peak for {dev.platform}"
-        lk = dev.device_kind.lower()
-        for sub, peak in TPU_PEAK_BF16:
-            if sub in lk:
-                return peak, f"datasheet bf16 ({dev.device_kind})"
+        peak = TPU_PEAK_BF16.get(dev.device_kind)
+        if peak is not None:
+            return peak, f"datasheet bf16 ({dev.device_kind})"
         return None, f"unknown TPU kind {dev.device_kind!r}"
     except Exception as e:
         return None, f"device query failed: {type(e).__name__}"

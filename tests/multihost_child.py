@@ -219,16 +219,10 @@ def main() -> int:
 
     import jax
 
-    # CPU platform must be selected before the backend initializes; env vars
-    # are read too late on this image (see spacy_ray_tpu/devices.py).
+    # CPU platform and device count must be selected before the backend
+    # initializes
     jax.config.update("jax_platforms", "cpu")
-    try:  # jax >= 0.4.34; older builds only have the XLA_FLAGS spelling
-        jax.config.update("jax_num_cpu_devices", 4)
-    except AttributeError:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=4"
-        ).strip()
+    jax.config.update("jax_num_cpu_devices", 4)
     jax.distributed.initialize(
         coordinator_address=f"127.0.0.1:{port}", num_processes=2, process_id=rank
     )

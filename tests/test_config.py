@@ -160,5 +160,5 @@ def test_device_gpu_fails_loudly_without_cuda():
 
     from spacy_ray_tpu.cli import _setup_device
 
-    with pytest.raises(SystemExit, match="no usable CUDA backend"):
+    with pytest.raises(SystemExit, match="JAX found no gpu here"):
         _setup_device("gpu")

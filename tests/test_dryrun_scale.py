@@ -21,9 +21,7 @@ pytestmark = pytest.mark.slow
 def test_dryrun_multichip_16_devices():
     env = dict(os.environ)
     env.pop("JAX_PLATFORMS", None)
-    # explicit device-count flag: works on every supported jax, overriding
-    # the conftest's 8-device value (force_cpu's jax_num_cpu_devices config
-    # key alone requires jax >= 0.4.34)
+    # explicit device-count flag, overriding the conftest's 8-device value
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
     proc = subprocess.run(
         [

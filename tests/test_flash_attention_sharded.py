@@ -9,7 +9,6 @@ import pytest
 import spacy_ray_tpu.ops.flash_attention as fa
 from spacy_ray_tpu.parallel import context as pctx
 from spacy_ray_tpu.parallel.mesh import build_mesh
-from spacy_ray_tpu.parallel.smap import PARTIAL_MANUAL
 
 
 @pytest.fixture(autouse=True)
@@ -30,7 +29,6 @@ def _mk(B=4, T=128, H=4, Dh=32, seed=0):
     return q, k, v, mask
 
 
-@pytest.mark.skipif(not PARTIAL_MANUAL, reason="needs partial-manual shard_map")
 def test_sharded_attention_matches_dense():
     q, k, v, mask = _mk()
     want = np.asarray(fa.reference_attention(q, k, v, mask))
@@ -43,7 +41,6 @@ def test_sharded_attention_matches_dense():
     )
 
 
-@pytest.mark.skipif(not PARTIAL_MANUAL, reason="needs partial-manual shard_map")
 def test_sharded_attention_falls_back_on_indivisible_layout():
     # H=3 does not divide over model=2: attention() must fall back to the
     # XLA path rather than produce wrong shards

@@ -171,22 +171,13 @@ def test_pipe_composes_with_tp(trf_nlp):
 def test_pipe_composes_with_context(trf_nlp):
     """PP x CP x DP in one mesh: ring attention nests as a partial-manual
     region (manual over `context` only) inside the pipeline's `pipe`
-    region, and the result equals the dense loop. (On jax without
-    partial-manual shard_map this combination raises instead.)"""
-    from spacy_ray_tpu.parallel.smap import PARTIAL_MANUAL
-
+    region, and the result equals the dense loop."""
     nlp, egs = trf_nlp
     batch = nlp.collate(egs[:8], with_targets=False, pad_batch_to=8, pad_len_to=16)
     forward = nlp.make_forward_fn()
     mesh = build_mesh(n_data=2, n_context=2, n_pipe=2)
     params = place_replicated(nlp.params, mesh)
     tokens = place_batch(batch["tokens"], mesh)
-
-    if not PARTIAL_MANUAL:
-        with pctx.use_mesh(mesh):
-            with pytest.raises(ValueError, match="partial-manual"):
-                jax.jit(forward)(params, tokens)
-        return
 
     dense = jax.jit(forward)(nlp.params, batch["tokens"])
     with pctx.use_mesh(mesh):
