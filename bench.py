@@ -4678,7 +4678,7 @@ def main() -> None:
             # leak into later specs in this process
             import spacy_ray_tpu.ops.flash_attention as _fa
 
-            _fa._PROBED = None
+            _fa.GATE.reset()
         try:
             rec = run_one(spec, platform)
         except Exception as e:  # one broken config must not hide the others
@@ -4692,7 +4692,7 @@ def main() -> None:
                 else:
                     os.environ[k] = v
             if spec_env:
-                _fa._PROBED = None
+                _fa.GATE.reset()
         if rec is None:
             continue
         base = baseline.get(rec["name"])

@@ -146,18 +146,27 @@ def require(cond: bool, what: str) -> None:
         raise PhaseFailed(what)
 
 
-def require_active(runtime: Dict[str, Any], key: str, rehearsal: bool) -> None:
+def require_active(
+    runtime: Dict[str, Any], key: str, rehearsal: bool,
+    how: str = "active (pallas)",
+) -> None:
     """A main-path kernel must be ``active`` in compiled mode on the chip.
-    (On the CPU rehearsal it must be ``off`` — with its reason.)"""
+    The status is the path(s) the child's programs took, so a kernel that
+    gave way to XLA on some shape fails here with its reason. (On the CPU
+    rehearsal it must be ``off`` — with its reason.)"""
     status = str(runtime.get(key, ""))
     if rehearsal:
-        require(status.startswith(("off (", "not probed", "active (xla")),
-                f"{key}: {status!r}")
+        require(status.startswith(("off (", "active (xla")), f"{key}: {status!r}")
     else:
         require(
-            status == "active (pallas)",
-            f"{key} is not active in compiled mode on the chip: {status!r}",
+            status == how,
+            f"{key} is not {how!r} in compiled mode on the chip: {status!r}",
         )
+
+
+def trunk_dtype(rehearsal: bool) -> str:
+    """What trf.cfg's ``compute_dtype = "auto"`` must resolve to."""
+    return "float32" if rehearsal else "bfloat16"
 
 
 def overrides(extra: Dict[str, Any]) -> List[str]:
@@ -236,6 +245,7 @@ def read_metrics(metrics_dir: Path) -> Dict[str, List[Dict[str, Any]]]:
 def phase_train(
     name: str, cfg: str, window: int, work: Path, device: str,
     rehearsal: bool, extra: Dict[str, Any], kernels: List[str],
+    compute_dtype: str,
 ) -> Dict[str, Any]:
     """``train`` for 2*window optimizer steps with an evaluation and a
     checkpoint at each window's end; every loss finite, the last window's
@@ -281,8 +291,8 @@ def phase_train(
             f"{name} ran on {runtime['device']}")
     for key in kernels:
         require_active(runtime, key, rehearsal)
-    if not rehearsal:
-        require(runtime["compute_dtype"] == "bfloat16", runtime["compute_dtype"])
+    require(runtime["compute_dtype"] == compute_dtype,
+            f"{name}: compute_dtype {runtime['compute_dtype']!r}")
     return runtime
 
 
@@ -303,6 +313,8 @@ def phase_evaluate(work: Path, device: str, rehearsal: bool) -> Dict[str, Any]:
             f"scores out of range: {flat}")
     require(runtime["device"]["platform"] == device, str(runtime["device"]))
     require_active(runtime, "flash_attention", rehearsal)
+    require(runtime["compute_dtype"] == trunk_dtype(rehearsal),
+            f"evaluate: compute_dtype {runtime['compute_dtype']!r}")
     return runtime
 
 
@@ -375,6 +387,8 @@ def phase_serve(work: Path, device: str, rehearsal: bool) -> Dict[str, Any]:
         require(health.get("status") == "ok", f"healthz: {health}")
         require(runtime["device"]["platform"] == device, str(runtime["device"]))
         require_active(runtime, "flash_attention", rehearsal)
+        require(runtime["compute_dtype"] == trunk_dtype(rehearsal),
+                f"serve: compute_dtype {runtime['compute_dtype']!r}")
         if not rehearsal:
             require(str(runtime["precision"]).startswith("bf16"), runtime["precision"])
         return runtime
@@ -418,9 +432,10 @@ def one_chip(args: argparse.Namespace) -> int:
             attempt(check_probed_kernels, rows, rehearsal)
             attempt(phase_train, "trf", "configs/trf.cfg", TRF_WINDOW, work,
                     device, rehearsal, TINY_TRF if rehearsal else {},
-                    ["flash_attention", "fused_update"])
+                    ["flash_attention", "fused_update"], trunk_dtype(rehearsal))
             attempt(phase_train, "sm", "configs/sm.cfg", SM_WINDOW, work,
-                    device, rehearsal, {}, ["hash_embed_kernel", "fused_update"])
+                    device, rehearsal, {}, ["hash_embed_kernel", "fused_update"],
+                    "n/a (no transformer trunk)")
             if (work / "trf" / "best-model" / "meta.json").exists():
                 attempt(phase_evaluate, work, device, rehearsal)
                 attempt(phase_serve, work, device, rehearsal)
@@ -500,13 +515,13 @@ def mesh_comparison(args: argparse.Namespace, rehearsal: bool) -> Optional[str]:
     import spacy_ray_tpu.ops.flash_attention as fa
     import spacy_ray_tpu.training.loop as loop
     from spacy_ray_tpu.config import load_config
+    from spacy_ray_tpu.devices import runtime_report
 
     ids = [d.id for d in jax.devices()]
     emit(phase="devices", ids=ids, coords=[
         list(getattr(d, "coords", ())) for d in jax.devices()])
 
     real_step = loop.make_train_step
-    real_sharded = fa._sharded_flash_attention
     seen: Dict[str, Any] = {}
 
     def bytes_by_device(tree: Any) -> Dict[int, int]:
@@ -534,12 +549,6 @@ def mesh_comparison(args: argparse.Namespace, rehearsal: bool) -> Optional[str]:
         run.__dict__.update(update.__dict__)
         return run
 
-    def spying_sharded(*a: Any, **k: Any):
-        out = real_sharded(*a, **k)
-        seen["sharded_flash_calls"] = seen.get("sharded_flash_calls", 0) + 1
-        seen["sharded_flash_taken"] = out is not None
-        return out
-
     runs: Dict[int, Dict[str, Any]] = {}
     with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
         work = Path(tmp)
@@ -553,39 +562,37 @@ def mesh_comparison(args: argparse.Namespace, rehearsal: bool) -> Optional[str]:
             **(TINY_TRF if rehearsal else {}),
         }
         loop.make_train_step = spying_step
-        fa._sharded_flash_attention = spying_sharded
         try:
             for n_workers in (4, 1):
                 seen.clear()
+                fa.GATE.reset()  # each run's attention status is its own
                 t0 = time.monotonic()
                 config = load_config(ROOT / "configs" / "trf.cfg", over,
                                      interpolate=False)
-                _, result = loop.train(config, n_workers=n_workers,
-                                       stdout_log=False)
+                nlp, result = loop.train(config, n_workers=n_workers,
+                                         stdout_log=False)
+                # what `train` prints as its runtime line
+                report = {**runtime_report(nlp), **result.resolved}
                 runs[n_workers] = {
                     "losses": [float(x) for x in seen["losses"]],
                     "resident": seen["resident"],
-                    "resolved": dict(result.resolved),
+                    "runtime": {k: report[k] for k in (
+                        "compute_dtype", "flash_attention", "fused_update",
+                        "update_sharding", "bf16_shadow")},
                     "eval_score": result.history[-1]["score"],
                     "eval_seconds": round(result.history[-1]["eval_seconds"], 2),
-                    "sharded_flash": (seen.get("sharded_flash_calls", 0),
-                                      seen.get("sharded_flash_taken")),
                     "wall_s": round(time.monotonic() - t0, 2),
                 }
+                del nlp
                 gc.collect()
         finally:
             loop.make_train_step = real_step
-            fa._sharded_flash_attention = real_sharded
 
     for n_workers, run in runs.items():
         emit(phase=f"mesh_{n_workers}", wall_s=run["wall_s"], steps=len(run["losses"]),
              losses=run["losses"], eval_score=run["eval_score"],
              eval_s=run["eval_seconds"],
-             resident_bytes_by_device=run["resident"],
-             attention=fa.flash_attention_status(),
-             sharded_flash_attention={"traced_calls": run["sharded_flash"][0],
-                                      "taken": run["sharded_flash"][1]},
-             **run["resolved"])
+             resident_bytes_by_device=run["resident"], **run["runtime"])
     l4, l1 = np.array(runs[4]["losses"]), np.array(runs[1]["losses"])
     rel = np.abs(l4 - l1) / np.abs(l1)
     emit(phase="mesh_compare", loss_rel_diff_by_step=[float(x) for x in rel],
@@ -611,10 +618,15 @@ def mesh_comparison(args: argparse.Namespace, rehearsal: bool) -> Optional[str]:
         problems.append(f"params not replicated: {res['params']}")
     if max(res["opt_state"].values()) > 0.5 * full["opt_state"][one]:
         problems.append(f"optimizer state not sharded: {res['opt_state']}")
-    if "full (state + apply sharded 4-way" not in runs[4]["resolved"]["update_sharding"]:
-        problems.append(f"update_sharding: {runs[4]['resolved']['update_sharding']}")
-    if not rehearsal and runs[4]["sharded_flash"][1] is not True:
-        problems.append(f"4-chip attention left the kernel: {runs[4]['sharded_flash']}")
+    if "full (state + apply sharded 4-way" not in runs[4]["runtime"]["update_sharding"]:
+        problems.append(f"update_sharding: {runs[4]['runtime']['update_sharding']}")
+    # the step and the evaluation both kept the kernel, one shard a chip
+    try:
+        require_active(runs[4]["runtime"], "flash_attention", rehearsal,
+                       "active (pallas, per shard in a shard_map)")
+        require_active(runs[1]["runtime"], "flash_attention", rehearsal)
+    except PhaseFailed as e:
+        problems.append(str(e))
     return "; ".join(problems) or None
 
 

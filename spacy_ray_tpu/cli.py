@@ -327,16 +327,17 @@ def train_command(argv: List[str]) -> int:
                 f"{stats['projectivized']} pseudo-projectivized, "
                 f"{stats['skipped']} skipped (unusable trees)"
             )
-    _print_runtime(**getattr(result, "resolved", {}))
+    _print_runtime(nlp, **getattr(result, "resolved", {}))
     return 0
 
 
-def _print_runtime(**extra: Any) -> None:
-    """One ``runtime {...}`` line: the device this process ran on and what
-    each platform-dependent switch resolved to (devices.runtime_report)."""
+def _print_runtime(nlp: Any, **extra: Any) -> None:
+    """One ``runtime {...}`` line, after the work: the device this process
+    ran on and what each platform-dependent switch resolved to for the
+    pipeline it ran (devices.runtime_report)."""
     from .devices import runtime_report
 
-    print("runtime " + json.dumps({**runtime_report(), **extra}), flush=True)
+    print("runtime " + json.dumps({**runtime_report(nlp), **extra}), flush=True)
 
 
 def evaluate_command(argv: List[str]) -> int:
@@ -376,7 +377,7 @@ def evaluate_command(argv: List[str]) -> int:
             encoding="utf8",
         )
         print(f"metrics written to {args.output}")
-    _print_runtime()
+    _print_runtime(nlp)
     return 0
 
 
@@ -1172,6 +1173,7 @@ def _probe_rows() -> List[tuple]:
     import jax
 
     from .devices import enable_compile_cache, runtime_report
+    from .models.transformer import _resolve_compute_dtype
     from .ops import flash_attention, fused_update, pallas_kernels
     from .ops.probe import KernelProbeError
     from .parallel.mesh import build_mesh
@@ -1186,19 +1188,21 @@ def _probe_rows() -> List[tuple]:
         return [("accelerator", f"UNREACHABLE ({type(e).__name__}: {e})")]
     platform_name = devs[0].platform
 
-    def resolved(fn) -> str:
-        try:
-            return str(fn())
-        except KernelProbeError as e:
-            return f"FAILED ({e})"
-
-    # run the kernel probes eagerly: each compiles and checks its kernel
+    # run the kernel probes eagerly: each compiles and checks its kernel,
+    # and a failure stays in its status ("FAILED (...)", ops/probe.Gate)
     for enabled in (
         flash_attention.flash_attention_enabled,
         pallas_kernels.pallas_enabled,
         fused_update.fused_kernel_enabled,
     ):
-        resolved(enabled)
+        try:
+            enabled()
+        except KernelProbeError:
+            pass
+    try:
+        precision = "{} ({})".format(*resolve_precision("int8", platform_name))
+    except KernelProbeError as e:
+        precision = f"FAILED ({e})"
     report = runtime_report()
     gc = resolve_grad_compression("auto", platform_name)
     return [
@@ -1211,13 +1215,12 @@ def _probe_rows() -> List[tuple]:
             build_mesh(n_data=len(devs)),
         )),
         ("grad_compression", f"auto -> {gc[0]} ({gc[1]})"),
-        ("compute_dtype", f"auto -> {report['compute_dtype']}"),
+        ("compute_dtype",
+         f"auto -> {_resolve_compute_dtype('auto').__name__}"),
         ("flash_attention", report["flash_attention"]),
         ("hash_embed", report["hash_embed_kernel"]),
         ("fused_kernel", fused_update.fused_kernel_status()),
-        ("precision", "int8 -> " + resolved(
-            lambda: "{} ({})".format(*resolve_precision("int8", platform_name))
-        )),
+        ("precision", "int8 -> " + precision),
         ("native_hash", report["native_hash"]),
         ("compile_cache", "{dir} ({entries} entries)".format(
             **report["compile_cache"]
@@ -2254,7 +2257,7 @@ def serve_command(argv: List[str]) -> int:
                 {"kind": "serving", "unix_time": _time.time(), **snap}
             )) + "\n")
         print(f"serving telemetry written to {args.metrics_dir}", flush=True)
-    _print_runtime(precision=engine.overlay.label)
+    _print_runtime(engine.nlp, precision=engine.overlay.label)
     if rc == 0:
         print("drained; exiting 0", flush=True)
     else:
@@ -2716,9 +2719,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         print(f"Unknown command {command!r}. Available: {', '.join(COMMANDS)}", file=sys.stderr)
         return 1
     _load_plugins()
-    # a warning from anywhere in the package (a native build that failed)
-    # reaches the operator whatever level a command gives the root logger
-    logger.setLevel(logging.WARNING)
     return COMMANDS[command](argv[1:])
 
 

@@ -123,26 +123,28 @@ def compile_cache_entries() -> int:
     return sum(1 for name in os.listdir(cache) if not name.endswith("-atime"))
 
 
-def runtime_report() -> Dict[str, Any]:
+def runtime_report(nlp: Any = None) -> Dict[str, Any]:
     """What this process ran on, and what every platform-dependent switch
     resolved to here — by name, with the reason wherever one is off. The
     commands that do device work print it, so a record never has to infer
-    the path from the flags that were passed."""
+    the path from the flags that were passed. Read it AFTER the work: the
+    kernel lines are the paths the traced programs took (a kernel that is
+    armed but gave way to XLA on this mesh or shape says so), and
+    ``compute_dtype`` is what the trunks of ``nlp`` computed in."""
     import jax
 
     from . import native
-    from .models.transformer import _resolve_compute_dtype
+    from .models.transformer import pipeline_compute_dtype
     from .ops.flash_attention import flash_attention_status
     from .ops.pallas_kernels import hash_embed_status
 
     devs = jax.devices()
-    return {
+    report = {
         "device": {
             "platform": devs[0].platform,
             "kind": devs[0].device_kind,
             "count": len(devs),
         },
-        "compute_dtype": _resolve_compute_dtype("auto").__name__,
         "flash_attention": flash_attention_status(),
         "hash_embed_kernel": hash_embed_status(),
         "native_hash": (
@@ -154,6 +156,9 @@ def runtime_report() -> Dict[str, Any]:
             "entries": compile_cache_entries(),
         },
     }
+    if nlp is not None:
+        report["compute_dtype"] = pipeline_compute_dtype(nlp)
+    return report
 
 
 def refuse_shared_chip(

@@ -289,20 +289,34 @@ def _wdot(h: jnp.ndarray, leaf, compute_dtype) -> jnp.ndarray:
     return h @ leaf.astype(compute_dtype)
 
 
-def pipeline_shadow_dtype(nlp) -> Optional[Any]:
-    """bfloat16 when some transformer trunk in the pipeline resolves its
-    compute dtype to bf16 (the only case a bf16 shadow is numerics-
-    preserving), else None — the ``[training] bf16_shadow = "auto"``
-    decision point."""
+def _trunk_compute_dtypes(nlp) -> List[Any]:
+    """The resolved compute dtype of every transformer trunk in the
+    pipeline ("auto" depends on the backend), in pipeline order."""
+    out = []
     for comp in nlp.components.values():
         model = getattr(comp, "model", None)
         if model is None:
             continue
         for m in model.walk():
             name = m.meta.get("compute_dtype_name")
-            if name and _resolve_compute_dtype(name) == jnp.bfloat16:
-                return jnp.bfloat16
-    return None
+            if name:
+                out.append(_resolve_compute_dtype(name))
+    return out
+
+
+def pipeline_shadow_dtype(nlp) -> Optional[Any]:
+    """bfloat16 when some transformer trunk in the pipeline resolves its
+    compute dtype to bf16 (the only case a bf16 shadow is numerics-
+    preserving), else None — the ``[training] bf16_shadow = "auto"``
+    decision point."""
+    return jnp.bfloat16 if jnp.bfloat16 in _trunk_compute_dtypes(nlp) else None
+
+
+def pipeline_compute_dtype(nlp) -> str:
+    """What ``compute_dtype`` resolved to for THIS pipeline's trunks on
+    this backend, for the run's records — not what "auto" would give."""
+    names = sorted({jnp.dtype(d).name for d in _trunk_compute_dtypes(nlp)})
+    return " + ".join(names) or "n/a (no transformer trunk)"
 
 
 def _resolve_compute_dtype(name: str):

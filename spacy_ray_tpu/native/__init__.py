@@ -27,6 +27,9 @@ _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 
 logger = logging.getLogger("spacy_ray_tpu.native")
+# its own level: the commands hold the root logger at ERROR unless --verbose,
+# and the one warning here (a build that failed) must reach the operator
+logger.setLevel(logging.WARNING)
 
 
 def _build() -> bool:

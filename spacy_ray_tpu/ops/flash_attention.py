@@ -280,10 +280,6 @@ def attention_vmem_ok(T: int, DP: int, dtype_bytes: int = 2) -> bool:
     return kv + dkv + scores + qblocks <= VMEM_ATTN_BUDGET
 
 
-_PROBED: Optional[bool] = None
-_STATUS = "not probed (no attention ran in this process)"
-
-
 def _probe_check() -> Optional[str]:
     """Forward AND gradients against the dense reference, on a shape with
     a ragged key mask and a T that needs padding to the query block."""
@@ -316,22 +312,25 @@ def _probe_check() -> Optional[str]:
     return None
 
 
+GATE = _probe.Gate(
+    "flash attention", "SRT_PALLAS_ATTN", _probe_check,
+    unprobed="no attention ran in this process",
+)
+
+
 def flash_attention_enabled() -> bool:
     """One-time probe (ops/probe.py): compile + validate forward AND
     gradients vs the dense reference on the current backend; cache the
     verdict. SRT_PALLAS_ATTN=1 forces the probe on any backend, =0 forces
     off; default arms on TPU only, where a failed probe raises."""
-    global _PROBED, _STATUS
-    if _PROBED is None:
-        _PROBED, _STATUS = _probe.probe(
-            "flash attention", "SRT_PALLAS_ATTN", _probe_check, _INTERPRET
-        )
-    return _PROBED
+    return GATE.enabled(_INTERPRET)
 
 
 def flash_attention_status() -> str:
-    """What the attention path resolved to in this process, in words."""
-    return _STATUS
+    """What attention did in this process, in words: the paths
+    :func:`attention` (and ring attention) took in the programs traced so
+    far, else the probe's verdict."""
+    return GATE.status()
 
 
 def _sharded_flash_attention(q, k, v, mask, mesh):
@@ -377,17 +376,32 @@ def attention(
     """Attention entry point for the trunk: pallas flash kernel when the
     probe enabled it and the shape fits VMEM, else XLA's fused
     ``jax.nn.dot_product_attention``. Under a multi-device mesh the kernel
-    runs per-shard inside a partial-manual shard_map over the data/model
-    axes (_sharded_flash_attention); layouts that don't divide fall back
-    to XLA attention, which partitions cleanly."""
+    runs per-shard inside a shard_map (_sharded_flash_attention); layouts
+    that don't divide fall back to XLA attention, which partitions cleanly.
+    An armed kernel that gives way says so: each branch notes its path on
+    ``GATE``, and ``flash_attention_status`` reports what was taken."""
     from ..parallel import context as pctx
 
-    if flash_attention_enabled() and attention_vmem_ok(
-        q.shape[1], _dp(q.shape[-1]), q.dtype.itemsize
-    ):
-        if pctx.single_device():
-            return flash_attention(q, k, v, mask)
-        out = _sharded_flash_attention(q, k, v, mask, pctx.current_mesh())
-        if out is not None:
-            return out
-    return jax.nn.dot_product_attention(q, k, v, mask=mask[:, None, None, :])
+    B, T, H, Dh = q.shape
+    out = None
+    if not flash_attention_enabled():
+        pass  # the probe's verdict says why
+    elif not attention_vmem_ok(T, _dp(Dh), q.dtype.itemsize):
+        GATE.took(f"xla (T={T} is past the kernel's VMEM budget)")
+    elif pctx.single_device():
+        GATE.took(_probe.active(_INTERPRET))
+        out = flash_attention(q, k, v, mask)
+    else:
+        mesh = pctx.current_mesh()
+        out = _sharded_flash_attention(q, k, v, mask, mesh)
+        axes = {name: n for name, n in mesh.shape.items() if n > 1}
+        GATE.took(
+            _probe.active(_INTERPRET, "per shard in a shard_map")
+            if out is not None else
+            f"xla (batch {B} x heads {H} does not divide the mesh {axes})"
+        )
+    if out is None:
+        out = jax.nn.dot_product_attention(
+            q, k, v, mask=mask[:, None, None, :]
+        )
+    return out

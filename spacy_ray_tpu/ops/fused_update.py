@@ -193,28 +193,6 @@ def _kernel_leaf(p, g, m, v, scal, hyper: FusedHyper, interpret=None):
 
 # ------------------------------------------------------------------- probe
 
-_PROBED: Optional[bool] = None
-_STATUS = "not probed (no fused update ran in this process)"
-
-
-def fused_kernel_enabled() -> bool:
-    """One-time probe (ops/probe.py): compile the kernel and validate it
-    against the XLA leaf math on the current backend; cache the verdict.
-    SRT_PALLAS_FUSED=1 forces the probe on any backend, =0 forces off;
-    default arms on TPU only, where a failed probe raises — the same
-    discipline as the flash-attention probe."""
-    global _PROBED, _STATUS
-    if _PROBED is None:
-        _PROBED, _STATUS = _probe.probe(
-            "fused update", "SRT_PALLAS_FUSED", _probe_kernel, _INTERPRET
-        )
-    return _PROBED
-
-
-def fused_kernel_status() -> str:
-    """What the fused-update kernel resolved to in this process, in words."""
-    return _STATUS
-
 
 def _probe_kernel(interpret=None) -> Optional[str]:
     """None when the kernel matches the XLA leaf math, else the mismatch."""
@@ -240,6 +218,26 @@ def _probe_kernel(interpret=None) -> Optional[str]:
     return None
 
 
+GATE = _probe.Gate(
+    "fused update", "SRT_PALLAS_FUSED", _probe_kernel,
+    unprobed="no fused update ran in this process",
+)
+
+
+def fused_kernel_enabled() -> bool:
+    """One-time probe (ops/probe.py): compile the kernel and validate it
+    against the XLA leaf math on the current backend; cache the verdict.
+    SRT_PALLAS_FUSED=1 forces the probe on any backend, =0 forces off;
+    default arms on TPU only, where a failed probe raises — the same
+    discipline as the flash-attention probe."""
+    return GATE.enabled(_INTERPRET)
+
+
+def fused_kernel_status() -> str:
+    """What the fused-update kernel's probe resolved to, in words."""
+    return GATE.verdict
+
+
 def fused_status(tx: Any, mesh: Any = None) -> str:
     """Honest-labeling string for bench records: what the optimizer update
     path ACTUALLY is (a CPU fallback must not masquerade as the kernel).
@@ -251,11 +249,11 @@ def fused_status(tx: Any, mesh: Any = None) -> str:
     if not getattr(tx, "applies_updates", False):
         return "off (optax chain)"
     multi = mesh is not None and int(mesh.size) > 1
-    if _PROBED is True and not multi:
-        return "active (pallas)"
+    if GATE.armed is True and not multi:
+        return _probe.active(_INTERPRET)
     if multi:
         return "active (xla; kernel gated off a multi-device mesh)"
-    return f"active (xla; kernel {_STATUS})"
+    return f"active (xla; kernel {GATE.verdict})"
 
 
 # ------------------------------------------------- fused transformation

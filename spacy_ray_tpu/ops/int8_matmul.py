@@ -21,16 +21,19 @@ the whole weight matrix from HBM. Quartering the weight bytes is the
 per-replica multiplier ROADMAP item 3a names; the arithmetic itself was
 never the bottleneck at these occupancies.
 
-Honesty rules (the flash-attention/fused-update discipline, verbatim):
+Honesty rules (the gate every kernel goes through, ops/probe.py):
 
 * enabled ONLY by :func:`int8_probe` — compile + numeric validation vs
   the f32-dequant reference on the current backend; ``SRT_PALLAS_INT8=1``
   forces on (interpret-mode on non-TPU backends, so CPU tests and the
   forced bench arm run the REAL kernel body, interpreted), ``=0`` forces
-  off; default auto-enables on TPU only.
+  off; default auto-enables on TPU only, where a failed probe raises.
 * the probe's reason string is the overlay label's source of truth:
   "active (pallas)" only when the compiled kernel runs, "active (pallas
-  interpret-mode, forced)" when interpreted, a typed refusal otherwise.
+  interpret-mode)" when interpreted, a typed refusal otherwise.
+* activations keep the precision they arrive in: bfloat16 (the trunk's
+  compute dtype on a TPU) goes to the MXU as it is, exactly; float32 is
+  contracted at full f32 precision, not rounded to bfloat16 on the way.
 * shapes whose per-block VMEM working set exceeds the budget fall back
   to the jnp dequant matmul (same numbers, no kernel) — the same
   host-side guard as ``flash_attention.attention_vmem_ok``.
@@ -136,9 +139,9 @@ def reference_int8_matmul(
 ) -> jnp.ndarray:
     """jnp fallback/reference: ``x [..., K] @ dequant(q8 [K, N]) -> [..., N]``
     in f32 — what the pallas kernel is validated against. HIGHEST precision:
-    the kernel's f32 dot is a true f32 contraction, while XLA's default on
-    a TPU rounds f32 operands to bfloat16 first — measured on a v5e, the
-    default-precision product sits 3.6e-3 away from the kernel."""
+    at the default a TPU rounds f32 operands to bfloat16 before the MXU
+    (measured on a v5e: 4.7e-3 from the f32 product), which is not what
+    the kernel does (see ``_kernel``) and no reference to hold it to."""
     return jnp.matmul(
         x.astype(jnp.float32), dequantize_int8(q8, scale),
         precision=jax.lax.Precision.HIGHEST,
@@ -149,14 +152,22 @@ def reference_int8_matmul(
 
 
 def _kernel(x_ref, wq_ref, s_ref, o_ref):
-    # x [BM, K] f32, wq [K, BN] int8, s [1, BN] f32 -> o [BM, BN] f32.
-    # Dequantize-in-kernel: the int8 block upcasts on the VPU; the scale
+    # x [BM, K] bf16|f32, wq [K, BN] int8, s [1, BN] f32 -> o [BM, BN] f32.
+    # Dequantize-in-kernel: the int8 block upcasts on the VPU to the
+    # activations' dtype (|q| <= 127 is exact in bfloat16 too); the scale
     # multiply lands on the f32 accumulator AFTER the dot (exact: scale
     # is constant per output column, so (x @ q) * s == x @ (q * s)).
+    # bf16 x bf16 is one exact MXU pass. An f32 dot at the default
+    # precision would be rounded to that same one pass (3.2e-3 from the
+    # f32 product, measured on a v5e), so f32 activations ask for HIGHEST.
     x = x_ref[...]
-    w = wq_ref[...].astype(jnp.float32)
+    w = wq_ref[...].astype(x.dtype)
     acc = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x, w, (((1,), (0,)), ((), ())),
+        precision=(
+            jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+        ),
+        preferred_element_type=jnp.float32,
     )
     o_ref[...] = acc * s_ref[...]
 
@@ -177,8 +188,8 @@ def _int8_matmul_raw(
     x2: jnp.ndarray, q8: jnp.ndarray, scale: jnp.ndarray,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """[M, K] f32, [K, N] int8, [N] f32 -> [M, N] f32. Pads M/N/K to the
-    block grid (zero rows/columns contribute nothing; padded scale
+    """[M, K] bf16|f32, [K, N] int8, [N] f32 -> [M, N] f32. Pads M/N/K to
+    the block grid (zero rows/columns contribute nothing; padded scale
     columns are sliced away with their outputs)."""
     if interpret is None:
         # forced-on non-TPU backends (CPU tests, the forced bench arm)
@@ -212,12 +223,13 @@ def _int8_matmul_raw(
 
 
 def int8_vmem_ok(K: int) -> bool:
-    """Whether one grid step's windows (x block f32 + w block int8 + out
+    """Whether one grid step's windows (x block + w block int8 + out
     block f32 + scale row, each double-buffered by the pipeline) fit the
     compiler's scoped VMEM for contraction dim ``K`` (kept fully resident
-    per step). Compiled for v5e the kernel is accepted at K=12288 and
-    refused at K=16384 (20.12M against the 16.00M limit); this arithmetic
-    stops at K=12928."""
+    per step). Sized for f32 activations, the larger of the two dtypes:
+    compiled for v5e that kernel is accepted at K=12928, where this
+    arithmetic stops, and refused at K=16384 (bf16 activations are still
+    accepted at K=20480)."""
     Kp = ((K + KP - 1) // KP) * KP
     need = 2 * (BM * Kp * 4 + Kp * BN * 1 + BM * BN * 4 + BN * 4)
     return need <= VMEM_INT8_BUDGET
@@ -226,15 +238,19 @@ def int8_vmem_ok(K: int) -> bool:
 def int8_matmul(
     x: jnp.ndarray, q8: jnp.ndarray, scale: jnp.ndarray
 ) -> jnp.ndarray:
-    """Weight-only int8 matmul: ``x [..., K]`` (any float dtype) times a
-    quantized weight ``q8 [K, N] int8`` with per-channel ``scale [N]``;
-    returns f32 ``[..., N]``. Uses the pallas kernel (compiled on TPU,
-    interpreted where the probe armed it that way); contraction dims past
-    the VMEM budget fall back to the jnp dequant matmul — identical
-    numbers, no kernel."""
+    """Weight-only int8 matmul: ``x [..., K]`` times a quantized weight
+    ``q8 [K, N] int8`` with per-channel ``scale [N]``; returns f32
+    ``[..., N]``. bfloat16 activations stay bfloat16 into the kernel (no
+    f32 copy of them is made); any other float dtype is taken as float32
+    and contracted at full f32 precision. Uses the pallas kernel (compiled
+    on TPU, interpreted where the probe armed it that way); contraction
+    dims past the VMEM budget fall back to the jnp dequant matmul —
+    identical numbers, no kernel."""
     lead = x.shape[:-1]
     K = x.shape[-1]
-    x2 = x.reshape(-1, K).astype(jnp.float32)
+    x2 = x.reshape(-1, K)
+    if x2.dtype != jnp.bfloat16:
+        x2 = x2.astype(jnp.float32)
     if not int8_vmem_ok(K):
         return reference_int8_matmul(x2, q8, scale).reshape(*lead, q8.shape[1])
     out = _int8_matmul_raw(x2, q8, scale)
@@ -246,8 +262,7 @@ def int8_matmul(
 
 # (env value, backend) -> (ok, reason); the env is part of the key so a
 # test that flips SRT_PALLAS_INT8 re-probes instead of reading a stale
-# verdict (the flash/fused probes cache one bool; this probe's verdict
-# is backend- AND force-dependent because of interpret mode)
+# verdict, the backend because the overlay can ask about another one
 _PROBE_CACHE: dict = {}
 
 
@@ -256,82 +271,47 @@ def _numeric_probe(interpret: bool) -> Optional[str]:
     kernel against the dequant reference; None when they agree. The flag
     is EXPLICIT: the unforced TPU gate must prove the COMPILED kernel —
     letting the interpret fallback answer for it would pass the probe on
-    hosts where the real kernel cannot lower."""
+    hosts where the real kernel cannot lower. Both activation dtypes the
+    kernel takes are held to the f32 product: bfloat16 (what a trunk hands
+    over under ``compute_dtype = "auto"`` on a TPU) and float32."""
     r = jax.random.split(jax.random.PRNGKey(0), 2)
     w = jax.random.normal(r[0], (96, 160), jnp.float32) * 0.05
-    # activations as serving hands them over on a TPU: bfloat16 values
-    # (the trunk's compute dtype) widened to f32. The MXU takes the
-    # kernel's f32 operands in one bfloat16 pass, which is exact for these
-    # and for the int8 weights; arbitrary f32 activations would be rounded
-    # (3.2e-3 from the f32 product, measured on a v5e), as XLA's own
-    # default-precision matmuls round them
-    x = jax.random.normal(r[1], (33, 96), jnp.bfloat16).astype(jnp.float32)
     q8, scale = quantize_int8(w)
-    got = jax.jit(
-        lambda x_, q_, s_: _int8_matmul_raw(x_, q_, s_, interpret=interpret)
-    )(x, q8, scale)
-    want = reference_int8_matmul(x, q8, scale)
-    return _probe.mismatch("x @ dequant(w)", got, want, atol=1e-4, rtol=1e-4)
+    for dtype in (jnp.bfloat16, jnp.float32):
+        x = jax.random.normal(r[1], (33, 96), dtype)
+        got = jax.jit(
+            lambda x_, q_, s_: _int8_matmul_raw(x_, q_, s_, interpret=interpret)
+        )(x, q8, scale)
+        bad = _probe.mismatch(
+            f"{jnp.dtype(dtype).name} x @ dequant(w)", got,
+            reference_int8_matmul(x, q8, scale), atol=1e-4, rtol=1e-4,
+        )
+        if bad:
+            return bad
+    return None
 
 
 def int8_probe(backend: Optional[str] = None) -> Tuple[bool, str]:
     """The serving overlay's int8 gate: ``(ok, reason)`` where the
-    reason string is exactly what the overlay label carries.
-
-    Policy (mirrors the bf16 auto policy's shape — accelerator-armed,
-    CPU off unless forced — and the pallas probes' force knob):
-
-    * ``SRT_PALLAS_INT8=0`` — refused everywhere.
-    * ``SRT_PALLAS_INT8=1`` — probe runs anywhere; non-TPU backends run
-      the kernel interpret-mode (the forced label says so).
-    * unset — TPU only: the compiled kernel is probed and must validate
-      (a failure there raises, ops/probe.py); any other backend refuses
-      (the CPU auto-OFF rule, test-enforced like bf16's).
-
-    ``backend`` names the backend the answer is for. A compiled kernel can
-    only be proven by the process that holds that backend: asked about
-    another one, the probe refuses instead of compiling for the wrong chip.
-    """
-    here = jax.default_backend()
-    if backend is None:
-        backend = here
-    env = os.environ.get("SRT_PALLAS_INT8")
-    key = (env, backend)
-    if key in _PROBE_CACHE:
-        return _PROBE_CACHE[key]
-    forced = env == "1"
-    interpret = _INTERPRET or (forced and here != "tpu")
-    if env == "0":
-        ok, why = False, "SRT_PALLAS_INT8=0 — probe refused"
-    elif not forced and backend != "tpu":
-        ok, why = False, (
-            f"int8 overlay OFF on {backend} unless forced "
-            "(SRT_PALLAS_INT8=1 runs the interpret-mode kernel) — "
-            "probe refused"
+    reason string is exactly what the overlay label carries. The policy
+    is the one every kernel shares (``ops/probe.probe``): ``=0`` refuses
+    everywhere, ``=1`` probes anywhere (interpret-mode off a TPU, and the
+    label says so), unset arms on a TPU only, where the compiled kernel
+    must validate or the probe raises. ``backend`` names the backend the
+    answer is for; only the process that holds it can prove a kernel."""
+    backend = backend or jax.default_backend()
+    key = (os.environ.get("SRT_PALLAS_INT8"), backend)
+    if key not in _PROBE_CACHE:
+        interpret = _INTERPRET or jax.default_backend() != "tpu"
+        ok, status = _probe.probe(
+            "int8 matmul", "SRT_PALLAS_INT8",
+            lambda: _numeric_probe(interpret), interpret, backend,
         )
-    elif backend != here and not interpret:
-        ok, why = False, (
-            f"the compiled int8 kernel can only be probed on {backend} "
-            f"itself (this process runs on {here}) — probe refused"
+        _PROBE_CACHE[key] = ok, (
+            f"int8 kernel {status} on {backend}" if ok
+            else f"int8 kernel {status} — probe refused"
         )
-    else:
-        problem = _probe.checked(
-            "int8 matmul", lambda: _numeric_probe(interpret)
-        )
-        if problem is not None:
-            ok, why = False, (
-                f"int8 kernel probe failed on {backend} "
-                f"({problem.strip().splitlines()[0]}) — probe refused"
-            )
-        elif interpret:
-            ok, why = True, (
-                "int8 kernel active (pallas interpret-mode, forced) "
-                f"on {backend}"
-            )
-        else:
-            ok, why = True, f"int8 kernel active (pallas) on {backend}"
-    _PROBE_CACHE[key] = (ok, why)
-    return ok, why
+    return _PROBE_CACHE[key]
 
 
 def int8_matmul_enabled() -> bool:

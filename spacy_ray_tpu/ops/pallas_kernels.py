@@ -109,10 +109,6 @@ def _pallas_lookup_bwd(res, ct):
 _pallas_lookup.defvjp(_pallas_lookup_fwd, _pallas_lookup_bwd)
 
 
-_PROBED: Optional[bool] = None
-_STATUS = "not probed (no hash-embed lookup ran in this process)"
-
-
 def _probe_check() -> Optional[str]:
     """Forward and table gradient against the jnp gather-sum, at the sm
     pipeline's table shape (2000 x 96) with repeated ids in play."""
@@ -131,20 +127,23 @@ def _probe_check() -> Optional[str]:
     return _probe.mismatch("table grad", g_got, g_want, atol=1e-4)
 
 
+GATE = _probe.Gate(
+    "hash-embed lookup", "SRT_PALLAS", _probe_check,
+    unprobed="no hash-embed lookup ran in this process",
+)
+
+
 def pallas_enabled() -> bool:
     """One-time probe: compile + numerically validate forward AND gradient
     on the default backend; cache the verdict."""
-    global _PROBED, _STATUS
-    if _PROBED is None:
-        _PROBED, _STATUS = _probe.probe(
-            "hash-embed lookup", "SRT_PALLAS", _probe_check
-        )
-    return _PROBED
+    return GATE.enabled()
 
 
 def hash_embed_status() -> str:
-    """What the hash-embed lookup resolved to in this process, in words."""
-    return _STATUS
+    """What the hash-embed lookup did in this process, in words: the paths
+    :func:`hash_embed_lookup` took in the programs traced so far, else the
+    probe's verdict."""
+    return GATE.status()
 
 
 # HBM budget for the one-hot counts operand ([tokens, rows] elements) —
@@ -158,7 +157,8 @@ def hash_embed_lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
     Uses the pallas kernel when the startup probe enabled it, the table
     fits the VMEM budget and the program runs on one device (a kernel has
     no partitioning rule: under a multi-device mesh the jnp paths below
-    partition cleanly). On TPU without the kernel, small tables use a
+    partition cleanly); an armed kernel that gives way notes why on
+    ``GATE``. On TPU without the kernel, small tables use a
     one-hot count-matrix matmul instead of the gather (TPU gathers
     serialize; summing the 4 one-hots gives a count row, and counts @
     table == the multiplicity-weighted row sum). Plain jnp gather
@@ -167,12 +167,18 @@ def hash_embed_lookup(table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
     from ..parallel import context as pctx
 
     lead_shape = ids.shape[:-1]
-    if (
-        pctx.single_device()
-        and pallas_enabled()
-        and table.dtype == jnp.float32
-        and table.nbytes <= VMEM_TABLE_BUDGET
-    ):
+    fits = table.dtype == jnp.float32 and table.nbytes <= VMEM_TABLE_BUDGET
+    if not pallas_enabled():
+        pass  # the probe's verdict says why
+    elif not pctx.single_device():
+        GATE.took("xla (kernel gated off a multi-device mesh)")
+    elif not fits:
+        GATE.took(
+            f"xla (table {table.shape[0]}x{table.shape[1]} {table.dtype} "
+            "is outside the kernel's f32 VMEM budget)"
+        )
+    else:
+        GATE.took(_probe.active())
         flat_ids = ids.reshape(-1, 4).astype(jnp.int32)
         n = flat_ids.shape[0]
         pad = (-n) % TOKEN_BLOCK

@@ -138,6 +138,15 @@ def ring_attention(
     # falls back to dense when the layout doesn't divide.
     if flash and (B_g % n_data or H_g % n_model):
         flash = False  # indivisible layout: dense partitions cleanly
+    from ..ops import flash_attention as fa
+    from ..ops.probe import active
+
+    if fa.flash_attention_enabled():  # armed: the status says what ran
+        fa.GATE.took(
+            active(fa._INTERPRET, "ring attention blocks") if flash else
+            "xla (ring attention: dense blocks — a block past the kernel's "
+            "VMEM budget, or a layout that does not divide the mesh)"
+        )
     sm_mesh, manual = manual_region(
         mesh, mesh.axis_names if flash else (AXIS,)
     )

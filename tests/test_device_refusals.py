@@ -106,14 +106,21 @@ KERNELS = {
 }
 
 
-@pytest.fixture
-def fake_tpu(monkeypatch):
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+def _fresh_gates(monkeypatch):
+    """Every kernel unprobed, nothing traced — put back afterwards."""
     for mod in (fa, pk, fu):
-        monkeypatch.setattr(mod, "_PROBED", None)
+        monkeypatch.setattr(mod.GATE, "armed", None)
+        monkeypatch.setattr(mod.GATE, "verdict", mod.GATE.verdict)
+        monkeypatch.setattr(mod.GATE, "paths", [])
     monkeypatch.setattr(i8, "_PROBE_CACHE", {})
     for _, _, _, env in KERNELS.values():
         monkeypatch.delenv(env, raising=False)
+
+
+@pytest.fixture
+def fake_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _fresh_gates(monkeypatch)
 
 
 @pytest.mark.parametrize("kernel", sorted(KERNELS))
@@ -137,11 +144,27 @@ def test_probe_that_disagrees_with_its_reference_on_tpu_raises(fake_tpu, monkeyp
         pk.pallas_enabled()
 
 
+def test_info_probe_reports_a_failed_kernel_in_the_compilers_words(
+    fake_tpu, monkeypatch
+):
+    """``info --probe`` catches the probe's error to show the whole
+    installation — and then must print the failure, not "not probed". On
+    this CPU no kernel compiles for the faked tpu. (The int8 row is asked
+    about the device JAX found, the cpu; its raise is tested above.)"""
+    from spacy_ray_tpu import cli
+
+    monkeypatch.setattr(fa, "_fwd_raw", _refuse)
+    monkeypatch.setattr(devices, "enable_compile_cache", lambda: None)
+    rows = dict(cli._probe_rows())
+    for key in ("flash_attention", "hash_embed", "fused_kernel"):
+        assert "FAILED (" in rows[key] and "did not compile on tpu" in rows[key], rows
+        assert "not probed" not in rows[key]
+    assert "Mosaic says no: block shape (1, 256)" in rows["flash_attention"]
+
+
 def test_probe_off_tpu_is_off_with_its_reason(monkeypatch):
     """Off a TPU nothing changes: auto-off, and the status says why."""
-    monkeypatch.setattr(fa, "_PROBED", None)
-    monkeypatch.setattr(fa, "_STATUS", fa._STATUS)  # restored afterwards
-    monkeypatch.delenv("SRT_PALLAS_ATTN", raising=False)
+    _fresh_gates(monkeypatch)
     assert fa.flash_attention_enabled() is False
     assert fa.flash_attention_status() == (
         "off (auto-off on cpu; SRT_PALLAS_ATTN=1 forces it)"
@@ -152,8 +175,7 @@ def test_probe_runs_eagerly_from_inside_a_trace(monkeypatch):
     """The first caller is the train step's trace. The probe must step out
     of it: run inside, its arrays are tracers, its comparison cannot be
     read, and (before this was fixed) every probe failed there — silently."""
-    monkeypatch.setattr(fa, "_PROBED", None)
-    monkeypatch.setattr(fa, "_STATUS", fa._STATUS)  # restored afterwards
+    _fresh_gates(monkeypatch)
     monkeypatch.setattr(fa, "_INTERPRET", True)
     monkeypatch.setenv("SRT_PALLAS_ATTN", "1")
     import jax.numpy as jnp
@@ -161,8 +183,111 @@ def test_probe_runs_eagerly_from_inside_a_trace(monkeypatch):
     q = jnp.ones((1, 128, 1, 64), jnp.float32)
     mask = jnp.ones((1, 128), bool)
     jax.jit(lambda q, m: fa.attention(q, q, q, m))(q, mask)
-    assert fa._PROBED is True, fa.flash_attention_status()
+    assert fa.GATE.armed is True, fa.flash_attention_status()
     assert fa.flash_attention_status() == "active (pallas interpret-mode)"
+
+
+# ----------------------------------------------------------------------
+# the status is the path the program took, not only the probe's verdict
+# ----------------------------------------------------------------------
+
+
+@pytest.fixture
+def armed(monkeypatch):
+    """Kernels as a TPU run has them after a passed probe."""
+    _fresh_gates(monkeypatch)
+    for mod in (fa, pk):
+        monkeypatch.setattr(mod.GATE, "armed", True)
+        monkeypatch.setattr(mod.GATE, "verdict", "active (pallas)")
+    monkeypatch.setattr(fa, "_INTERPRET", True)
+
+
+def _attend(B=2, T=128, H=2):
+    import jax.numpy as jnp
+
+    q = jax.ShapeDtypeStruct((B, T, H, 64), jnp.bfloat16)
+    jax.eval_shape(fa.attention, q, q, q, jax.ShapeDtypeStruct((B, T), bool))
+
+
+def test_flash_status_names_the_kernel_where_it_ran(armed):
+    assert fa.flash_attention_status() == "active (pallas)"  # the verdict
+    _attend()
+    assert fa.flash_attention_status() == "active (pallas interpret-mode)"
+
+
+def test_flash_status_says_so_when_an_armed_kernel_gives_way(armed):
+    """Past the VMEM gate, and on a mesh the layout does not divide,
+    attention() falls back to XLA: the run's record must not go on saying
+    "active (pallas)"."""
+    from spacy_ray_tpu.parallel import context as pctx
+    from spacy_ray_tpu.parallel.mesh import build_mesh
+
+    _attend(T=8192)
+    assert fa.flash_attention_status() == (
+        "xla (T=8192 is past the kernel's VMEM budget)"
+    )
+    with pctx.use_mesh(build_mesh(n_data=4)):
+        _attend(B=4)
+        _attend(B=3)
+    assert fa.flash_attention_status().split("; ")[1:] == [
+        "active (pallas interpret-mode, per shard in a shard_map)",
+        "xla (batch 3 x heads 2 does not divide the mesh {'data': 4})",
+    ]
+
+
+def test_hash_embed_status_knows_the_mesh(armed, monkeypatch):
+    """``train sm.cfg --n-workers 4``: the kernel is gated off a
+    multi-device mesh, and the status used to say "not probed" or
+    "active (pallas)" all the same."""
+    import jax.numpy as jnp
+
+    from spacy_ray_tpu.parallel import context as pctx
+    from spacy_ray_tpu.parallel.mesh import build_mesh
+
+    table = jax.ShapeDtypeStruct((2000, 96), jnp.float32)
+    ids = jax.ShapeDtypeStruct((64, 4), jnp.int32)
+    with pctx.use_mesh(build_mesh(n_data=4)):
+        jax.eval_shape(pk.hash_embed_lookup, table, ids)
+    assert pk.hash_embed_status() == "xla (kernel gated off a multi-device mesh)"
+    big = jax.ShapeDtypeStruct((50000, 96), jnp.float32)
+    jax.eval_shape(pk.hash_embed_lookup, big, ids)
+    assert pk.hash_embed_status().split("; ")[1] == (
+        "xla (table 50000x96 float32 is outside the kernel's f32 VMEM budget)"
+    )
+
+
+def test_runtime_report_gives_the_pipelines_own_compute_dtype():
+    """Not what "auto" would resolve to: what this pipeline's trunk was
+    configured with, resolved — and nothing at all for a CNN pipeline."""
+    from types import SimpleNamespace
+
+    from spacy_ray_tpu.models.transformer import TransformerEncoder
+
+    def nlp_with(*models):
+        return SimpleNamespace(components={
+            str(i): SimpleNamespace(model=m) for i, m in enumerate(models)
+        })
+
+    trunk = lambda dtype: TransformerEncoder(  # noqa: E731
+        width=32, depth=1, n_heads=2, embed_size=50, compute_dtype=dtype
+    )
+    report = devices.runtime_report(nlp_with(trunk("bfloat16")))
+    assert report["compute_dtype"] == "bfloat16"  # "auto" is float32 here
+    assert devices.runtime_report(nlp_with(trunk("auto")))["compute_dtype"] == "float32"
+    assert devices.runtime_report(nlp_with())["compute_dtype"] == (
+        "n/a (no transformer trunk)"
+    )
+    assert "compute_dtype" not in devices.runtime_report()
+
+
+def test_verbose_still_reaches_the_packages_loggers():
+    """The native build's warning is let through by ITS logger's level;
+    the package logger keeps none of its own, so ``--verbose`` (root at
+    DEBUG) still shows the serving, fleet and residency loggers' INFO."""
+    assert cli_main(["info"]) == 0
+    assert logging.getLogger("spacy_ray_tpu").level == logging.NOTSET
+    assert logging.getLogger("spacy_ray_tpu.native").level == logging.WARNING
+    assert logging.getLogger("spacy_ray_tpu.serving").level == logging.NOTSET
 
 
 # ----------------------------------------------------------------------

@@ -14,7 +14,7 @@ from spacy_ray_tpu.parallel.mesh import build_mesh
 @pytest.fixture(autouse=True)
 def _force_flash(monkeypatch):
     monkeypatch.setattr(fa, "_INTERPRET", True)
-    monkeypatch.setattr(fa, "_PROBED", True)  # pretend the probe passed
+    monkeypatch.setattr(fa.GATE, "armed", True)  # pretend the probe passed
 
 
 def _mk(B=4, T=128, H=4, Dh=32, seed=0):

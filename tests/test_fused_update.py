@@ -142,7 +142,7 @@ def test_fused_status_labels():
     fused = O.fuse_optimizer(tx)
     # CPU: the kernel probe is off -> the label must say the path is XLA
     assert fu.fused_status(fused).startswith("active (")
-    assert "pallas" not in fu.fused_status(fused) or fu._PROBED is True
+    assert "pallas" not in fu.fused_status(fused) or fu.GATE.armed is True
     # multi-device mesh: the kernel gate (single_device) keeps pallas off,
     # so the label must downgrade even when the probe passed — a multi-chip
     # bench record must never claim "active (pallas)" (honest labeling)
@@ -151,14 +151,14 @@ def test_fused_status_labels():
     from spacy_ray_tpu.parallel.mesh import build_mesh
 
     mesh = build_mesh(n_data=len(jax.devices()))
-    old = fu._PROBED
-    fu._PROBED = True
+    old = fu.GATE.armed
+    fu.GATE.armed = True
     try:
         if int(mesh.size) > 1:
             assert "pallas" not in fu.fused_status(fused, mesh)
         assert fu.fused_status(fused, None) == "active (pallas)"
     finally:
-        fu._PROBED = old
+        fu.GATE.armed = old
 
 
 # ------------------------------------------------------------------ shadow

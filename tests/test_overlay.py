@@ -107,7 +107,7 @@ def test_int8_cpu_auto_off_unless_forced(monkeypatch):
     _PROBE_CACHE.clear()
     resolved, reason = resolve_precision("int8", "cpu")
     assert resolved == "f32"
-    assert "probe refused" in reason and "OFF on cpu" in reason
+    assert "probe refused" in reason and "auto-off on cpu" in reason
     # requesting the tpu resolution from a CPU host must fail the
     # COMPILED-kernel probe, never pass via the interpret fallback
     resolved, reason = resolve_precision("int8", "tpu")
@@ -116,7 +116,7 @@ def test_int8_cpu_auto_off_unless_forced(monkeypatch):
     _PROBE_CACHE.clear()
     resolved, reason = resolve_precision("int8", "cpu")
     assert resolved == "int8"
-    assert "active (pallas interpret-mode, forced)" in reason
+    assert "active (pallas interpret-mode)" in reason
     _PROBE_CACHE.clear()
 
 
@@ -236,7 +236,7 @@ def test_int8_overlay_output_within_tolerance(trf_nlp, forced_int8):
     ov = build_serving_overlay(trf_nlp, "int8")
     assert ov.resolved == "int8"
     assert ov.n_overlaid == 8  # 2 layers x 4 dense matmul weights
-    assert "active (pallas interpret-mode, forced)" in ov.label
+    assert "active (pallas interpret-mode)" in ov.label
     out_i8 = fwd(ov.params, batch["tokens"])
     logits_f32 = np.asarray(out_f32["tagger"].X)
     logits_i8 = np.asarray(out_i8["tagger"].X)

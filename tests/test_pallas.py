@@ -79,7 +79,7 @@ def test_onehot_lookup_matches_gather(monkeypatch):
     ids = _jax.random.randint(_jax.random.PRNGKey(1), (10, 3, 4), 0, 64)
     ids = ids.at[0, 0].set(jnp.array([5, 5, 5, 9]))  # repeats
 
-    monkeypatch.setattr(PK, "_PROBED", False)
+    monkeypatch.setattr(PK.GATE, "armed", False)
     monkeypatch.setattr(PK.jax, "default_backend", lambda: "tpu")
     got = PK.hash_embed_lookup(table, ids)
     want = PK._reference_lookup(table, ids)

@@ -81,12 +81,14 @@ SINGLE_CHIP_CASES = [
     ("fused_update_20000x768",
      lambda p, g, m, v, s: fu._kernel_leaf(p, g, m, v, s, _HYPER, interpret=False),
      [((20000, 768), jnp.float32)] * 4 + [((6,), jnp.float32)]),
-    ("int8_matmul_512x768x3072",
+] + [
+    # bf16 activations are what a trunk hands over under compute_dtype
+    # "auto" on a TPU; f32 ones take the kernel's HIGHEST-precision dot
+    (f"int8_matmul_512x{K}x{N}_{jnp.dtype(dtype).name}",
      lambda x, q, s: i8._int8_matmul_raw(x, q, s, interpret=False),
-     [((512, 768), jnp.float32), ((768, 3072), jnp.int8), ((3072,), jnp.float32)]),
-    ("int8_matmul_512x3072x768",
-     lambda x, q, s: i8._int8_matmul_raw(x, q, s, interpret=False),
-     [((512, 3072), jnp.float32), ((3072, 768), jnp.int8), ((768,), jnp.float32)]),
+     [((512, K), dtype), ((K, N), jnp.int8), ((N,), jnp.float32)])
+    for K, N in ((768, 3072), (3072, 768))
+    for dtype in (jnp.bfloat16, jnp.float32)
 ]
 
 
@@ -106,7 +108,7 @@ def test_kernel_compiles_for_v5e(topo, fn, operands):
 def flash_armed(monkeypatch):
     # the gate asks jax.default_backend(), which is the CPU here: steer it
     # in the test, the program has no option for this
-    monkeypatch.setattr(fa, "_PROBED", True)
+    monkeypatch.setattr(fa.GATE, "armed", True)
 
 
 @pytest.mark.parametrize("mode", ["fwd", "grad"])
