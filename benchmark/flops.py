@@ -1,0 +1,69 @@
+"""Operations the algorithm needs, from the configuration's shapes: the
+numerator of ``model_flops_util``. Kept with the benchmark so that no change
+to the program can move it. One multiply-add is two operations.
+
+What counts: the matrix products of the forward pass for one REAL word, and
+twice that again for the backward pass. What does not: padding, recomputation
+under remat, the one-hot products that stand in for gathers, elementwise work,
+the optimizer. So the utilization it gives is the share of the chip's peak
+spent on work the model needs, and padding and remat show as a LOW share.
+
+``shapes`` is the ``shapes`` object of ``benchmark/configs/<config>.json``;
+``context_words`` is how many words a word attends to: the words of its own
+document, so the mean document length weighted by words (sum L^2 / sum L),
+which the harness counts from the masks of the window's batches.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict
+
+
+def embed_flops(s: Dict[str, Any]) -> float:
+    """MultiHashEmbed's mix: concat of the tables' widths -> pieces x width."""
+    d = s["width"]
+    return 2.0 * (s["embed_tables"] * d) * (s["embed_mix_pieces"] * d)
+
+
+def trunk_forward_flops_per_word(s: Dict[str, Any], context_words: float) -> float:
+    d = s["width"]
+    if s["trunk"] == "transformer":
+        ffn = s["ffn_mult"] * d
+        # qkv 3d^2, out d^2, two ffn products d*ffn each
+        per_layer = 2.0 * (4 * d * d + 2 * d * ffn)
+        # scores and weighted sum against the words of the same document
+        per_layer += 2.0 * 2 * context_words * d
+        return embed_flops(s) + s["depth"] * per_layer
+    if s["trunk"] == "cnn":
+        window = 2 * s["window_size"] + 1
+        per_layer = 2.0 * (window * d) * (s["maxout_pieces"] * d)
+        return embed_flops(s) + s["depth"] * per_layer
+    raise ValueError(f"no operation count for trunk {s['trunk']!r}")
+
+
+def heads_forward_flops_per_word(s: Dict[str, Any]) -> float:
+    d = s["width"]
+    total = 0.0
+    for head in s["heads"]:
+        if head["kind"] == "tagger":
+            total += 2.0 * d * head["n_out"]
+        elif head["kind"] == "transition":
+            # one maxout over the state's feature tokens, one output layer,
+            # once per transition; `states_per_word` transitions per word
+            hidden = head["hidden_width"] * head["maxout_pieces"]
+            per_state = 2.0 * (head["n_feats"] * d * hidden
+                               + head["hidden_width"] * head["n_out"])
+            total += head["states_per_word"] * per_state
+        else:
+            raise ValueError(f"no operation count for head {head['kind']!r}")
+    return total
+
+
+def forward_flops_per_word(config_file: Dict[str, Any], context_words: float) -> float:
+    s = config_file["shapes"]
+    return trunk_forward_flops_per_word(s, context_words) + heads_forward_flops_per_word(s)
+
+
+def train_flops_per_word(config_file: Dict[str, Any], context_words: float) -> float:
+    """Forward, and a backward that costs two forwards."""
+    return 3.0 * forward_flops_per_word(config_file, context_words)
