@@ -197,6 +197,21 @@ def chain(*layers: Model, name: str = "chain") -> Model:
     return Model(name, init_fn, apply_fn, dims=dims, layers=list(layers))
 
 
+def scoped(model: Model, scope: str) -> Model:
+    """``model`` with ``jax.named_scope(scope)`` round its apply: the
+    operations it traces (and their transposes in the backward pass) carry
+    ``scope`` in their metadata, which is how a device trace is read by
+    layer (``spacy_ray_tpu/names.py``). No operation is added."""
+    inner = model.apply_fn
+
+    def apply_fn(params: Params, x: Any, ctx: Context) -> Any:
+        with jax.named_scope(scope):
+            return inner(params, x, ctx)
+
+    model.apply_fn = apply_fn
+    return model
+
+
 def residual(layer: Model, name: str = "residual") -> Model:
     def init_fn(rng: jax.Array) -> Params:
         return {"inner": layer.init(rng)}

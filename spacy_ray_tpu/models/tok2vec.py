@@ -16,9 +16,10 @@ from __future__ import annotations
 
 from typing import List, Optional
 
+from ..names import SCOPE_EMBED, SCOPE_TRUNK
 from ..registry import registry
 from ..ops.hashing import hash_string_u64
-from .core import Model, chain, residual
+from .core import Model, chain, residual, scoped
 from .layers import (
     ConcatPadded,
     Dropout,
@@ -83,7 +84,7 @@ def MultiHashEmbed(
         name="multi_hash_embed",
     )
     mix.dims.update({"nO": width})
-    return mix
+    return scoped(mix, SCOPE_EMBED)
 
 
 @registry.architectures("spacy.MultiHashEmbed.v1")
@@ -134,7 +135,7 @@ def MaxoutWindowEncoder(
     layers = [block(i) for i in range(depth)]
     enc = chain(*layers, name="maxout_window_encoder")
     enc.dims.update({"nI": width, "nO": width})
-    return enc
+    return scoped(enc, SCOPE_TRUNK)
 
 
 @registry.architectures("spacy.TorchBiLSTMEncoder.v1")

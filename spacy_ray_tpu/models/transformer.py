@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..names import SCOPE_TRUNK
 from ..registry import registry
 from ..ops import ops as O
 from ..types import Padded, TokenBatch
@@ -595,6 +596,10 @@ def TransformerEncoder(
 
     def apply_fn(params, batch: TokenBatch, ctx: Context) -> Padded:
         emb: Padded = embed.apply(params["embed"], batch, ctx)
+        with jax.named_scope(SCOPE_TRUNK):
+            return encode(params, emb, ctx)
+
+    def encode(params, emb: Padded, ctx: Context) -> Padded:
         T = emb.X.shape[1]
         if T > max_len:
             import warnings

@@ -1,0 +1,69 @@
+"""Every name the program writes into a profiler trace, in one place.
+
+A trace is read by name: the benchmark's readers, ``PERF.md`` section 3 and
+whoever opens a ``train --profile`` trace in Perfetto look for exactly these
+strings, so they are constants here and nowhere a literal. Changing one is a
+change to what a metric reads.
+
+Host side: one span call (``PipelineStats.timer`` / ``TraceBuffer.span``)
+records a stage's seconds, a Chrome-trace span when telemetry is on, and a
+``jax.profiler.TraceAnnotation`` named ``SPAN_PREFIX + key`` on the thread
+that did the work. ``/`` separates a parent from its child. Every span is per
+batch or per micro-batch, never per document.
+
+Device side: ``jax.named_scope`` names (metadata of the compiled operations,
+no operation is added), the ``name=`` of each pallas kernel, and the function
+names of the jitted programs (an XLA module is ``jit_<name>``).
+"""
+
+from __future__ import annotations
+
+# ---- host spans (keys of PipelineStats.seconds) ----------------------------
+SPAN_PREFIX = "srt:"
+
+READ = "read"  # corpus + batcher: one update's raw batches
+COLLATE = "collate"  # collate_group: raw batches -> stacked host arrays
+TRANSFER = "transfer"  # place_batch: device_put of tokens and targets
+QUEUE_WAIT = "queue_wait"  # the loop waiting for its next group
+# children of COLLATE; a cache hit has none (it is COLLATE's self time)
+COLLATE_FEATURES = "collate/features"  # vocab.featurize + attr_keys/mask/vector_rows
+COLLATE_TARGETS = "collate/targets"  # the loop over head_names()
+COLLATE_STACK = "collate/stack"  # np.stack of the micro-batches (accumulate_gradient > 1)
+DEVICE_CALL = "device_call"  # child of a head's span: a call that leaves the host
+# the loop thread, per dispatch: from the return of next(groups) to the next
+# call of it, evaluation and checkpoint excluded
+LOOP_HOST = "loop_host"
+LOOP_DISPATCH = "loop_host/dispatch"  # rng split, k > 1 stacking, the update call: the enqueue
+
+
+def collate_head(name: str) -> str:
+    """The span of one head's ``make_targets``: ``collate/targets/<name>``."""
+    return f"{COLLATE_TARGETS}/{name}"
+
+
+# ---- device scopes ------------------------------------------------------------
+SCOPE_EMBED = "embed"  # hash embeddings + their mix (CNN and transformer alike)
+SCOPE_TRUNK = "trunk"  # the encoder: CNN window layers or the transformer stack
+SCOPE_LOSS = "loss"  # the sum of the heads' losses and the auxiliary terms
+SCOPE_UPDATE = "update"  # optimizer apply (fused or not), shadow refresh, grad norm
+SCOPE_GRAD_ACCUM = "grad_accum"  # the scan over micro-batches
+
+
+def head_scope(name: str) -> str:
+    """One head's forward and loss: ``head/<component name>``."""
+    return f"head/{name}"
+
+
+# ---- pallas kernels -------------------------------------------------------------
+KERNEL_FLASH_FWD = "srt_flash_fwd"
+KERNEL_FLASH_BWD = "srt_flash_bwd"
+KERNEL_FUSED_ADAM = "srt_fused_adam"
+KERNEL_HASH_EMBED = "srt_hash_embed"
+KERNEL_INT8_MATMUL = "srt_int8_matmul"
+
+# ---- jitted programs --------------------------------------------------------------
+PROGRAM_TRAIN_STEP = "srt_train_step"
+PROGRAM_TRAIN_STEP_MULTI = "srt_train_step_multi"  # steps_per_dispatch > 1
+PROGRAM_EVAL_FORWARD = "srt_eval_forward"
+PROGRAM_UPDATE_ONLY = "srt_update_only"  # bench.py's optimizer-only program
+PROGRAM_SHARD_APPLY = "srt_shard_apply"  # the trainer fleet's owner-shard apply

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..names import KERNEL_FLASH_BWD, KERNEL_FLASH_FWD
 from . import probe as _probe
 
 BQ = 128  # query block (MXU-aligned)
@@ -164,6 +165,7 @@ def _fwd_raw(q, k, v, bias, *, scale, interpret=None):
         in_specs=[qspec, kvspec, kvspec, bspec],
         out_specs=(qspec, lspec),
         interpret=interpret,
+        name=KERNEL_FLASH_FWD,
     )(q, k, v, bias[:, None, :])
     return o, lse[:, :, 0, :]
 
@@ -183,6 +185,7 @@ def _bwd_raw(q, k, v, bias, do, o, lse, dlse, *, scale, interpret=None):
         in_specs=[qspec, kvspec, kvspec, bspec, qspec, qspec, lspec, lspec],
         out_specs=(qspec, kvspec, kvspec),
         interpret=interpret,
+        name=KERNEL_FLASH_BWD,
     )(q, k, v, bias[:, None, :], do, o, lse[:, :, None, :], dlse[:, :, None, :])
 
 

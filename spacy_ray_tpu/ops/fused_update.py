@@ -39,6 +39,7 @@ import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..names import KERNEL_FUSED_ADAM
 from . import probe as _probe
 
 # kernel block: BR rows x 128 lanes of f32 per grid step (1 MB/operand —
@@ -183,6 +184,7 @@ def _kernel_leaf(p, g, m, v, scal, hyper: FusedHyper, interpret=None):
         # HBM, the same no-new-allocation contract the donated XLA path has
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
+        name=KERNEL_FUSED_ADAM,
     )(scal, prep(p), prep(g), prep(m), prep(v))
 
     def unprep(x):

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..names import KERNEL_INT8_MATMUL
 from . import probe as _probe
 
 __all__ = [
@@ -218,6 +219,7 @@ def _int8_matmul_raw(
         out_specs=pl.BlockSpec((BM, BN), lambda i, j: (i, j),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
+        name=KERNEL_INT8_MATMUL,
     )(xp, wp, sp)
     return out[:M, :N]
 

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..names import KERNEL_HASH_EMBED
 from . import probe as _probe
 
 TOKEN_BLOCK = 256
@@ -89,6 +90,7 @@ def _pallas_lookup_raw(
             (TOKEN_BLOCK, D), lambda i: (i, 0), memory_space=pltpu.VMEM
         ),
         interpret=interpret,
+        name=KERNEL_HASH_EMBED,
     )(ids, table)
 
 

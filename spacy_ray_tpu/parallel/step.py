@@ -50,6 +50,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import names
 from . import context as pctx
 from .mesh import replicated, zero1_spec
 
@@ -293,59 +294,61 @@ def make_train_step(
                 acc_grads = jax.tree_util.tree_map(jnp.add, acc_grads, grads)
                 return (acc_grads, rng), (loss, metrics)
 
-            zero_grads = jax.tree_util.tree_map(jnp.zeros_like, params)
-            (grads, _), (losses, metricses) = jax.lax.scan(
-                body, (zero_grads, rng), (tokens, targets)
-            )
-            grads = jax.tree_util.tree_map(lambda g: g / accum, grads)
-            loss = jnp.mean(losses)
-            metrics = jax.tree_util.tree_map(jnp.mean, metricses)
-        if pin_grads:
-            # pin the all-reduced grads REPLICATED and fence them: XLA must
-            # not rewrite the gradient all-reduce into a reduce-scatter
-            # (a different accumulation order drifts last-ulp values), and
-            # any global reduction inside the optimizer (grad-clip norm)
-            # then sees the identical full arrays — the two properties the
-            # full==replicated equality test stands on
-            grads = jax.lax.optimization_barrier(_to_replicated(grads))
-        upd_params = _to_owner_shards(params) if full_sharded else params
-        if applies_updates:
-            # fused path (ops/fused_update.py): the whole optimizer chain
-            # plus apply_updates in one traversal
-            new_params, new_opt_state = tx.update(grads, opt_state, upd_params)
-        else:
-            updates, new_opt_state = tx.update(grads, opt_state, upd_params)
-            if full_sharded:
-                updates = _to_owner_shards(updates)
-            new_params = optax.apply_updates(upd_params, updates)
-        if full_sharded:
-            # shard-local results; the shadow refresh happens PRE-allgather
-            # (each rank casts only its owned shard, and the gather moves
-            # bf16 bytes); then the ONE allgather returns the updated
-            # params to the replicated data-parallel layout
-            new_params = _to_owner_shards(new_params)
-            new_shadow = None
-            if shadow_t is not None:
-                new_shadow = _to_replicated(
-                    _to_owner_shards(refresh_shadow(new_params, shadow_t))
+            with jax.named_scope(names.SCOPE_GRAD_ACCUM):
+                zero_grads = jax.tree_util.tree_map(jnp.zeros_like, params)
+                (grads, _), (losses, metricses) = jax.lax.scan(
+                    body, (zero_grads, rng), (tokens, targets)
                 )
-            new_params = _to_replicated(new_params)
-        else:
-            new_shadow = (
-                refresh_shadow(new_params, shadow_t)
-                if shadow_t is not None
-                else None
-            )
-        if pin_grads:
-            # same partitioner-proof reduction the fused clip uses, so the
-            # reported norm is identical across modes and mesh shapes (the
-            # free-floating optax.global_norm compiles to a different
-            # accumulation order per program — ops/fused_update.py)
-            from ..ops.fused_update import stable_global_norm
+                grads = jax.tree_util.tree_map(lambda g: g / accum, grads)
+                loss = jnp.mean(losses)
+                metrics = jax.tree_util.tree_map(jnp.mean, metricses)
+        with jax.named_scope(names.SCOPE_UPDATE):
+            if pin_grads:
+                # pin the all-reduced grads REPLICATED and fence them: XLA must
+                # not rewrite the gradient all-reduce into a reduce-scatter
+                # (a different accumulation order drifts last-ulp values), and
+                # any global reduction inside the optimizer (grad-clip norm)
+                # then sees the identical full arrays — the two properties the
+                # full==replicated equality test stands on
+                grads = jax.lax.optimization_barrier(_to_replicated(grads))
+            upd_params = _to_owner_shards(params) if full_sharded else params
+            if applies_updates:
+                # fused path (ops/fused_update.py): the whole optimizer chain
+                # plus apply_updates in one traversal
+                new_params, new_opt_state = tx.update(grads, opt_state, upd_params)
+            else:
+                updates, new_opt_state = tx.update(grads, opt_state, upd_params)
+                if full_sharded:
+                    updates = _to_owner_shards(updates)
+                new_params = optax.apply_updates(upd_params, updates)
+            if full_sharded:
+                # shard-local results; the shadow refresh happens PRE-allgather
+                # (each rank casts only its owned shard, and the gather moves
+                # bf16 bytes); then the ONE allgather returns the updated
+                # params to the replicated data-parallel layout
+                new_params = _to_owner_shards(new_params)
+                new_shadow = None
+                if shadow_t is not None:
+                    new_shadow = _to_replicated(
+                        _to_owner_shards(refresh_shadow(new_params, shadow_t))
+                    )
+                new_params = _to_replicated(new_params)
+            else:
+                new_shadow = (
+                    refresh_shadow(new_params, shadow_t)
+                    if shadow_t is not None
+                    else None
+                )
+            if pin_grads:
+                # same partitioner-proof reduction the fused clip uses, so the
+                # reported norm is identical across modes and mesh shapes (the
+                # free-floating optax.global_norm compiles to a different
+                # accumulation order per program — ops/fused_update.py)
+                from ..ops.fused_update import stable_global_norm
 
-            grad_norm = stable_global_norm(grads)
-        else:
-            grad_norm = optax.global_norm(grads)
+                grad_norm = stable_global_norm(grads)
+            else:
+                grad_norm = optax.global_norm(grads)
         metrics = dict(metrics)
         metrics["grad_norm"] = grad_norm
         return new_params, new_opt_state, new_shadow, loss, metrics
@@ -422,6 +425,12 @@ def make_train_step(
     if donate:
         jit_kwargs["donate_argnums"] = donate_argnums
 
+    # a fixed function name is a fixed XLA module name (jit_<name>): a trace
+    # tells the programs apart, and a refactor does not rename them
+    update.__name__ = (
+        names.PROGRAM_TRAIN_STEP_MULTI if multi_dispatch
+        else names.PROGRAM_TRAIN_STEP
+    )
     jitted = jax.jit(update, **jit_kwargs)
 
     def run(*args):
@@ -508,6 +517,7 @@ def make_update_only(
     # constraints fully pin the (sharded) output placement
     if donate:
         jit_kwargs["donate_argnums"] = (0, 1)
+    update.__name__ = names.PROGRAM_UPDATE_ONLY
     jitted = jax.jit(update, **jit_kwargs)
 
     def run(*args):
@@ -554,6 +564,7 @@ def make_shard_apply(tx: Any, *, donate: bool = True) -> Callable:
     jit_kwargs: Dict[str, Any] = {}
     if donate:
         jit_kwargs["donate_argnums"] = (0, 1)
+    update.__name__ = names.PROGRAM_SHARD_APPLY
     jitted = jax.jit(update, **jit_kwargs)
 
     def run(params, opt_state, grads):

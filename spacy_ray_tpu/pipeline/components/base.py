@@ -93,8 +93,16 @@ class Component:
         return prune_empty(self.model.init(rng))
 
     # --------------------------- collate -----------------------------
-    def make_targets(self, examples: List[Example], B: int, T: int) -> Dict[str, np.ndarray]:
-        """Lower gold annotations to padded arrays for the device loss."""
+    def make_targets(
+        self, examples: List[Example], B: int, T: int, span: Any = None
+    ) -> Dict[str, np.ndarray]:
+        """Lower gold annotations to padded arrays for the device loss.
+
+        ``span`` is the caller's open span of this head
+        (``PipelineStats.timer``; ``NO_SPAN`` where nothing is recorded).
+        An implementation that leaves the host (an eager ``jnp`` call, a
+        copy back from the device) wraps that call in
+        ``span.child(names.DEVICE_CALL)``; pure host work ignores it."""
         return {}
 
     # ---------------------------- device -----------------------------

@@ -147,7 +147,9 @@ class EditTreeLemmatizerComponent(TaggerComponent):
             self._trees_for = self.labels
         return self._trees
 
-    def make_targets(self, examples: List[Example], B: int, T: int) -> Dict[str, np.ndarray]:
+    def make_targets(
+        self, examples: List[Example], B: int, T: int, span: Any = None
+    ) -> Dict[str, np.ndarray]:
         label_ids = {label: i for i, label in enumerate(self.labels)}
         tags = np.zeros((B, T), dtype=np.int32)
         mask = np.zeros((B, T), dtype=bool)

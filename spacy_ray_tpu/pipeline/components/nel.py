@@ -119,7 +119,9 @@ class EntityLinkerComponent(Component):
             for s in eg.predicted.ents
         ]
 
-    def make_targets(self, examples: List[Example], B: int, T: int) -> Dict[str, np.ndarray]:
+    def make_targets(
+        self, examples: List[Example], B: int, T: int, span: Any = None
+    ) -> Dict[str, np.ndarray]:
         assert self.kb is not None
         K = self.n_candidates
         D = self.kb.entity_vector_length

@@ -22,11 +22,13 @@ from typing import Any, Dict, List
 import numpy as np
 import jax.numpy as jnp
 
+from ... import names
 from ...registry import registry
 from ...models.core import Context, Params
 from ...models.parser import NER_N_FEATURES, decode_biluo, decode_biluo_viterbi, ner_window_features
 from ...ops import ops as O
 from ...pipeline.doc import Doc, Example, Span
+from ...training.collate_pool import NO_SPAN
 from ...types import Padded
 from .base import Component
 
@@ -80,7 +82,9 @@ class NERComponent(Component):
         self.listens = bool(model.meta.get("has_listener"))
         return model
 
-    def make_targets(self, examples: List[Example], B: int, Tlen: int) -> Dict[str, np.ndarray]:
+    def make_targets(
+        self, examples: List[Example], B: int, Tlen: int, span: Any = NO_SPAN
+    ) -> Dict[str, np.ndarray]:
         label_ids = {label: i for i, label in enumerate(self.labels)}
         actions = np.zeros((B, Tlen), dtype=np.int32)
         mask = np.zeros((B, Tlen), dtype=bool)
@@ -95,7 +99,10 @@ class NERComponent(Component):
                 mask[i, t] = True
         while len(lengths) < B:
             lengths.append(0)
-        feats = np.asarray(ner_window_features(Tlen, np.asarray(lengths)))
+        # eager jnp on the collate thread: it runs on the device, behind
+        # whatever the device is doing, and the copy back waits for it
+        with span.child(names.DEVICE_CALL):
+            feats = np.asarray(ner_window_features(Tlen, np.asarray(lengths)))
         return {"actions": actions, "feats": feats, "ner_mask": mask}
 
     def loss(self, params: Params, inputs: Any, targets: Dict[str, Any], ctx: Context):

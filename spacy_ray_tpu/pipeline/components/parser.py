@@ -75,7 +75,9 @@ class ParserComponent(Component):
         return model
 
     # ------------------------------------------------------------------
-    def make_targets(self, examples: List[Example], B: int, Tlen: int) -> Dict[str, np.ndarray]:
+    def make_targets(
+        self, examples: List[Example], B: int, Tlen: int, span: Any = None
+    ) -> Dict[str, np.ndarray]:
         label_ids = {label: i for i, label in enumerate(self.labels)}
         n_act = T.n_actions(len(self.labels))
         S = 2 * Tlen + 2

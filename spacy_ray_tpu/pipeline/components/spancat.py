@@ -142,7 +142,9 @@ class SpanCatComponent(Component):
         assert self.model is not None
         return self.model.meta["sizes"]
 
-    def make_targets(self, examples: List[Example], B: int, Tlen: int) -> Dict[str, np.ndarray]:
+    def make_targets(
+        self, examples: List[Example], B: int, Tlen: int, span: Any = None
+    ) -> Dict[str, np.ndarray]:
         label_ids = {label: i for i, label in enumerate(self.labels)}
         sizes = self.sizes if self.model else [1, 2, 3]
         grid = span_grid(Tlen, sizes)

@@ -30,7 +30,9 @@ class TaggerComponent(Component):
                 labels.update(t for t in eg.reference.tags if t)
         self.labels = list(labels)
 
-    def make_targets(self, examples: List[Example], B: int, T: int) -> Dict[str, np.ndarray]:
+    def make_targets(
+        self, examples: List[Example], B: int, T: int, span: Any = None
+    ) -> Dict[str, np.ndarray]:
         label_ids = {label: i for i, label in enumerate(self.labels)}
         tags = np.zeros((B, T), dtype=np.int32)
         mask = np.zeros((B, T), dtype=bool)

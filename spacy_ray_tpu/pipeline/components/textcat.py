@@ -32,7 +32,9 @@ class TextCatComponent(Component):
             labels.update(eg.reference.cats.keys())
         self.labels = list(labels)
 
-    def make_targets(self, examples: List[Example], B: int, T: int) -> Dict[str, np.ndarray]:
+    def make_targets(
+        self, examples: List[Example], B: int, T: int, span: Any = None
+    ) -> Dict[str, np.ndarray]:
         label_ids = {label: i for i, label in enumerate(self.labels)}
         cats = np.zeros((B, len(self.labels)), dtype=np.float32)
         mask = np.zeros((B,), dtype=bool)

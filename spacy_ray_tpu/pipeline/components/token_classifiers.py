@@ -47,7 +47,9 @@ class MorphologizerComponent(TaggerComponent):
                         labels.add(label)
         self.labels = list(labels)
 
-    def make_targets(self, examples: List[Example], B: int, T: int) -> Dict[str, np.ndarray]:
+    def make_targets(
+        self, examples: List[Example], B: int, T: int, span: Any = None
+    ) -> Dict[str, np.ndarray]:
         label_ids = {label: i for i, label in enumerate(self.labels)}
         tags = np.zeros((B, T), dtype=np.int32)
         mask = np.zeros((B, T), dtype=bool)
@@ -99,7 +101,9 @@ class SenterComponent(TaggerComponent):
     def finish_labels(self) -> None:
         self.labels = ["I", "S"]
 
-    def make_targets(self, examples: List[Example], B: int, T: int) -> Dict[str, np.ndarray]:
+    def make_targets(
+        self, examples: List[Example], B: int, T: int, span: Any = None
+    ) -> Dict[str, np.ndarray]:
         tags = np.zeros((B, T), dtype=np.int32)
         mask = np.zeros((B, T), dtype=bool)
         for i, eg in enumerate(examples):

@@ -25,10 +25,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import names
 from ..config import Config
 from ..models.core import Context, Params
 from ..registry import registry
 from ..training.batcher import bucket_batch_size, bucket_length, DEFAULT_LENGTH_BUCKETS
+from ..training.collate_pool import NO_SPAN, PipelineStats
 from ..types import TokenBatch
 from .components.base import Component
 from .components.tok2vec import Tok2VecComponent
@@ -338,6 +340,7 @@ class Pipeline:
         pad_batch_to: Optional[int] = None,
         pad_len_to: Optional[int] = None,
         host: bool = False,
+        stats: Optional[PipelineStats] = None,
     ) -> Dict[str, Any]:
         """Lower ragged Examples into a statically-shaped padded batch.
 
@@ -345,7 +348,46 @@ class Pipeline:
         which on CPU already commits the data to a jax buffer): the
         parallel collation pool runs this on worker threads and the
         consumer thread alone performs the ``device_put`` (see
-        training/collate_pool.py for the threading contract)."""
+        training/collate_pool.py for the threading contract).
+
+        ``stats``: the training loop's stage clocks. With them the call
+        times its parts (features, targets, each head: ``names.py``), one
+        span per batch and never per document; without (serving,
+        ``evaluate``, tests) nothing is recorded and no clock is read."""
+        timer = stats.timer if stats is not None else (lambda key: NO_SPAN)
+        with timer(names.COLLATE_FEATURES):
+            tokens, lengths, T, B = self._collate_features(
+                examples, pad_batch_to, pad_len_to, host
+            )
+        batch: Dict[str, Any] = {
+            "tokens": tokens,
+            "n_words": int(sum(min(l, T) for l in lengths)),
+            "lengths": lengths,
+        }
+        if with_targets:
+            as_array = np.asarray if host else jnp.asarray
+            targets: Dict[str, Any] = {}
+            with timer(names.COLLATE_TARGETS):
+                for name in self.head_names():
+                    with timer(names.collate_head(name)) as span:
+                        t = self.components[name].make_targets(
+                            examples, B, T, span
+                        )
+                        if t:
+                            targets[name] = {
+                                k: as_array(v) for k, v in t.items()
+                            }
+            batch["targets"] = targets
+        return batch
+
+    def _collate_features(
+        self,
+        examples: List[Example],
+        pad_batch_to: Optional[int],
+        pad_len_to: Optional[int],
+        host: bool,
+    ) -> Tuple[TokenBatch, List[int], int, int]:
+        """The token side of ``collate``: (tokens, lengths, T, B)."""
         as_array = np.asarray if host else jnp.asarray
         lengths = [len(eg) for eg in examples]
         max_len = max(lengths) if lengths else 1
@@ -384,24 +426,12 @@ class Pipeline:
                 vec_rows[i, :n] = self.vectors.rows_of(
                     examples[i].reference.words[:T]
                 )
-        batch: Dict[str, Any] = {
-            "tokens": TokenBatch(
-                attr_keys=as_array(attr_keys),
-                mask=as_array(mask),
-                vector_rows=as_array(vec_rows) if vec_rows is not None else None,
-            ),
-            "n_words": int(sum(min(l, T) for l in lengths)),
-            "lengths": lengths,
-        }
-        if with_targets:
-            targets: Dict[str, Any] = {}
-            for name in self.head_names():
-                comp = self.components[name]
-                t = comp.make_targets(examples, B, T)
-                if t:
-                    targets[name] = {k: as_array(v) for k, v in t.items()}
-            batch["targets"] = targets
-        return batch
+        tokens = TokenBatch(
+            attr_keys=as_array(attr_keys),
+            mask=as_array(mask),
+            vector_rows=as_array(vec_rows) if vec_rows is not None else None,
+        )
+        return tokens, lengths, T, B
 
     # ------------------------------------------------------------------
     # Pure loss (jit-traceable)
@@ -445,21 +475,24 @@ class Pipeline:
                 rng, sub = jax.random.split(rng)
                 # heads with an inline (non-listener) tok2vec may embed an
                 # MoE trunk themselves — give them the same aux sink
-                loss, comp_metrics = comp.loss(
-                    comp_params, inputs, targets[name],
-                    Context(train=True, rng=sub, aux_losses=aux_sink, dropout=drop),
-                )
+                with jax.named_scope(names.head_scope(name)):
+                    loss, comp_metrics = comp.loss(
+                        comp_params, inputs, targets[name],
+                        Context(train=True, rng=sub, aux_losses=aux_sink, dropout=drop),
+                    )
                 metrics[f"loss_{name}"] = loss
                 # namespace per component: shared base classes emit the same
                 # metric keys (e.g. tag_acc_batch) and would clobber
                 metrics.update({f"{name}_{k}": v for k, v in comp_metrics.items()})
-                total = total + loss
+                with jax.named_scope(names.SCOPE_LOSS):
+                    total = total + loss
             if aux_sink and (t2v_name is None or t2v_name not in frozen):
-                aux_total = jnp.float32(0.0)
-                for a in aux_sink:
-                    aux_total = aux_total + a
-                metrics["loss_aux"] = aux_total
-                total = total + aux_total
+                with jax.named_scope(names.SCOPE_LOSS):
+                    aux_total = jnp.float32(0.0)
+                    for a in aux_sink:
+                        aux_total = aux_total + a
+                    metrics["loss_aux"] = aux_total
+                    total = total + aux_total
             return total, metrics
 
         return loss_fn
@@ -489,9 +522,14 @@ class Pipeline:
                 if comp.model is None:
                     continue  # host-side components have no device forward
                 inputs = t2v_out if comp.listens else tokens
-                outputs[name] = comp.forward(params[name], inputs, Context(train=False))
+                with jax.named_scope(names.head_scope(name)):
+                    outputs[name] = comp.forward(
+                        params[name], inputs, Context(train=False)
+                    )
             return outputs
 
+        # the XLA module of every jit of this function: jit_srt_eval_forward
+        forward.__name__ = names.PROGRAM_EVAL_FORWARD
         return forward
 
     # ------------------------------------------------------------------

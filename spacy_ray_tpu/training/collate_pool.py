@@ -26,9 +26,12 @@ Three pieces, composable and individually inert when disabled:
   active (fresh Example copies every epoch would only churn it) and in
   annotating mode (targets depend on per-step predictions).
 * :class:`PipelineStats` — thread-safe per-stage timers (read /
-  collate / transfer / queue-wait) + cache counters, surfaced in the
-  training log at every eval row and stamped into bench records
-  (``bench.py --input-pipeline``).
+  collate / transfer / queue-wait, the stages inside a collate call, and
+  the loop's own host time: the keys are in ``spacy_ray_tpu/names.py``)
+  + cache counters, surfaced in the training log at every eval row and
+  stamped into bench records (``bench.py --input-pipeline``). Its
+  ``timer`` is the one span call of the training path: stage seconds, a
+  Chrome-trace span when telemetry is on, and a profiler annotation.
 """
 
 from __future__ import annotations
@@ -40,13 +43,16 @@ from collections import OrderedDict
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from ..names import COLLATE, QUEUE_WAIT, READ, SPAN_PREFIX, TRANSFER
 from .resilience import maybe_fail
 
 __all__ = [
     "OrderedPool",
     "CollateCache",
     "PipelineStats",
+    "NO_SPAN",
     "ordered_map",
     "cached_collate",
 ]
@@ -56,7 +62,29 @@ __all__ = [
 # Per-stage instrumentation
 # ----------------------------------------------------------------------
 
-STAGES = ("read", "collate", "transfer", "queue_wait")
+# the stages every snapshot carries, run or not; the timers add their own
+# keys (names.py) as they first fire
+STAGES = (READ, COLLATE, TRANSFER, QUEUE_WAIT)
+
+
+class _NoSpan:
+    """The span of a caller that records nothing (serving, ``evaluate``,
+    tests): entering it, leaving it and asking it for a child cost one
+    method call each and touch no clock."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        return None
+
+    def child(self, name: str) -> "_NoSpan":
+        return self
+
+
+NO_SPAN = _NoSpan()
 
 
 class PipelineStats:
@@ -83,6 +111,9 @@ class PipelineStats:
         # one track (the satellite fix: single-threaded runs must be
         # comparable in traces).
         self._trace: Optional[Any] = None
+        # per thread: how many timers are open on it, and the closed ones
+        # that wait for the outermost to close
+        self._open = threading.local()
 
     def attach_trace(self, trace: Any) -> None:
         self._trace = trace
@@ -90,28 +121,63 @@ class PipelineStats:
     def add(
         self, stage: str, seconds: float, n: int = 1, t0: Optional[float] = None
     ) -> None:
+        self._add_all([(stage, seconds, t0)], n)
+
+    def _add_all(
+        self, spans: List[Tuple[str, float, Optional[float]]], n: int = 1
+    ) -> None:
         with self._lock:
-            self.seconds[stage] = self.seconds.get(stage, 0.0) + seconds
-            self.counts[stage] = self.counts.get(stage, 0) + n
+            for stage, seconds, _ in spans:
+                self.seconds[stage] = self.seconds.get(stage, 0.0) + seconds
+                self.counts[stage] = self.counts.get(stage, 0) + n
         trace = self._trace
-        if trace is not None and t0 is not None:
-            trace.add_span(stage, t0, seconds, cat="pipeline")
+        if trace is not None:
+            for stage, seconds, t0 in spans:
+                if t0 is not None:
+                    trace.add_span(stage, t0, seconds, cat="pipeline")
 
     class _Timer:
-        __slots__ = ("_stats", "_stage", "_t0")
+        """One span, three sinks: the stage's seconds and count (always),
+        the attached ``TraceBuffer`` (telemetry on), and a
+        ``TraceAnnotation`` — a flag check unless a profiler trace is
+        running (the benchmark's, ``train --profile``'s, an operator's),
+        in which case the span is a host event of that trace, on its clock
+        and on the thread that did the work.
+
+        The seconds of a span that closes inside another span of its thread
+        are added when the outermost one closes, all under one lock: whoever
+        reads ``seconds`` between two instants (the benchmark's window
+        edges) finds a part only together with its whole."""
+
+        __slots__ = ("_stats", "_stage", "_t0", "_note")
 
         def __init__(self, stats: "PipelineStats", stage: str):
             self._stats = stats
             self._stage = stage
 
         def __enter__(self) -> "PipelineStats._Timer":
+            mine = self._stats._open
+            if not getattr(mine, "depth", 0):  # the outermost of its thread
+                mine.depth, mine.closed = 0, []
+            mine.depth += 1
+            self._note = TraceAnnotation(SPAN_PREFIX + self._stage)
+            self._note.__enter__()
             self._t0 = time.perf_counter()
             return self
 
         def __exit__(self, *exc: Any) -> None:
-            self._stats.add(
-                self._stage, time.perf_counter() - self._t0, t0=self._t0
-            )
+            seconds = time.perf_counter() - self._t0
+            self._note.__exit__(*exc)
+            mine = self._stats._open
+            mine.depth -= 1
+            mine.closed.append((self._stage, seconds, self._t0))
+            if mine.depth == 0:
+                self._stats._add_all(mine.closed)
+
+        def child(self, name: str) -> "PipelineStats._Timer":
+            """The timer of ``<this stage>/<name>``: a callee handed this
+            span times a part of itself without knowing its own key."""
+            return PipelineStats._Timer(self._stats, f"{self._stage}/{name}")
 
     def timer(self, stage: str) -> "PipelineStats._Timer":
         return PipelineStats._Timer(self, stage)
@@ -128,9 +194,9 @@ class PipelineStats:
         with self._lock:
             return {
                 "stage_seconds": {
-                    s: round(self.seconds.get(s, 0.0), 4) for s in STAGES
+                    s: round(v, 4) for s, v in self.seconds.items()
                 },
-                "stage_counts": {s: self.counts.get(s, 0) for s in STAGES},
+                "stage_counts": dict(self.counts),
                 "cache": {
                     "enabled": self.cache_enabled,
                     "hits": self.cache_hits,

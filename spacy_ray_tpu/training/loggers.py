@@ -141,7 +141,7 @@ def jsonl_logger(path: Optional[str] = None):
                     "epoch", "step", "words", "wps", "eval_seconds",
                     "score", "losses", "other_scores", "input_pipeline",
                     # telemetry gauge snapshot (step-time p50/p95, HBM,
-                    # compile count, MFU) when [training] metrics_dir is on
+                    # compile count) when [training] metrics_dir is on
                     "telemetry",
                 )
             }

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import names
 from ..config import Config
 from ..pipeline.doc import Example
 from ..pipeline.language import Pipeline
@@ -559,8 +560,6 @@ def train(
     # Process 0 owns the files (every rank's loop is replica-identical, so
     # rank 0's timeline IS the pod's); disabled = `tel is None` and the
     # hot loop makes ZERO telemetry calls — every use below is guarded.
-    from contextlib import nullcontext
-
     tel = None
     tel_http = None
     tel_dir = str(metrics_dir) if metrics_dir is not None else str(
@@ -580,7 +579,7 @@ def train(
             "--metrics-dir/[training] metrics_dir to enable it",
         )
     if tel_dir and jax.process_index() == 0:
-        from .telemetry import Telemetry, program_flops
+        from .telemetry import Telemetry
 
         trace_steps = T.get("trace_steps") or [0, 50]
         tel = Telemetry(
@@ -622,9 +621,10 @@ def train(
             )
 
     def _tspan(name: str, **args: Any):
-        """Span context when telemetry is on, else a free nullcontext."""
+        """Span context when telemetry is on, else the bare profiler
+        annotation (a flag check unless a profiler trace is running)."""
         if tel is None:
-            return nullcontext()
+            return jax.profiler.TraceAnnotation(names.SPAN_PREFIX + name)
         return tel.trace.span(name, cat="loop", **args)
 
     # ---- corpora ----
@@ -1088,19 +1088,19 @@ def train(
         batch_iter = batches_forever()
         while True:
             # gather `accum` raw batches (stacked microbatches per update)
-            t_read = time.perf_counter()
             raw_batches: List[List[Example]] = []
             cur_epoch = epoch
-            try:
-                for _ in range(accum):
-                    cur_epoch, b = next(batch_iter)
-                    raw_batches.append(b)
-                have_group = True
-            except StopIteration:
-                # end of data: an incomplete accumulation group would under-
-                # scale the mean gradient (scan still divides by `accum`)
-                have_group = False
-            pipe_stats.add("read", time.perf_counter() - t_read, t0=t_read)
+            with pipe_stats.timer(names.READ):
+                try:
+                    for _ in range(accum):
+                        cur_epoch, b = next(batch_iter)
+                        raw_batches.append(b)
+                    have_group = True
+                except StopIteration:
+                    # end of data: an incomplete accumulation group would
+                    # under-scale the mean gradient (scan still divides by
+                    # `accum`)
+                    have_group = False
             if process_count > 1:
                 # loop termination must be COLLECTIVE: if any host ran out
                 # of data, all hosts stop this step, else the continuing
@@ -1205,40 +1205,42 @@ def train(
         (batch identity, bucket shape) when the cache is enabled — a
         steady-state epoch then reduces to cache lookups + device_put.
         """
-        t_collate = time.perf_counter()
         raw_batches = item["raw_batches"]
         B_pad, T_pad = item["B_pad"], item["T_pad"]
-        collated = [
-            cached_collate(
-                collate_cache,
-                b,
-                B_pad,
-                T_pad,
-                lambda b_, B_, T_: nlp.collate(
-                    b_, pad_batch_to=B_, pad_len_to=T_, host=True
-                ),
-                pipe_stats,
-            )
-            for b in raw_batches
-        ]
-        n_words = item["n_words"]
-        if n_words is None:  # single-process: no dims allgather happened
-            n_words = sum(c["n_words"] for c in collated)
-        if accum == 1:
-            tokens, targets = collated[0]["tokens"], collated[0]["targets"]
-        else:
-            # host-side stack: one contiguous array per leaf so the transfer
-            # stage pays a single device_put (multi-host place_batch
-            # re-assembles on the host anyway)
-            tokens = jax.tree_util.tree_map(
-                lambda *xs: np.stack(xs), *[c["tokens"] for c in collated]
-            )
-            targets = jax.tree_util.tree_map(
-                lambda *xs: np.stack(xs), *[c["targets"] for c in collated]
-            )
-        pipe_stats.add(
-            "collate", time.perf_counter() - t_collate, t0=t_collate
-        )
+        with pipe_stats.timer(names.COLLATE):
+            # a cache hit opens no child span: it is this span's self time
+            collated = [
+                cached_collate(
+                    collate_cache,
+                    b,
+                    B_pad,
+                    T_pad,
+                    lambda b_, B_, T_: nlp.collate(
+                        b_, pad_batch_to=B_, pad_len_to=T_, host=True,
+                        stats=pipe_stats,
+                    ),
+                    pipe_stats,
+                )
+                for b in raw_batches
+            ]
+            n_words = item["n_words"]
+            if n_words is None:  # single-process: no dims allgather happened
+                n_words = sum(c["n_words"] for c in collated)
+            if accum == 1:
+                tokens, targets = collated[0]["tokens"], collated[0]["targets"]
+            else:
+                # host-side stack: one contiguous array per leaf so the
+                # transfer stage pays a single device_put (multi-host
+                # place_batch re-assembles on the host anyway)
+                with pipe_stats.timer(names.COLLATE_STACK):
+                    tokens = jax.tree_util.tree_map(
+                        lambda *xs: np.stack(xs),
+                        *[c["tokens"] for c in collated],
+                    )
+                    targets = jax.tree_util.tree_map(
+                        lambda *xs: np.stack(xs),
+                        *[c["targets"] for c in collated],
+                    )
         return {
             "tokens": tokens,
             "targets": targets,
@@ -1262,12 +1264,13 @@ def train(
         )
         try:
             for group in collated_iter:
-                t_put = time.perf_counter()
-                group["tokens"] = place_batch(group["tokens"], mesh, accum=accum > 1)
-                group["targets"] = place_batch(group["targets"], mesh, accum=accum > 1)
-                pipe_stats.add(
-                    "transfer", time.perf_counter() - t_put, t0=t_put
-                )
+                with pipe_stats.timer(names.TRANSFER):
+                    group["tokens"] = place_batch(
+                        group["tokens"], mesh, accum=accum > 1
+                    )
+                    group["targets"] = place_batch(
+                        group["targets"], mesh, accum=accum > 1
+                    )
                 yield group
         finally:
             close = getattr(collated_iter, "close", None)
@@ -1363,6 +1366,20 @@ def train(
 
     last_consumed_epoch = epoch
     dispatch_pushback: Optional[Dict[str, Any]] = None  # bucket-change carry
+    # the open `loop_host` span: everything this thread does per dispatch
+    # that is not waiting for input, evaluating or checkpointing. It is
+    # closed round each of those and re-opened after, so its seconds are
+    # the loop's own host work and nothing else.
+    host_span: Optional[Any] = None
+
+    def host_work(on: bool) -> None:
+        nonlocal host_span
+        if host_span is not None:
+            host_span.__exit__(None, None, None)
+            host_span = None
+        if on:
+            host_span = pipe_stats.timer(names.LOOP_HOST).__enter__()
+
     params_cell = {"params": params}  # read by the annotation pass
     groups: Iterator[Dict[str, Any]] = device_groups()
     prefetch_n = int(T.get("prefetch_batches", 2) or 0)
@@ -1397,15 +1414,12 @@ def train(
                 group = dispatch_pushback
                 dispatch_pushback = None
             else:
-                t_wait = time.perf_counter()
                 try:
-                    group = next(groups)
+                    with pipe_stats.timer(names.QUEUE_WAIT):
+                        group = next(groups)
                 except StopIteration:
                     break
-                finally:
-                    pipe_stats.add(
-                        "queue_wait", time.perf_counter() - t_wait, t0=t_wait
-                    )
+            host_work(True)
             # multi-step dispatch: pull up to K groups, CAPPED so the
             # dispatch lands exactly on the next eval/max_steps/patience
             # boundary — those paths then run identically to K=1 (the
@@ -1437,18 +1451,15 @@ def train(
                 # dispatch and the odd group leads the next one
                 sig0 = _group_shape_sig(group)
                 while len(dispatch_groups) < k_this:
-                    t_wait = time.perf_counter()
+                    host_work(False)
                     try:
-                        g = next(groups)
+                        with pipe_stats.timer(names.QUEUE_WAIT):
+                            g = next(groups)
                     except StopIteration:
                         # stream ran dry mid-gather: dispatch what we have
                         break
                     finally:
-                        pipe_stats.add(
-                            "queue_wait",
-                            time.perf_counter() - t_wait,
-                            t0=t_wait,
-                        )
+                        host_work(True)
                     if _group_shape_sig(g) != sig0:
                         dispatch_pushback = g
                         break
@@ -1466,8 +1477,12 @@ def train(
                 and profile_start < profile_stop  # [start, stop): empty = off
                 and profile_start <= steps_run < profile_stop
             ):
+                # a span that was open when the trace starts is not in it:
+                # re-open the loop's, so the first profiled step has one
+                host_work(False)
                 jax.profiler.start_trace(str(profile_dir))
                 profile_active = True
+                host_work(True)
             if before_update is not None:
                 before_update(nlp, {"step": step, "epoch": cur_epoch})
             # fault-injection site "step": a `sigterm` rule here exercises
@@ -1480,54 +1495,59 @@ def train(
             for _ in range(k_this):
                 maybe_fail("step")
                 poisons.append(resilience.consume_poison("step"))
-            if k_this == 1:
-                rng, sub = jax.random.split(rng)
-                if shadow is not None:
-                    params, opt_state, shadow, loss, metrics = update(
-                        params, opt_state, shadow, tokens, targets, sub
-                    )
-                else:
-                    params, opt_state, loss, metrics = update(
-                        params, opt_state, tokens, targets, sub
-                    )
-                step_metrics = [(metrics, poisons[0])]
-            else:
-                # ONE host round-trip for k_this steps: stack the staged
-                # device batches with a leading [k] dim and scan the
-                # update over them (bit-identical to k singles — the rng
-                # split chain continues inside the program)
-                def _stack(groups_, key):
-                    return jax.tree_util.tree_map(
-                        lambda *xs: jnp.stack(xs), *[g[key] for g in groups_]
-                    )
-
-                s_tokens = _stack(dispatch_groups, "tokens")
-                s_targets = _stack(dispatch_groups, "targets")
-                if shadow is not None:
-                    params, opt_state, shadow, rng, losses, metricses = (
-                        update_multi(
-                            params, opt_state, shadow, s_tokens, s_targets, rng
+            with pipe_stats.timer(names.LOOP_DISPATCH):
+                if k_this == 1:
+                    rng, sub = jax.random.split(rng)
+                    if shadow is not None:
+                        params, opt_state, shadow, loss, metrics = update(
+                            params, opt_state, shadow, tokens, targets, sub
                         )
-                    )
+                    else:
+                        params, opt_state, loss, metrics = update(
+                            params, opt_state, tokens, targets, sub
+                        )
+                    step_metrics = [(metrics, poisons[0])]
                 else:
-                    params, opt_state, rng, losses, metricses = update_multi(
-                        params, opt_state, s_tokens, s_targets, rng
-                    )
-                loss = losses[-1]
+                    # ONE host round-trip for k_this steps: stack the staged
+                    # device batches with a leading [k] dim and scan the
+                    # update over them (bit-identical to k singles — the rng
+                    # split chain continues inside the program)
+                    def _stack(groups_, key):
+                        return jax.tree_util.tree_map(
+                            lambda *xs: jnp.stack(xs), *[g[key] for g in groups_]
+                        )
 
-                def _inner(tree, i):
-                    return jax.tree_util.tree_map(lambda x: x[i], tree)
+                    s_tokens = _stack(dispatch_groups, "tokens")
+                    s_targets = _stack(dispatch_groups, "targets")
+                    if shadow is not None:
+                        params, opt_state, shadow, rng, losses, metricses = (
+                            update_multi(
+                                params, opt_state, shadow, s_tokens, s_targets, rng
+                            )
+                        )
+                    else:
+                        params, opt_state, rng, losses, metricses = update_multi(
+                            params, opt_state, s_tokens, s_targets, rng
+                        )
+                    loss = losses[-1]
 
-                step_metrics = [
-                    (_inner(metricses, i), poisons[i]) for i in range(k_this)
-                ]
+                    def _inner(tree, i):
+                        return jax.tree_util.tree_map(lambda x: x[i], tree)
+
+                    step_metrics = [
+                        (_inner(metricses, i), poisons[i]) for i in range(k_this)
+                    ]
             params_cell["params"] = params
             step += k_this
             steps_run += k_this
             if profile_active and steps_run >= profile_stop:
+                # waiting for the device and writing the trace are not
+                # the loop's work per step
+                host_work(False)
                 jax.block_until_ready(loss)
                 jax.profiler.stop_trace()
                 profile_active = False
+                host_work(True)
             if use_averages:
                 # steps_per_dispatch is bypassed to 1 under use_averages,
                 # so the running mean still sees every step's params
@@ -1558,6 +1578,7 @@ def train(
 
             info: Optional[Dict[str, Any]] = None
             if step % eval_frequency == 0:
+                host_work(False)
                 drain_metrics()
                 # eval (and best-model save) uses averaged params when enabled.
                 # Params stay ON DEVICE through prediction — gathering the full
@@ -1565,7 +1586,8 @@ def train(
                 # costs two full-model transfers for nothing.
                 eval_src = avg_params if use_averages else params
                 eval_t0 = time.perf_counter()
-                scores = nlp.evaluate(dev_examples, eval_src, mesh=mesh)
+                with _tspan("eval", step=step):
+                    scores = nlp.evaluate(dev_examples, eval_src, mesh=mesh)
                 eval_seconds = time.perf_counter() - eval_t0
                 score = weighted_score(scores, score_weights)
                 now = time.perf_counter()
@@ -1588,10 +1610,6 @@ def train(
                     "input_pipeline": pipe_stats.snapshot(),
                 }
                 if tel is not None:
-                    tel.trace.add_span(
-                        "eval", eval_t0, eval_seconds, cat="loop",
-                        args={"step": step}, force=True,
-                    )
                     info["telemetry"] = tel.eval_boundary(
                         step=step,
                         epoch=cur_epoch,
@@ -1600,21 +1618,6 @@ def train(
                         score=score,
                         eval_seconds=eval_seconds,
                         input_pipeline=info["input_pipeline"],
-                        # one-shot XLA cost analysis (a trace, not a
-                        # compile) — bench.py's MFU numerator path; always
-                        # the SINGLE-step program (per-step flops), with
-                        # the shadow argument when the update takes one
-                        flops_fn=lambda: program_flops(
-                            update,
-                            *(
-                                (params, opt_state, shadow)
-                                if shadow is not None
-                                else (params, opt_state)
-                            ),
-                            tokens,
-                            targets,
-                            rng,
-                        ),
                         wps=wps,
                     )
                     info["step_ms_p50"] = _ms(
@@ -1638,6 +1641,7 @@ def train(
                     # eval + checkpoint time must not count against the
                     # NEXT step's measured step time
                     tel.rearm_step_clock()
+                host_work(True)
             log_step(info)
             if watchdog is not None:
                 watchdog.beat()
@@ -1651,6 +1655,7 @@ def train(
             # step (stop conditions above are replica-identical, so the
             # poll itself stays collective-aligned)
             if not stop and shutdown.coordinated_stop(process_count):
+                host_work(False)
                 with _tspan("preemption_drain", step=step):
                     drain_metrics()
                     save_last(group)
@@ -1662,8 +1667,10 @@ def train(
                     step=step,
                 )
                 stop = True
+            host_work(False)
 
     finally:
+        host_work(False)  # a step/eval that raised left the span open
         # stop the prefetch producer and drop its buffered (on-device)
         # batches even when a step/eval raises — train() may be called
         # again in the same process

@@ -38,8 +38,8 @@ Four pieces, individually inert and composable:
 
 ``metrics.jsonl`` row kinds: ``step`` (per-step step-time + words, and
 per-step ``loss`` on the trainer-fleet path), ``eval`` (gauges: HBM,
-compile count, live buffers, step-time p50/p95, MFU estimate, per-stage
-seconds), ``anomaly``, ``serving`` (a serve run's snapshot), ``fleet``
+compile count, live buffers, step-time p50/p95, per-stage seconds),
+``anomaly``, ``serving`` (a serve run's snapshot), ``fleet``
 (a trainer-fleet worker's exit row: counters, phase ledger, dynamics-
 histogram snapshots). Rows buffer in memory and flush at eval
 boundaries / finalize / watchdog fire — never per-step file I/O in the
@@ -56,6 +56,10 @@ from bisect import bisect_left
 from collections import deque
 from pathlib import Path
 from typing import Any, Callable, Dict, IO, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
+
+from ..names import SPAN_PREFIX
 
 __all__ = [
     "MetricsRegistry",
@@ -708,21 +712,29 @@ class TraceBuffer:
             self._events.append(ev)
 
     class _Span:
-        __slots__ = ("_buf", "_name", "_cat", "_args", "_force", "_t0")
+        __slots__ = (
+            "_buf", "_name", "_cat", "_args", "_force", "_t0", "_note"
+        )
 
         def __init__(self, buf, name, cat, args, force):
             self._buf, self._name = buf, name
             self._cat, self._args, self._force = cat, args, force
 
         def __enter__(self):
+            # the same span on the profiler's clock whenever a profiler
+            # trace is running (a flag check otherwise): names.py
+            self._note = TraceAnnotation(SPAN_PREFIX + self._name)
+            self._note.__enter__()
             self._t0 = self._buf._clock()
             return self
 
         def __exit__(self, *exc: Any) -> None:
+            dur = self._buf._clock() - self._t0
+            self._note.__exit__(*exc)
             self._buf.add_span(
                 self._name,
                 self._t0,
-                self._buf._clock() - self._t0,
+                dur,
                 cat=self._cat,
                 args=self._args,
                 force=self._force,
@@ -901,8 +913,8 @@ def program_flops(
 ) -> Optional[float]:
     """FLOPs of one compiled step from XLA cost analysis of the lowered
     program (a trace, not a compile). None when the backend can't say —
-    callers (bench.py's ``_program_flops``, the eval-boundary MFU gauge)
-    choose their own fallback/labeling; ``on_error`` receives the failure
+    callers (bench.py's ``_program_flops``) choose their own
+    fallback/labeling; ``on_error`` receives the failure
     reason so a missing-MFU record stays debuggable."""
     try:
         cost = jit_fn.lower(*args).cost_analysis()
@@ -921,7 +933,7 @@ def device_peak_flops() -> Tuple[Optional[float], str]:
 
     Deliberately datasheet-only: the training loop must never run
     bench.py's matmul microbench mid-run (it would steal the very step
-    time being measured). Without a datasheet number the MFU gauge stays
+    time being measured). Without a datasheet number the peak stays
     None — an honest absence, not a made-up denominator.
     """
     try:
@@ -1473,10 +1485,6 @@ class Telemetry:
         self._rows_lock = threading.Lock()
         self._last_boundary: Optional[float] = None
         self._t0 = clock()
-        self.flops_per_step: Optional[float] = None
-        self._flops_probed = False
-        self._peak: Optional[float] = None
-        self._peak_kind: Optional[str] = None
         self._handle: Optional[IO[str]] = None
         self._finalized = False
         # ticker starts LAST: it snapshots the registry, so every
@@ -1688,7 +1696,6 @@ class Telemetry:
         score: Optional[float],
         eval_seconds: float,
         input_pipeline: Optional[Dict[str, Any]] = None,
-        flops_fn: Optional[Callable[[], Optional[float]]] = None,
         wps: Optional[float] = None,
     ) -> Dict[str, Any]:
         """Sample gauges, run detectors, flush rows; returns the snapshot
@@ -1703,30 +1710,9 @@ class Telemetry:
             reg.gauge("live_buffers").set(device["live_buffers"])
         compiles = device["compile_count"] - self._compiles_at_start
         reg.gauge("compile_count").set(compiles)
-        # one-shot cost model: lowering is a trace (no compile), but not
-        # free — probe on the first eval only
-        if not self._flops_probed and flops_fn is not None:
-            self._flops_probed = True
-            try:
-                self.flops_per_step = flops_fn()
-            except Exception:
-                self.flops_per_step = None
-            self._peak, self._peak_kind = device_peak_flops()
         hist = self._step_hist
         p50 = hist.percentile(0.5)
         p95 = hist.percentile(0.95)
-        mfu = None
-        if self.flops_per_step and self._peak and p50:
-            try:
-                import jax
-
-                n_chips = len(jax.devices())
-            except Exception:
-                n_chips = 1
-            # e2e MFU: the denominator is wall step time (host work
-            # included) — chip utilization of the whole pipeline, same
-            # convention as bench.py's e2e records
-            mfu = self.flops_per_step / p50 / (self._peak * n_chips)
         loss_total = sum(float(v) for v in losses.values()) if losses else None
         if self.detectors is not None:
             if loss_total is not None:
@@ -1755,8 +1741,6 @@ class Telemetry:
             "hbm_bytes_limit": device["hbm_bytes_limit"],
             "live_buffers": device["live_buffers"],
             "compile_count": compiles,
-            "flops_per_step": self.flops_per_step,
-            "mfu": round(mfu, 5) if mfu is not None else None,
             "platform": device["platform"],
         }
         if input_pipeline is not None:
@@ -1773,7 +1757,6 @@ class Telemetry:
             "hbm_peak_bytes": device["hbm_peak_bytes"],
             "live_buffers": device["live_buffers"],
             "compile_count": compiles,
-            "mfu": row["mfu"],
             "trace_events": len(self.trace),
         }
         return snapshot
@@ -2093,11 +2076,16 @@ def summarize_metrics(path: Path) -> str:
         last = evals[-1]
         stages = (last.get("input_pipeline") or {}).get("stage_seconds") or {}
         if stages:
-            stage_total = sum(stages.values()) or 1.0
-            lines.append("host input-pipeline breakdown (cumulative seconds):")
-            for stage, seconds in stages.items():
+            # a key with a "/" is a part of its parent (names.py): shares
+            # are of the top-level stages' sum, children listed under them
+            stage_total = sum(
+                v for k, v in stages.items() if "/" not in k
+            ) or 1.0
+            lines.append("host stage breakdown (cumulative seconds):")
+            for stage, seconds in sorted(stages.items()):
+                indent = "  " * (1 + stage.count("/"))
                 lines.append(
-                    f"  {stage:12s} {seconds:10.3f}s  "
+                    f"{indent}{stage:34s} {seconds:10.3f}s  "
                     f"{100 * seconds / stage_total:5.1f}%"
                 )
         lines.append(
@@ -2106,8 +2094,6 @@ def summarize_metrics(path: Path) -> str:
             f"live_buffers={last.get('live_buffers')}  "
             f"compiles={last.get('compile_count')}"
         )
-        if isinstance(last.get("mfu"), (int, float)):
-            lines.append(f"mfu (e2e, p50 step): {last['mfu']:.4f}")
         # sanitize_json stores a NaN score as the string "nan" — keep only
         # finite numerics, or the digest of a NaN run (the headline use
         # case) would crash on the format specifier

@@ -1,0 +1,491 @@
+"""The program's own spans and names (``spacy_ray_tpu/names.py``): host
+spans on the profiler's clock, a fixed number of them a step, nothing
+recorded without a stats handle, and scope / kernel / program names in the
+lowered train step."""
+
+import json
+import re
+import threading
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from spacy_ray_tpu import names
+from spacy_ray_tpu.config import Config
+from spacy_ray_tpu.training import collate_pool
+from spacy_ray_tpu.training.corpus import _doc_to_json
+from spacy_ray_tpu.training.loop import train
+from spacy_ray_tpu.util import synth_corpus
+
+# the sm shape (tagger + parser + NER over one shared CNN trunk) and the trf
+# shape (the same heads over a transformer trunk), at test widths
+SM_CFG = """
+[paths]
+train = null
+dev = null
+
+[nlp]
+lang = "en"
+pipeline = ["tok2vec","tagger","parser","ner"]
+
+[components.tok2vec]
+factory = "tok2vec"
+
+[components.tok2vec.model]
+@architectures = "spacy.HashEmbedCNN.v2"
+width = 32
+depth = 2
+embed_size = 256
+
+[components.tagger]
+factory = "tagger"
+
+[components.tagger.model]
+@architectures = "spacy.Tagger.v2"
+
+[components.tagger.model.tok2vec]
+@architectures = "spacy.Tok2VecListener.v1"
+width = 32
+
+[components.parser]
+factory = "parser"
+
+[components.parser.model]
+@architectures = "spacy.TransitionBasedParser.v2"
+state_type = "parser"
+hidden_width = 32
+maxout_pieces = 2
+
+[components.parser.model.tok2vec]
+@architectures = "spacy.Tok2VecListener.v1"
+width = 32
+
+[components.ner]
+factory = "ner"
+
+[components.ner.model]
+@architectures = "spacy.TransitionBasedParser.v2"
+state_type = "ner"
+hidden_width = 32
+maxout_pieces = 2
+
+[components.ner.model.tok2vec]
+@architectures = "spacy.Tok2VecListener.v1"
+width = 32
+
+[corpora.train]
+@readers = "spacy.JsonlCorpus.v1"
+path = ${paths.train}
+
+[corpora.dev]
+@readers = "spacy.JsonlCorpus.v1"
+path = ${paths.dev}
+
+[training]
+seed = 0
+max_steps = 6
+eval_frequency = 1000
+patience = 0
+
+[training.optimizer]
+@optimizers = "Adam.v1"
+learn_rate = 0.005
+
+[training.batcher]
+@batchers = "spacy.batch_by_words.v1"
+size = 200
+
+[training.score_weights]
+tag_acc = 0.34
+dep_las = 0.33
+ents_f = 0.33
+"""
+
+TRF_TRUNK = """[components.tok2vec]
+factory = "transformer"
+
+[components.tok2vec.model]
+@architectures = "spacy_ray_tpu.TransformerEncoder.v1"
+width = 32
+depth = 2
+n_heads = 2
+ffn_mult = 2
+max_len = 64
+embed_size = 256
+"""
+
+TRF_CFG = re.sub(
+    r"\[components\.tok2vec\]\n.*?embed_size = 256\n", TRF_TRUNK, SM_CFG,
+    flags=re.S,
+)
+
+
+def _write_mixed(path, n, seed):
+    egs = synth_corpus(n // 2, "parser", seed=seed) + synth_corpus(
+        n // 2, "ner", seed=seed + 1
+    )
+    with open(path, "w", encoding="utf8") as f:
+        for eg in egs:
+            f.write(json.dumps(_doc_to_json(eg.reference)) + "\n")
+
+
+@pytest.fixture(scope="module")
+def mixed_dir(tmp_path_factory):
+    d = tmp_path_factory.mktemp("spans_data")
+    _write_mixed(d / "train.jsonl", 240, seed=0)
+    _write_mixed(d / "dev.jsonl", 20, seed=7)
+    return d
+
+
+def _config(text, data_dir, **overrides):
+    return Config.from_str(text).apply_overrides(
+        {
+            "paths.train": str(data_dir / "train.jsonl"),
+            "paths.dev": str(data_dir / "dev.jsonl"),
+            **overrides,
+        }
+    )
+
+
+class RecordingStats(collate_pool.PipelineStats):
+    """The loop's stage clocks, keeping every span they are given (the
+    handle the benchmark takes: a subclass that registers itself)."""
+
+    made = []
+
+    def __init__(self):
+        super().__init__()
+        self.calls = []
+        RecordingStats.made.append(self)
+
+    def _add_all(self, spans, n=1):
+        self.calls.extend((stage, seconds) for stage, seconds, _ in spans)
+        super()._add_all(spans, n)
+
+
+@pytest.fixture
+def recorded(monkeypatch):
+    RecordingStats.made.clear()
+    monkeypatch.setattr(collate_pool, "PipelineStats", RecordingStats)
+    return RecordingStats.made
+
+
+def _host_spans(profile_dir):
+    """``{thread line: [(start_ns, end_ns, name)]}`` of the program's spans
+    on the host plane of the one trace under ``profile_dir`` (a thread's
+    line is known by its place: every Python thread's is named alike)."""
+    from jax.profiler import ProfileData
+
+    (path,) = list(profile_dir.rglob("*.xplane.pb"))
+    lines = {}
+    for p, plane in enumerate(ProfileData.from_file(str(path)).planes):
+        if not plane.name.startswith("/host:"):
+            continue
+        for n, line in enumerate(plane.lines):
+            spans = [
+                (int(e.start_ns), int(e.start_ns + e.duration_ns), e.name)
+                for e in line.events
+                if e.name.startswith(names.SPAN_PREFIX)
+            ]
+            if spans:
+                lines[(p, n)] = spans
+    return lines
+
+
+def test_spans_are_on_the_profilers_clock(mixed_dir, tmp_path, recorded):
+    """One trace holds the program's spans beside the device's operations:
+    ``train --profile`` writes the collate thread's and the loop's spans
+    as host events, each child inside its parent on its thread's line, and
+    what the profiler's clock says of ``collate`` is what ``PipelineStats``
+    counted for the same calls."""
+    cfg = _config(
+        SM_CFG, mixed_dir,
+        **{"training.max_steps": 8, "training.profile_window": [1, 7]},
+    )
+    train(cfg, n_workers=1, stdout_log=False, profile_dir=tmp_path / "prof")
+    lines = _host_spans(tmp_path / "prof")
+    by_name = {}
+    for line_name, spans in lines.items():
+        for a, b, name in spans:
+            by_name.setdefault(name, []).append((line_name, a, b))
+    for key in (
+        names.COLLATE,
+        names.COLLATE_FEATURES,
+        names.COLLATE_TARGETS,
+        names.collate_head("parser"),
+        names.collate_head("ner") + "/" + names.DEVICE_CALL,
+        names.QUEUE_WAIT,
+        names.TRANSFER,
+        names.LOOP_HOST,
+        names.LOOP_DISPATCH,
+    ):
+        assert names.SPAN_PREFIX + key in by_name, (key, sorted(by_name))
+
+    # a child lies inside a span of its parent, on the same thread line
+    # (a span that was open when the trace began is not in it: its children
+    # are the ones that start before the first of their parent's name)
+    checked = 0
+    for name, found in by_name.items():
+        key = name[len(names.SPAN_PREFIX):]
+        if "/" not in key:
+            continue
+        parents = by_name[names.SPAN_PREFIX + key.rsplit("/", 1)[0]]
+        for line, a, b in found:
+            on_line = [(pa, pb) for pl, pa, pb in parents if pl == line]
+            assert on_line, f"{name} on a line without its parent"
+            if a < min(pa for pa, _ in on_line):
+                continue
+            assert any(pa <= a and b <= pb for pa, pb in on_line), (
+                f"{name} [{a}, {b}] lies in no parent span"
+            )
+            checked += 1
+    assert checked >= 30
+
+    # the collate thread is not the loop's thread
+    collate_lines = {l for l, _, _ in by_name[names.SPAN_PREFIX + names.COLLATE]}
+    loop_lines = {l for l, _, _ in by_name[names.SPAN_PREFIX + names.LOOP_DISPATCH]}
+    assert collate_lines.isdisjoint(loop_lines)
+
+    # the same calls on two clocks: the trace's collate spans are a run of
+    # consecutive calls among those PipelineStats was given
+    (stats,) = recorded
+    counted = [s for stage, s in stats.calls if stage == names.COLLATE]
+    traced = [
+        (b - a) / 1e9
+        for _, a, b in sorted(by_name[names.SPAN_PREFIX + names.COLLATE], key=lambda x: x[1])
+    ]
+    assert 3 <= len(traced) <= len(counted)
+    gaps = [
+        abs(sum(traced) - sum(counted[i:i + len(traced)]))
+        for i in range(len(counted) - len(traced) + 1)
+    ]
+    assert min(gaps) <= 0.05 * sum(traced) + 1e-3, (traced, counted)
+    assert stats.seconds[names.COLLATE] == pytest.approx(sum(counted))
+
+
+def test_a_part_is_counted_together_with_its_whole():
+    """The benchmark reads ``seconds`` at two instants and takes ratios of
+    what lies between: a child's seconds must not arrive before its
+    parent's, or a window edge inside the parent skews every share."""
+    stats = collate_pool.PipelineStats()
+    with stats.timer(names.COLLATE) as whole:
+        with stats.timer(names.COLLATE_TARGETS):
+            with whole.child("targets/ner"):
+                pass
+        assert names.COLLATE_TARGETS not in stats.seconds
+        assert stats.counts[names.COLLATE] == 0
+        # another thread's spans are its own
+        def read():
+            with stats.timer(names.READ):
+                pass
+
+        other = threading.Thread(target=read)
+        other.start()
+        other.join(10)
+        assert stats.counts[names.READ] == 1
+    assert stats.counts[names.COLLATE] == 1
+    assert stats.counts[names.collate_head("ner")] == 1
+    assert 0 < stats.seconds[names.COLLATE_TARGETS] <= stats.seconds[names.COLLATE]
+    stats.add(names.TRANSFER, 0.5)  # a caller that only counts: at once
+    assert stats.seconds[names.TRANSFER] == 0.5
+
+
+@pytest.mark.parametrize("accumulate", [1, 2])
+def test_spans_a_step_do_not_grow_with_the_batch(mixed_dir, recorded, accumulate):
+    """Every span is per batch or per micro-batch, never per document: a
+    batch of four times the words records the same spans a step."""
+    per_step = []
+    for size in (100, 400):
+        cfg = _config(
+            SM_CFG, mixed_dir,
+            **{
+                "training.max_steps": 3,
+                "training.batcher.size": size,
+                "training.accumulate_gradient": accumulate,
+                "training.prefetch_batches": 0,  # inline: 3 steps, 3 collates
+            },
+        )
+        train(cfg, n_workers=1, stdout_log=False)
+        stats = recorded[-1]
+        assert stats.counts[names.COLLATE] in (3, 4)  # the end of data or not
+        per_step.append({
+            stage: round(n / stats.counts[names.COLLATE], 3)
+            for stage, n in stats.counts.items()
+            if stage.startswith(names.COLLATE)
+        })
+    assert per_step[0] == per_step[1]
+    assert per_step[0][names.COLLATE_TARGETS] == accumulate
+    assert per_step[0].get(names.COLLATE_STACK, 0) == (1 if accumulate > 1 else 0)
+    stats = recorded[-1]
+    assert stats.counts[names.LOOP_DISPATCH] == 3
+    assert stats.counts[names.LOOP_HOST] == 3
+    # the snapshot (eval rows, watchdog dump) carries every key it holds
+    snap = stats.snapshot()
+    assert set(snap["stage_seconds"]) == set(stats.seconds)
+    assert snap["stage_counts"][names.collate_head("parser")] == 3 * accumulate
+
+
+def test_collate_without_a_stats_handle_records_nothing(mixed_dir, monkeypatch):
+    """Serving, ``evaluate`` and tests call ``collate`` with no handle: no
+    clock is read, no annotation is made, and the batch is the same."""
+    import numpy as np
+
+    nlp, _ = train(
+        _config(SM_CFG, mixed_dir, **{"training.max_steps": 1}),
+        n_workers=1, stdout_log=False,
+    )
+    examples = synth_corpus(8, "parser", seed=3)
+    stats = collate_pool.PipelineStats()
+    timed = nlp.collate(examples, host=True, stats=stats)
+    assert stats.counts[names.COLLATE_FEATURES] == 1
+    assert stats.counts[names.collate_head("ner") + "/" + names.DEVICE_CALL] == 1
+
+    def boom(*a, **k):
+        raise AssertionError("a span was made without a stats handle")
+
+    monkeypatch.setattr(collate_pool.PipelineStats, "timer", boom)
+    monkeypatch.setattr(collate_pool, "TraceAnnotation", boom)
+    monkeypatch.setattr(collate_pool.time, "perf_counter", boom)
+    bare = nlp.collate(examples, host=True)
+    for a, b in zip(jax.tree_util.tree_leaves(timed["targets"]),
+                    jax.tree_util.tree_leaves(bare["targets"])):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(
+        timed["tokens"].attr_keys, bare["tokens"].attr_keys
+    )
+
+
+def _lowered_step(text, data_dir, monkeypatch):
+    """The train step of ``text``'s pipeline, lowered from its first call's
+    arguments (a trace, no compile), and the name of its function."""
+    import spacy_ray_tpu.training.loop as loop
+
+    made = []
+    real = loop.make_train_step
+
+    def keeping(*a, **k):
+        update = real(*a, **k)
+        made.append(update)
+
+        def run(*args):
+            if not hasattr(run, "lowered"):
+                run.lowered = update.lower(*args).as_text(debug_info=True)
+            return update(*args)
+
+        run.__dict__.update(update.__dict__)
+        made.append(run)
+        return run
+
+    monkeypatch.setattr(loop, "make_train_step", keeping)
+    train(_config(text, data_dir, **{"training.max_steps": 1}),
+          n_workers=1, stdout_log=False)
+    return made[1].lowered
+
+
+@pytest.mark.parametrize("shape", ["sm", "trf"])
+def test_the_lowered_step_carries_the_scope_names(mixed_dir, monkeypatch, shape):
+    """Device time is read by name: every operation of the step lies in one
+    of the scopes of ``names.py`` (its transposes too), and the program is
+    ``jit_srt_train_step``. Metadata only: nothing is added to the program."""
+    text = _lowered_step(SM_CFG if shape == "sm" else TRF_CFG, mixed_dir, monkeypatch)
+    assert f"module @jit_{names.PROGRAM_TRAIN_STEP} " in text
+    for scope in (
+        names.SCOPE_EMBED,
+        names.SCOPE_TRUNK,
+        names.head_scope("tagger"),
+        names.head_scope("parser"),
+        names.head_scope("ner"),
+        names.SCOPE_LOSS,
+        names.SCOPE_UPDATE,
+    ):
+        assert re.search(rf'loc\("[^"]*\b{re.escape(scope)}[)/]', text), scope
+    # the backward pass keeps the forward's name
+    for scope in (names.SCOPE_TRUNK, names.head_scope("parser")):
+        assert re.search(
+            rf'loc\("[^"]*transpose\(jvp\({re.escape(scope)}\)\)/', text
+        ), f"no transposed operation under {scope}"
+    if shape == "trf":
+        # the layers are rematerialised: the forward's and the backward's
+        # copies of the scanned stack are both called from under the trunk
+        for side in ("jvp", "transpose\\(jvp"):
+            assert re.search(
+                rf'loc\("[^"]*{side}\({names.SCOPE_TRUNK}\)+/while/body/closed_call"', text
+            ), side
+
+
+def test_accumulated_steps_are_scoped_and_named(mixed_dir, monkeypatch):
+    """``accumulate_gradient`` > 1: the scan over micro-batches is the
+    ``grad_accum`` scope, and the model's scopes are inside its body."""
+    text = _lowered_step(
+        SM_CFG.replace("patience = 0", "patience = 0\naccumulate_gradient = 2"),
+        mixed_dir, monkeypatch,
+    )
+    assert f"module @jit_{names.PROGRAM_TRAIN_STEP} " in text
+    assert re.search(rf'loc\("[^"]*/{names.SCOPE_GRAD_ACCUM}/while/body/', text)
+    assert re.search(rf'loc\("[^"]*transpose\(jvp\({names.SCOPE_TRUNK}\)\)/', text)
+
+
+def _pallas_names(fn, *args):
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn.params["name"])
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (list, tuple)) else [v]:
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def _flash():
+    from spacy_ray_tpu.ops import flash_attention as fa
+
+    q = jnp.ones((1, 2, 128, 128), jnp.float32)
+    bias = jnp.zeros((1, 128), jnp.float32)
+    flash = fa._make_flash(0.125)
+    return jax.grad(lambda q, k, v: flash(q, k, v, bias).sum(), argnums=(0, 1, 2)), (q, q, q)
+
+
+def _fused_adam():
+    from spacy_ray_tpu.ops import fused_update as fu
+
+    p = jnp.ones((300, 7), jnp.float32)
+    hyper = fu.FusedHyper("adam", 0.9, 0.999, 1e-8, 1.0, 0.0, 0.01)
+    scal = jnp.ones((8,), jnp.float32)
+    return (lambda p, g, m, v: fu._kernel_leaf(p, g, m, v, scal, hyper)), (p, p, p, p)
+
+
+def _hash_embed():
+    from spacy_ray_tpu.ops import pallas_kernels as pk
+
+    return pk._pallas_lookup_raw, (
+        jnp.ones((512, 128), jnp.float32), jnp.zeros((256, 4), jnp.int32))
+
+
+def _int8():
+    from spacy_ray_tpu.ops import int8_matmul as im
+
+    return im._int8_matmul_raw, (
+        jnp.ones((8, 256), jnp.float32), jnp.ones((256, 128), jnp.int8),
+        jnp.ones((128,), jnp.float32))
+
+
+@pytest.mark.parametrize(
+    "build,expected",
+    [
+        (_flash, {names.KERNEL_FLASH_FWD, names.KERNEL_FLASH_BWD}),
+        (_fused_adam, {names.KERNEL_FUSED_ADAM}),
+        (_hash_embed, {names.KERNEL_HASH_EMBED}),
+        (_int8, {names.KERNEL_INT8_MATMUL}),
+    ],
+    ids=["flash", "fused_adam", "hash_embed", "int8_matmul"],
+)
+def test_each_pallas_call_carries_its_name(build, expected):
+    fn, args = build()
+    assert set(_pallas_names(fn, *args)) == expected

@@ -10,6 +10,7 @@ recovery, CLI fleet, bounded-staleness convergence) are slow-marked —
 """
 
 import json
+import os
 import socket
 import threading
 import time
@@ -45,6 +46,32 @@ def _free_ports(n):
     for s in socks:
         s.close()
     return ports
+
+
+def _free_base_port(n):
+    """A base port for a CLI fleet, whose worker k binds ``base + k``
+    seconds later, once its process is up. ``_free_ports`` cannot give
+    one: it answers for a single port, and the kernel hands a port it has
+    just seen closed to the next ``bind(0)`` of any test running beside
+    this one. So the run of ``n`` ports is taken BELOW the range the
+    kernel draws from (``ip_local_port_range``: 32768-60999), where only a
+    process that asks for the number can sit, and every one of them is
+    bound once to see that nobody does."""
+    start = 21000 + (os.getpid() * 64) % 10000
+    for base in range(start, start + 10000, 64):
+        socks = []
+        try:
+            for k in range(n):
+                s = socket.socket()
+                socks.append(s)
+                s.bind(("127.0.0.1", base + k))
+            return base
+        except OSError:
+            continue
+        finally:
+            for s in socks:
+                s.close()
+    raise RuntimeError(f"no run of {n} free ports from {start}")
 
 
 @pytest.fixture(scope="module")
@@ -827,7 +854,7 @@ def test_fleet_obs_acceptance_subprocess_trace_and_report(
     cfg_path = tmp_path / "cfg.cfg"
     cfg_path.write_text(tagger_config_text, encoding="utf8")
     out = tmp_path / "out"
-    base_port = _free_ports(1)[0]
+    base_port = _free_base_port(2)
     cmd = _fleet_cli_cmd(
         cfg_path, data_dir, out, 2, steps=16, quorum=2, staleness=1,
         base_port=base_port,
@@ -1041,7 +1068,7 @@ def test_fleet_cli_subprocess_run(tagger_config_text, data_dir, tmp_path):
     cfg_path = tmp_path / "cfg.cfg"
     cfg_path.write_text(tagger_config_text, encoding="utf8")
     out = tmp_path / "out"
-    base_port = _free_ports(1)[0]
+    base_port = _free_base_port(2)
     proc = subprocess.run(
         _fleet_cli_cmd(cfg_path, data_dir, out, 2, steps=8, quorum=2,
                        staleness=0, base_port=base_port),
@@ -1078,7 +1105,7 @@ def test_fleet_sigkill_recovery(tagger_config_text, data_dir, tmp_path):
     cfg_path = tmp_path / "cfg.cfg"
     cfg_path.write_text(tagger_config_text, encoding="utf8")
     out = tmp_path / "out"
-    base_port = _free_ports(1)[0]
+    base_port = _free_base_port(2)
     # quorum=1: neither worker ever blocks on the other, so the fleet
     # keeps stepping through the kill; 40 steps keeps the survivor alive
     # well past the victim's ~20s restart (wait_for_peers at rejoin
