@@ -26,6 +26,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..registry import registry
 from ..pipeline import transition as T
@@ -37,20 +38,24 @@ PARSER_N_FEATURES = T.N_FEATURES
 NER_N_FEATURES = 5  # token window [t-2, t-1, t, t+1, t+2]
 
 
-def ner_window_features(Tlen: int, lengths: jnp.ndarray) -> jnp.ndarray:
-    """[B, T, 5] window indices [t-2 .. t+2], -1 outside [0, length).
+def ner_window_features(Tlen: int, lengths):
+    """[B, T, 5] int32 window indices [t-2 .. t+2], -1 outside [0, length).
 
-    Single source of truth for the NER feature layout — used by both the
-    training targets (host) and the jit decode path.
+    Single source of truth for the NER feature layout. The type of
+    ``lengths`` picks the array module: a ``numpy.ndarray`` (the training
+    targets, ``NERComponent.make_targets`` on the collate thread) is
+    answered in NumPy on the host and dispatches nothing to the device;
+    anything else (the tracer of ``NERComponent.forward`` under jit: decode,
+    ``evaluate``, serving; a ``jax.Array``) is answered in ``jax.numpy`` as
+    part of the caller's program. Same values either way (tested).
     """
+    xp = np if isinstance(lengths, np.ndarray) else jnp
     grid = (
-        jnp.arange(Tlen)[None, :, None]
-        + jnp.array([-2, -1, 0, 1, 2])[None, None, :]
+        xp.arange(Tlen, dtype=xp.int32)[None, :, None]
+        + xp.array([-2, -1, 0, 1, 2], dtype=xp.int32)[None, None, :]
     )
-    lengths = jnp.asarray(lengths)
-    return jnp.where(
-        (grid >= 0) & (grid < lengths[:, None, None]), grid, -1
-    ).astype(jnp.int32)
+    lengths = xp.asarray(lengths)
+    return xp.where((grid >= 0) & (grid < lengths[:, None, None]), grid, -1)
 
 
 # HBM budget for the one-hot operand ([*feats.shape, T] elements, live

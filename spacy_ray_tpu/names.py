@@ -29,7 +29,9 @@ QUEUE_WAIT = "queue_wait"  # the loop waiting for its next group
 COLLATE_FEATURES = "collate/features"  # vocab.featurize + attr_keys/mask/vector_rows
 COLLATE_TARGETS = "collate/targets"  # the loop over head_names()
 COLLATE_STACK = "collate/stack"  # np.stack of the micro-batches (accumulate_gradient > 1)
-DEVICE_CALL = "device_call"  # child of a head's span: a call that leaves the host
+# child of a head's span: a call that leaves the host (Component.make_targets'
+# contract). No shipped head opens it: the collate path stays on the host
+DEVICE_CALL = "device_call"
 # the loop thread, per dispatch: from the return of next(groups) to the next
 # call of it, evaluation and checkpoint excluded
 LOOP_HOST = "loop_host"

@@ -102,7 +102,10 @@ class Component:
         (``PipelineStats.timer``; ``NO_SPAN`` where nothing is recorded).
         An implementation that leaves the host (an eager ``jnp`` call, a
         copy back from the device) wraps that call in
-        ``span.child(names.DEVICE_CALL)``; pure host work ignores it."""
+        ``span.child(names.DEVICE_CALL)``: on the chip such a call queues
+        behind the running step, and the span is how that wait is seen.
+        Pure host work ignores it, and no shipped head opens it (NER's
+        window features are NumPy: ``models/parser.ner_window_features``)."""
         return {}
 
     # ---------------------------- device -----------------------------

@@ -22,13 +22,11 @@ from typing import Any, Dict, List
 import numpy as np
 import jax.numpy as jnp
 
-from ... import names
 from ...registry import registry
 from ...models.core import Context, Params
 from ...models.parser import NER_N_FEATURES, decode_biluo, decode_biluo_viterbi, ner_window_features
 from ...ops import ops as O
 from ...pipeline.doc import Doc, Example, Span
-from ...training.collate_pool import NO_SPAN
 from ...types import Padded
 from .base import Component
 
@@ -83,26 +81,21 @@ class NERComponent(Component):
         return model
 
     def make_targets(
-        self, examples: List[Example], B: int, Tlen: int, span: Any = NO_SPAN
+        self, examples: List[Example], B: int, Tlen: int, span: Any = None
     ) -> Dict[str, np.ndarray]:
         label_ids = {label: i for i, label in enumerate(self.labels)}
         actions = np.zeros((B, Tlen), dtype=np.int32)
         mask = np.zeros((B, Tlen), dtype=bool)
-        lengths = []
+        lengths = np.zeros(B, dtype=np.int32)  # the padded rows stay empty
         for i, eg in enumerate(examples):
             ref = eg.reference
-            n = min(len(ref), Tlen)
-            lengths.append(n)
+            n = lengths[i] = min(len(ref), Tlen)
             tags = ref.ents_biluo()
             for t in range(n):
                 actions[i, t] = biluo_action_id(tags[t], label_ids)
                 mask[i, t] = True
-        while len(lengths) < B:
-            lengths.append(0)
-        # eager jnp on the collate thread: it runs on the device, behind
-        # whatever the device is doing, and the copy back waits for it
-        with span.child(names.DEVICE_CALL):
-            feats = np.asarray(ner_window_features(Tlen, np.asarray(lengths)))
+        # a numpy.ndarray in: NumPy out, on this thread, nothing dispatched
+        feats = ner_window_features(Tlen, lengths)
         return {"actions": actions, "feats": feats, "ner_mask": mask}
 
     def loss(self, params: Params, inputs: Any, targets: Dict[str, Any], ctx: Context):

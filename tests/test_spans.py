@@ -157,10 +157,12 @@ class RecordingStats(collate_pool.PipelineStats):
     def __init__(self):
         super().__init__()
         self.calls = []
+        self.spans = []  # (stage, seconds, start on time.perf_counter)
         RecordingStats.made.append(self)
 
     def _add_all(self, spans, n=1):
         self.calls.extend((stage, seconds) for stage, seconds, _ in spans)
+        self.spans.extend(spans)
         super()._add_all(spans, n)
 
 
@@ -214,13 +216,15 @@ def test_spans_are_on_the_profilers_clock(mixed_dir, tmp_path, recorded):
         names.COLLATE_FEATURES,
         names.COLLATE_TARGETS,
         names.collate_head("parser"),
-        names.collate_head("ner") + "/" + names.DEVICE_CALL,
+        names.collate_head("ner"),
         names.QUEUE_WAIT,
         names.TRANSFER,
         names.LOOP_HOST,
         names.LOOP_DISPATCH,
     ):
         assert names.SPAN_PREFIX + key in by_name, (key, sorted(by_name))
+    # no shipped head leaves the host inside make_targets
+    assert not [n for n in by_name if n.endswith("/" + names.DEVICE_CALL)]
 
     # a child lies inside a span of its parent, on the same thread line
     # (a span that was open when the trace began is not in it: its children
@@ -291,6 +295,46 @@ def test_a_part_is_counted_together_with_its_whole():
     assert stats.seconds[names.TRANSFER] == 0.5
 
 
+def test_a_head_that_leaves_the_host_times_the_call(mixed_dir):
+    """The ``make_targets`` contract (``components/base.py``): a head that
+    must leave the host wraps that call in ``span.child(DEVICE_CALL)``. No
+    shipped head does, so a stub keeps the contract under test: the key is
+    ``collate/targets/<head>/device_call``, it arrives together with its
+    whole, and it lies inside its parent on the clock."""
+    from spacy_ray_tpu.pipeline.components.base import Component
+    from spacy_ray_tpu.pipeline.language import Pipeline
+
+    key = names.collate_head("stub") + "/" + names.DEVICE_CALL
+    stats = RecordingStats()
+    early = []  # was the child's key there before its whole had closed?
+
+    class LeavesTheHost(Component):
+        def make_targets(self, examples, B, T, span=None):
+            with span.child(names.DEVICE_CALL):
+                rows = jax.device_get(jnp.zeros((B, T), jnp.int32))
+            early.append(key in stats.seconds)
+            return {"rows": rows}
+
+    nlp = Pipeline.from_config(_config(SM_CFG, mixed_dir))
+    examples = synth_corpus(8, "parser", seed=3)
+    nlp.initialize(lambda: iter(examples), seed=0)
+    nlp.components["stub"] = LeavesTheHost("stub", {})
+    nlp.pipe_names.append("stub")
+
+    batch = nlp.collate(examples, host=True, stats=stats)
+    assert batch["targets"]["stub"]["rows"].shape == batch["tokens"].mask.shape
+    assert early == [False]  # a part is added together with its whole
+    assert stats.counts[key] == 1
+    assert [k for k in stats.counts if k.endswith("/" + names.DEVICE_CALL)] == [key]
+    spans = {stage: (t0, t0 + seconds) for stage, seconds, t0 in stats.spans}
+    child, parent = spans[key], spans[names.collate_head("stub")]
+    assert parent[0] <= child[0] <= child[1] <= parent[1]
+    assert 0 < stats.seconds[key] <= stats.seconds[names.collate_head("stub")]
+    # nothing recorded, nothing opened: the same stub under NO_SPAN
+    bare = nlp.collate(examples, host=True)
+    assert bare["targets"]["stub"]["rows"].shape == batch["tokens"].mask.shape
+
+
 @pytest.mark.parametrize("accumulate", [1, 2])
 def test_spans_a_step_do_not_grow_with_the_batch(mixed_dir, recorded, accumulate):
     """Every span is per batch or per micro-batch, never per document: a
@@ -339,7 +383,8 @@ def test_collate_without_a_stats_handle_records_nothing(mixed_dir, monkeypatch):
     stats = collate_pool.PipelineStats()
     timed = nlp.collate(examples, host=True, stats=stats)
     assert stats.counts[names.COLLATE_FEATURES] == 1
-    assert stats.counts[names.collate_head("ner") + "/" + names.DEVICE_CALL] == 1
+    assert stats.counts[names.collate_head("ner")] == 1
+    assert not [k for k in stats.counts if k.endswith("/" + names.DEVICE_CALL)]
 
     def boom(*a, **k):
         raise AssertionError("a span was made without a stats handle")
