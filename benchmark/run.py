@@ -48,6 +48,9 @@ def main() -> int:
     runner = load_module(".", f"{cell['traffic_file']['kind']}_cell")
     out = runner.run(cell, args)
 
+    # where the set-up went, in seconds from the start of the process
+    print(f"setup phases { {k: round(t - T_PROCESS_START, 3) for k, t in out['setup_marks'].items()} }",
+          flush=True)
     metrics = {}
     if args.trace:
         for metric in cell["per_layer"]:
@@ -76,6 +79,12 @@ def main() -> int:
         line = {"rehearsal": True, "correct": out["correct"], "attempted": out["attempted"],
                 "failed": out["failed"], "metrics": {}, "device": device,
                 "would_report": sorted(metrics)}
+    # each number `correct` compared beside its limit: last in the line, and
+    # the last lines on standard error
+    line["compared"] = out.get("compared", {})
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared {name}: {value} limit {limit}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

@@ -8,7 +8,11 @@ from pathlib import Path
 BENCH = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(BENCH))
 
+import pytest  # noqa: E402
+
+import common  # noqa: E402
 import flops  # noqa: E402
+from common import load_module  # noqa: E402
 
 
 def config(name):
@@ -50,11 +54,55 @@ def test_sm_forward_by_hand():
     assert heads > embed + 4 * layer
 
 
-def test_unknown_shapes_are_errors():
-    import pytest
+# (configuration, train_wps_chip, words of context) -> the metric as PR 25's
+# flops.py gave it (computed with that file, before kinds moved into files)
+FIXED_RECORDS = [
+    ("trf", 29855.0, 171.37, 605356247.04, 9.174066373288934),
+    ("sm", 26369.0, 171.37, 6997056.0, 0.09365754805279188),
+]
 
-    with pytest.raises(ValueError):
-        flops.trunk_forward_flops_per_word({"trunk": "lstm", "width": 8, "embed_tables": 1,
-                                            "embed_mix_pieces": 1}, 0)
-    with pytest.raises(ValueError):
-        flops.heads_forward_flops_per_word({"width": 8, "heads": [{"kind": "spancat"}]})
+
+@pytest.mark.parametrize("name,wps,context,per_word,expected", FIXED_RECORDS)
+def test_model_flops_util_of_a_fixed_record_did_not_move(name, wps, context, per_word, expected):
+    record = {"kind": "train", "train_wps_chip": wps, "config": config(name),
+              "window": {"attention_context_words": context}, "device_kind": "TPU v5 lite"}
+    assert flops.train_flops_per_word(config(name), context) == per_word
+    assert load_module("layer_metrics", "model_flops_util").read(record) == expected
+
+
+STUB_KIND = '''
+def trunk_forward_flops_per_word(shapes, context_words):
+    return 2.0 * shapes["state"] * shapes["state"] * shapes["depth"] + context_words
+
+
+def head_forward_flops_per_word(shapes, head):
+    return 2.0 * shapes["state"] * head["n_spans"]
+'''
+
+
+def test_a_new_kind_is_a_file_and_its_absence_an_error(tmp_path, monkeypatch):
+    """A trunk or head that ``flops.py`` does not know is counted by
+    ``benchmark/flops_kinds/<kind>.py``, added with no edit to ``flops.py``;
+    no such file is a ``BenchError`` that names it."""
+    shapes = {"trunk": "lstm", "state": 8, "depth": 3,
+              "heads": [{"kind": "spancat", "n_spans": 5}, {"kind": "tagger", "n_out": 13}],
+              "width": 8}
+    monkeypatch.setattr(common, "ROOT", tmp_path)
+    monkeypatch.setattr(common, "BENCH", tmp_path / "benchmark")
+    with pytest.raises(common.BenchError, match="benchmark/flops_kinds/lstm.py"):
+        flops.trunk_forward_flops_per_word(shapes, 0)
+    with pytest.raises(common.BenchError, match="benchmark/flops_kinds/spancat.py"):
+        flops.heads_forward_flops_per_word(shapes)
+    kinds = tmp_path / "benchmark" / "flops_kinds"
+    kinds.mkdir(parents=True)
+    (kinds / "lstm.py").write_text(STUB_KIND)
+    (kinds / "spancat.py").write_text(STUB_KIND)
+    assert flops.trunk_forward_flops_per_word(shapes, 7) == 2 * 8 * 8 * 3 + 7
+    assert flops.heads_forward_flops_per_word(shapes) == 2 * 8 * 5 + 2 * 8 * 13
+    assert flops.train_flops_per_word({"shapes": shapes}, 7) == 3 * (391 + 288)
+    # a file that counts trunks only is no count of a head
+    (kinds / "spancat.py").write_text("def trunk_forward_flops_per_word(s, c):\n    return 0.0\n")
+    with pytest.raises(common.BenchError, match="head_forward_flops_per_word"):
+        flops.heads_forward_flops_per_word(shapes)
+    # the kinds counted in flops.py never look for a file
+    assert flops.trunk_forward_flops_per_word(config("sm")["shapes"], 0) == 884_736

@@ -116,6 +116,68 @@ def test_slice_and_cuts():
     assert "bench:slice" not in gaps and s["n_gaps"] == 2
 
 
+def with_kernels():
+    """The hand-made profile with a step program, eleven fusions longer than
+    either kernel, and two of the program's kernels: ``srt_flash_fwd`` twice
+    (``.16`` and ``.17``, 3 + 2 ns) and ``srt_tiny`` once (1 ns), so that
+    neither is among the ten longest rows."""
+    planes = {k: dict(v) for k, v in HAND_MADE.items()}
+    ops = list(HAND_MADE["/device:TPU:0"]["XLA Ops"])
+    ops += [(f"fusion.{100 + i}", 160 + 20 * i, 10) for i in range(11)]  # 160 .. 370
+    ops += [("%srt_flash_fwd.16 = (bf16[64,12,256,128]{3,2,1,0}, f32[64,12,1,256]{3,2,1,0}) "
+             "custom-call(bf16[64,12,256,128]{3,2,1,0} %x)", 600, 3),
+            ("srt_flash_fwd.17", 610, 2), ("%srt_tiny = f32[8]{0} custom-call(f32[8]{0} %y)", 620, 1),
+            ("not_srt_fused.2", 630, 1)]
+    planes["/device:TPU:0"]["XLA Ops"] = ops
+    planes["/device:TPU:0"]["XLA Modules"] = [
+        ("jit_srt_train_step(4589997382407016891)", 0, 150),
+        ("jit__threefry_split(77)", 390, 5),
+        ("jit_srt_train_step(4589997382407016891)", 400, 90),
+        ("jit_srt_train_step(4589997382407016891)", 700, 300)]
+    # the second chip's kernels are not added in: the first chip's plane, like device_ops
+    planes["/device:TPU:1"]["XLA Ops"] = HAND_MADE["/device:TPU:1"]["XLA Ops"] + [
+        ("srt_flash_fwd.16", 600, 3)]
+    return planes
+
+
+def test_kernels_and_steps():
+    s = trace_reduce.reduce_data(profile(with_kernels()))
+    top = [name for name, _ in s["device_ops"]]
+    assert len(top) == 10 and not any(name.startswith("srt_") for name in top)
+    assert s["kernels_s"] == {"srt_flash_fwd": pytest.approx(5e-9), "srt_tiny": pytest.approx(1e-9)}
+    assert s["steps"] == 3
+    # the numbers the accepted readers take are those of the profile without them,
+    # but for the 110 + 7 ns the new operations keep chip 0 busy
+    base = trace_reduce.reduce_data(profile(HAND_MADE))
+    assert s["busy_s_by_chip"]["/device:TPU:0"] == pytest.approx(
+        base["busy_s_by_chip"]["/device:TPU:0"] + 117e-9)
+    assert s["collective_share"] == base["collective_share"]
+    assert base["kernels_s"] == {} and base["steps"] == 0
+
+
+def test_kernels_and_steps_inside_the_slice():
+    """Slice 100-900 with 400-500 cut: the step whose middle lies before the
+    slice and the one inside the cut are not the slice's; the step that began
+    3 ns before the slice (the clocks' skew) is; the kernels at 600-621 are."""
+    planes = with_kernels()
+    planes["/host:CPU"] = {"main": HAND_MADE["/host:CPU"]["main"] + [("bench:slice", 100, 800)]}
+    planes["/device:TPU:0"]["XLA Modules"] = planes["/device:TPU:0"]["XLA Modules"] + [
+        ("jit_srt_train_step(4589997382407016891)", 97, 50)]
+    s = trace_reduce.reduce_data(profile(planes), cuts_s=[(0.3e-6, 0.4e-6)])
+    assert s["steps"] == 2  # 97-147 and 700-1000 (middle 850); not 0-150, not 400-490
+    assert s["kernels_s"] == {"srt_flash_fwd": pytest.approx(5e-9), "srt_tiny": pytest.approx(1e-9)}
+    planes["/host:CPU"] = {"main": [("bench:slice", 100, 503)]}  # ends with the first kernel
+    s = trace_reduce.reduce_data(profile(planes))
+    assert s["kernels_s"] == {"srt_flash_fwd": pytest.approx(3e-9)} and s["steps"] == 2
+
+
+def test_kernel_names():
+    assert trace_reduce.kernel_name("%srt_fused_adam.56 = (f32[8192,128]) custom-call()") == "srt_fused_adam"
+    assert trace_reduce.kernel_name("srt_hash_embed") == "srt_hash_embed"
+    assert trace_reduce.kernel_name("fusion.5") is None
+    assert trace_reduce.kernel_name("%while.202 = (s32[]) while(...)") is None
+
+
 def test_subtract():
     assert trace_reduce.subtract([(0, 10), (20, 30)], [(5, 22), (25, 26)]) == [
         (0, 5), (22, 25), (26, 30)]

@@ -21,6 +21,15 @@ Conventions, fixed here so that every PR computes the same number:
 * a collective is an operation whose name starts with one of ``COLLECTIVES``,
   on the operation line or on ``Async XLA Ops`` (where the TPU puts the
   ``-start``/``-done`` halves of an overlapped one): the union of both;
+* a kernel of the program is an operation whose instruction name starts with
+  ``srt_`` (the ``name=`` of its ``pallas_call``): ``kernels_s`` gives the
+  seconds of EVERY such kernel on the first chip, by name without the ``.N``
+  suffix, however far down the list of operations it comes. A kernel's
+  operation is a leaf, so its duration is its self time. ``steps`` counts the
+  events of the ``XLA Modules`` line of that chip whose name starts with
+  ``jit_srt_train_step`` and whose middle lies inside the window (the host's
+  and the device's clocks differ by microseconds, and the slice's first step
+  begins with the slice): what a reader divides a kernel's seconds by;
 * an idle gap on the first chip belongs to the harness annotation
   (``bench:...``) that covers most of it, if that is at least half, else to
   ``host: other``.
@@ -44,6 +53,9 @@ NOT_OP_LINES = ("Steps", "XLA Modules", "XLA TraceMe", "Framework Name Scope",
                 "Framework Ops", "Source code")
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute", "collective-broadcast")
+MODULE_LINE = "XLA Modules"
+STEP_MODULE = "jit_srt_train_step"
+KERNEL_PREFIX = "srt_"
 ANNOTATION_PREFIX = "bench:"
 TOP = 10
 
@@ -108,6 +120,15 @@ def op_name(text: str) -> str:
     return f"{head} {shape.group(0) if shape else ''}".strip()[:120]
 
 
+def kernel_name(text: str) -> Optional[str]:
+    """``srt_flash_fwd`` for ``%srt_flash_fwd.16 = (bf16[...], ...) custom-call(...)``,
+    None for an operation that is no kernel of the program."""
+    head = text.lstrip("%").partition(" = ")[0]
+    if not head.startswith(KERNEL_PREFIX):
+        return None
+    return re.sub(r"\.\d+$", "", head)
+
+
 def _op_lines(plane: Any) -> List[Any]:
     lines = list(plane.lines)
     named = [l for l in lines if l.name == OP_LINE]
@@ -124,6 +145,7 @@ SLICE = "bench:slice"
 def reduce_data(data: Any, cuts_s: Iterable[Tuple[float, float]] = ()) -> Optional[Dict[str, Any]]:
     """The summary of one profile, or None where no device operation ran."""
     devices: Dict[str, List[Tuple[int, int, str]]] = {}
+    modules: Dict[str, List[Tuple[int, int, str]]] = {}
     background: Dict[str, List[Tuple[int, int, str]]] = {}
     annotations: List[Tuple[int, int, str]] = []
     for plane in data.planes:
@@ -133,6 +155,8 @@ def reduce_data(data: Any, cuts_s: Iterable[Tuple[float, float]] = ()) -> Option
                 devices[plane.name] = events
                 background[plane.name] = [ev for line in plane.lines
                                           if line.name == ASYNC_LINE for ev in _events(line)]
+                modules[plane.name] = [ev for line in plane.lines
+                                       if line.name == MODULE_LINE for ev in _events(line)]
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 annotations.extend(ev for ev in _events(line)
@@ -156,10 +180,18 @@ def reduce_data(data: Any, cuts_s: Iterable[Tuple[float, float]] = ()) -> Option
         collective.append(total(kept(
             (a, b) for a, b, n in events + background[plane_name] if is_collective(n))))
 
-    first = devices[sorted(devices)[0]]
+    first_chip = sorted(devices)[0]
+    first = devices[first_chip]
     by_op: Dict[str, int] = {}
+    by_kernel: Dict[str, int] = {}
     for a, b, name in first:
-        by_op[op_name(name)] = by_op.get(op_name(name), 0) + total(kept([(a, b)]))
+        inside = total(kept([(a, b)]))
+        by_op[op_name(name)] = by_op.get(op_name(name), 0) + inside
+        kernel = kernel_name(name)
+        if kernel is not None and inside:
+            by_kernel[kernel] = by_kernel.get(kernel, 0) + inside
+    steps = sum(1 for a, b, name in modules[first_chip]
+                if name.startswith(STEP_MODULE) and kept([((a + b) // 2, (a + b) // 2 + 1)]))
     merged = merge(kept((a, b) for a, b, _ in first) + holes)  # a hole is no gap
     edges = [t0] + [x for iv in merged for x in iv] + [t1]
     gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
@@ -191,6 +223,8 @@ def reduce_data(data: Any, cuts_s: Iterable[Tuple[float, float]] = ()) -> Option
         "collective_share": sum(collective) / n / window,
         "busy_s_by_chip": {name: b / 1e9 for name, b in zip(devices, busy)},
         "device_ops": top(by_op),
+        "kernels_s": {name: ns / 1e9 for name, ns in sorted(by_kernel.items())},
+        "steps": steps,
         "idle_gaps": top(by_cause),
         "longest_gap_s": max((b - a for a, b in gaps), default=0) / 1e9,
         "n_gaps": len(gaps),

@@ -319,6 +319,10 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
 
     jax.monitoring.register_event_duration_secs_listener(count_on_loop_thread)
 
+    # the set-up's stations: JAX and the chip up, the corpus made and written,
+    # the loop's first update call (corpus read, model built, first batch
+    # collated), the window's opening (program compiled or loaded, warm-up)
+    marks = {"jax_up": time.perf_counter()}
     with WorkDir() as work:
         # ---- data from the seed -------------------------------------------
         train_docs = generator.generate(int(docs_spec["n_train"]), args.seed, docs_spec)
@@ -327,6 +331,7 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
         del train_docs
         write_jsonl(work / "dev.jsonl",
                     generator.generate(int(docs_spec["n_dev"]), args.seed + 1, docs_spec))
+        marks["corpus_written"] = time.perf_counter()
         overrides = {
             **config_file.get("overrides", {}),
             **(config_file.get("rehearse_overrides", {}) if rehearsal else {}),
@@ -416,10 +421,11 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
             problems.append(f"the step ran on {residency.get('params', {}).get('devices')} "
                             f"devices, the cell asks for {chips}")
         expected = config_file["expect_runtime"][str(chips)]
-        if not rehearsal:
-            for key, prefix in expected.items():
-                if not str(runtime.get(key, "")).startswith(prefix):
-                    problems.append(f"runtime {key}: {runtime.get(key)!r}, expected {prefix!r}")
+        mismatched = [] if rehearsal else [
+            f"runtime {key}: {runtime.get(key)!r}, expected {prefix!r}"
+            for key, prefix in expected.items()
+            if not str(runtime.get(key, "")).startswith(prefix)]
+        problems.extend(mismatched)
         non_finite = sum(1 for x in losses if not math.isfinite(x))
         if non_finite:
             problems.append(f"{non_finite} non-finite losses")
@@ -440,9 +446,23 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
 
         trunk = trunk_check.check(
             nlp, nlp.params, cell["config"],
-            generator.generate(trunk_check.N_SEQUENCES, args.seed + 7919, docs_spec))
+            generator.generate(trunk_check.N_SEQUENCES, args.seed + 7919, docs_spec),
+            seed=args.seed)
         if not trunk["ok"]:
             problems.append(f"trunk differs from the reference: {trunk}")
+        # every number `correct` compared, beside its limit
+        compared = {
+            "devices": [residency.get("params", {}).get("devices"), chips],
+            "residency_problems": [len(residency["problems"]), 0],
+            "runtime_mismatches": [len(mismatched), 0],
+            "non_finite_losses": [non_finite, 0],
+            "loss_last_third_below_first": [loss_means["last_third_mean"],
+                                            loss_means["first_third_mean"]],
+            "words_counted_equal_loop": [counted, result.words_seen],
+            "compiles_in_window": [window["compiles"], MAX_COMPILES_IN_WINDOW],
+            "words_taken_of_corpus": [counted, corpus_words],
+            **trunk_check.compared(trunk, cell["config"]),
+        }
 
     wps_chip = window["words"] / window["seconds"] / chips
     blocked = spy.blocked_done_at
@@ -458,6 +478,10 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
           f"{corpus_words}, taken {counted}; blocked step intervals (s) "
           f"{[round(x, 3) for x in record['step_intervals_s']]}", flush=True)
     print(f"runtime {runtime}", flush=True)
+    if trace_summary:
+        print("trace " + str({k: trace_summary[k] for k in (
+            "chips", "window_s", "busy_s", "busy_s_by_chip", "collective_s", "steps",
+            "kernels_s", "longest_gap_s", "annotation_s")}), flush=True)
     print(f"losses first/last third {loss_means}; steps {len(losses)} "
           f"(warm-up {spy.edges['open']['step']}); trunk {trunk}; residency "
           f"{ {k: v for k, v in residency.items() if k != 'problems'} }", flush=True)
@@ -465,10 +489,13 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
         print(f"NOT CORRECT: {problems}", flush=True)
     return {
         "correct": not problems,
+        "compared": compared,
         "attempted": len(losses),
         "failed": non_finite,
         "end_to_end": {"train_wps_chip": wps_chip},
         "window_open_at": spy.t_run_open,
+        "setup_marks": dict(marks, first_update_call=spy.t_first_call,
+                            window_open=spy.t_run_open),
         "record": record,
         "memory_peaks": peaks,
     }
