@@ -134,7 +134,7 @@ def runtime_report(nlp: Any = None) -> Dict[str, Any]:
     import jax
 
     from . import native
-    from .models.transformer import pipeline_compute_dtype
+    from .models.shadow import pipeline_compute_dtype
     from .ops.flash_attention import flash_attention_status
     from .ops.pallas_kernels import hash_embed_status
 

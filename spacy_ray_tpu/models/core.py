@@ -43,20 +43,26 @@ class Context:
     call with ``[training] dropout`` before each update (reference
     worker.py:181 passes it into ``train_while_improving``). ``None``
     (the predict path and direct ``apply`` calls) keeps each layer's own
-    configured rate."""
+    configured rate.
+
+    ``metrics`` is an optional sink for device counters a layer makes while
+    it is traced (the routed trunk's assignments and loads); the loss
+    builder hands them on in the step's ``metrics``, so they leave the
+    device with the losses and cost no synchronisation of their own."""
 
     train: bool = False
     rng: Optional[jax.Array] = None
     aux_losses: Optional[list] = None
     dropout: Optional[float] = None
+    metrics: Optional[dict] = None
 
     def split(self) -> Tuple["Context", "Context"]:
         if self.rng is None:
             return self, self
         r1, r2 = jax.random.split(self.rng)
         return (
-            Context(self.train, r1, self.aux_losses, self.dropout),
-            Context(self.train, r2, self.aux_losses, self.dropout),
+            Context(self.train, r1, self.aux_losses, self.dropout, self.metrics),
+            Context(self.train, r2, self.aux_losses, self.dropout, self.metrics),
         )
 
     def dropout_rate(self, configured: float) -> float:
@@ -67,6 +73,12 @@ class Context:
     def add_aux_loss(self, value) -> None:
         if self.aux_losses is not None:
             self.aux_losses.append(value)
+
+    def add_metrics(self, values: dict) -> None:
+        """Counters to add up: a key met twice is summed."""
+        if self.metrics is not None:
+            for key, value in values.items():
+                self.metrics[key] = self.metrics[key] + value if key in self.metrics else value
 
 
 @dataclass

@@ -1,0 +1,443 @@
+"""Latent-attention trunk with sigmoid-routed experts: the second
+architecture the trunk slot takes (``spacy_ray_tpu.LatentMoETrunk.v1``).
+
+A pre-norm decoder stack as the DeepSeek-V3 family publishes it (RMSNorm,
+rotary positions, multi-head latent attention, SwiGLU, ``first_dense``
+leading dense layers, then layers of routed experts chosen top-k by sigmoid
+scores beside shared experts), used as the pipeline's shared trunk: one
+vector a word, the heads listen to it as they do to the encoder of
+``models/transformer.py``. The equations are ISSUE 27's and
+``benchmark/reference/kanana2_a3b.py`` is their plain form; this module is
+the one the program trains.
+
+**The chip's share.** The layer is told which experts it holds: rank
+``expert_rank`` of ``n_experts / experts_held`` holds experts
+``[rank * held, (rank + 1) * held)``. It routes every word over ALL
+``n_experts`` (the router keeps its published width and top-k), computes the
+terms of the sum whose expert it holds, and adds the shared experts. What
+the absent experts would add is left out; nothing stands in for the other
+chips or for their exchange.
+
+**Dispatch without a capacity.** The (word, choice) pairs are sorted by held
+expert (pairs on absent experts and on padding sort last), the rows gathered
+in that order, and ONE grouped product (``jax.lax.ragged_dot``) runs over the
+experts held: group sizes vary from step to step, shapes do not (the buffer
+has the static size ``N x top_k``, which no routing exceeds). No pair is ever
+dropped. Sorting is a permutation, so un-sorting is the inverse permutation:
+dispatch and combine are gathers in both directions (``_permute``), where a
+scatter-add of ``N x top_k`` rows would serialise on the chip.
+
+Float32 where it decides something: the residual stream, every RMSNorm, the
+router (``h W_r`` at precision ``highest``, sigmoid, top-k, weights), the
+softmax. The matrix products run in the compute dtype (bfloat16 on a TPU).
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from functools import partial
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .. import names
+from ..ops.hashing import hash_embed_ids, hash_string_u64
+from ..registry import registry
+from ..types import Padded, TokenBatch
+from .core import Context, Model, normal_init
+from .shadow import _resolve_compute_dtype, register_trunk_leaves
+from .tok2vec import attr_index
+
+MATMUL_LEAVES = (
+    "q_W", "kva_W", "kvb_W", "ao_W",  # latent attention
+    "g_W", "u_W", "d_W",  # dense gated FFN
+    "eg_W", "eu_W", "ed_W",  # the experts held, stacked [held, ., .]
+    "sg_W", "su_W", "sd_W",  # shared experts, side by side
+)
+register_trunk_leaves(
+    shadow=MATMUL_LEAVES,
+    # norm gains, the router and its selection bias feed float32 ops
+    f32=("rms1_g", "rms2_g", "rmskv_g", "router_W", "router_b"),
+    # none of this trunk's products goes through the int8 kernel: an "int8"
+    # label over it would be false, so that overlay refuses the tree
+    int8_unsupported=MATMUL_LEAVES,
+)
+
+# one row a word: the NORM key reduced into the table by the program's hashing
+EMBED_SEED = hash_string_u64("latent-moe-embed-NORM") & 0x7FFFFFFF
+# what an expert layer counts, in this order (device counters: names.py)
+COUNTER_KEYS = (
+    names.MOE_ASSIGNMENTS, names.MOE_ASSIGNMENTS_HELD, names.MOE_COMPUTED,
+    names.MOE_MAX_LOAD, names.MOE_LAYER_CALLS,
+)
+N_COUNTERS = len(COUNTER_KEYS)
+
+
+@dataclass(frozen=True)
+class Shape:
+    """The trunk's static sizes (python ints: they specialise the program)."""
+
+    width: int
+    n_heads: int
+    qk_nope: int
+    qk_rope: int
+    v_head: int
+    kv_rank: int
+    dense_ffn: int
+    expert_ffn: int
+    n_experts: int
+    experts_held: int
+    expert_rank: int
+    top_k: int
+    n_shared: int
+    route_scale: float
+    first_dense: int
+    depth: int
+    vocab_rows: int
+    rope_theta: float
+    rms_eps: float = 1e-6
+
+    @property
+    def held_from(self) -> int:
+        return self.expert_rank * self.experts_held
+
+
+def rms_norm(x: jnp.ndarray, g: jnp.ndarray, eps: float) -> jnp.ndarray:
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * g
+
+
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotate adjacent pairs ``(2i, 2i+1)`` of the last axis by
+    ``pos * theta^(-2i/d)``. x [B, T, H, d], positions [B, T]; float32."""
+    d = x.shape[-1]
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions.astype(jnp.float32)[:, :, None, None] * freq  # [B, T, 1, d/2]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x = x.astype(jnp.float32)
+    a, b = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1).reshape(x.shape)
+
+
+def _gated(h16: jnp.ndarray, wg, wu, wd, cd) -> jnp.ndarray:
+    """``(silu(h Wg) * (h Wu)) Wd``: products in the compute dtype, the
+    activation in float32."""
+    gate = (h16 @ wg.astype(cd)).astype(jnp.float32)
+    up = (h16 @ wu.astype(cd)).astype(jnp.float32)
+    return ((jax.nn.silu(gate) * up).astype(cd) @ wd.astype(cd)).astype(jnp.float32)
+
+
+def latent_attention(p, h: jnp.ndarray, mask, positions, s: Shape, cd) -> jnp.ndarray:
+    """h [B, T, D] float32 (normed) -> [B, T, D] float32. Causal, as
+    published; ONE rotary key shared by all heads."""
+    from ..ops.flash_attention import attention
+
+    B, T, _ = h.shape
+    H = s.n_heads
+    h16 = h.astype(cd)
+    q = (h16 @ p["q_W"].astype(cd)).reshape(B, T, H, s.qk_nope + s.qk_rope)
+    kva = h16 @ p["kva_W"].astype(cd)
+    c = rms_norm(kva[..., : s.kv_rank], p["rmskv_g"], s.rms_eps)
+    kv = (c.astype(cd) @ p["kvb_W"].astype(cd)).reshape(B, T, H, s.qk_nope + s.v_head)
+    with jax.named_scope(names.SCOPE_ATTN_ROPE):
+        q_pe = rope(q[..., s.qk_nope:], positions, s.rope_theta).astype(cd)
+        k_pe = rope(kva[..., None, s.kv_rank:], positions, s.rope_theta).astype(cd)
+    q = jnp.concatenate([q[..., : s.qk_nope], q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., : s.qk_nope], jnp.broadcast_to(k_pe, (B, T, H, s.qk_rope))], axis=-1)
+    out = attention(q, k, kv[..., s.qk_nope:], mask, causal=True)
+    return (out.reshape(B, T, H * s.v_head) @ p["ao_W"].astype(cd)).astype(jnp.float32)
+
+
+@jax.custom_vjp
+def _permute(x: jnp.ndarray, perm: jnp.ndarray, inverse: jnp.ndarray) -> jnp.ndarray:
+    """``x[perm]`` for a permutation whose inverse is known: the transpose
+    of a gather by a permutation is the gather by its inverse, so neither
+    direction needs a scatter."""
+    return x[perm]
+
+
+def _permute_fwd(x, perm, inverse):
+    return x[perm], inverse
+
+
+def _permute_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def route(p, h: jnp.ndarray, s: Shape) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """h [N, D] float32 -> (chosen experts [N, top_k] int32, their weights
+    [N, top_k] float32). Scores are sigmoids; the bias moves the SELECTION
+    only (no gradient reaches it); the weights are the chosen scores,
+    normalised and scaled."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        h.astype(jnp.float32), p["router_W"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(p["router_b"]), s.top_k)
+    chosen = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = s.route_scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), weights
+
+
+def routed_experts(p, h: jnp.ndarray, token_mask, idx, weights, s: Shape, cd):
+    """The held experts' part of ``sum_k w_k Expert_k(h)``. h [N, D] float32,
+    token_mask [N] bool, idx / weights [N, top_k]. Returns ([N, D] float32,
+    counters int32 [N_COUNTERS])."""
+    N, D = h.shape
+    K, held = s.top_k, s.experts_held
+    P = N * K
+    with jax.named_scope(names.SCOPE_MOE_DISPATCH):
+        local = idx - s.held_from
+        valid = (local >= 0) & (local < held) & token_mask[:, None]  # [N, K]
+        key = jnp.where(valid, local, held).reshape(P)  # absent or padding: last
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros((P,), jnp.int32).at[order].set(
+            jnp.arange(P, dtype=jnp.int32), unique_indices=True)
+        group_sizes = jnp.sum(
+            key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :], axis=0, dtype=jnp.int32)
+        # rows past the last group belong to no expert: the grouped product
+        # owes them nothing, so they are zeroed going in, between and coming out
+        live = (jnp.arange(P, dtype=jnp.int32) < jnp.sum(group_sizes))[:, None]
+        rows = _permute(jnp.repeat(h.astype(cd), K, axis=0), order, inverse)
+        rows = jnp.where(live, rows, 0)
+    with jax.named_scope(names.SCOPE_MOE_EXPERTS):
+        grouped = partial(jax.lax.ragged_dot, group_sizes=group_sizes)
+        gate = grouped(rows, p["eg_W"].astype(cd)).astype(jnp.float32)
+        up = grouped(rows, p["eu_W"].astype(cd)).astype(jnp.float32)
+        inner = jnp.where(live, jax.nn.silu(gate) * up, 0).astype(cd)
+        out_rows = jnp.where(live, grouped(inner, p["ed_W"].astype(cd)), 0)
+    with jax.named_scope(names.SCOPE_MOE_COMBINE):
+        pairs = _permute(out_rows, inverse, order).reshape(N, K, D)
+        w = jnp.where(valid, weights, 0.0)[..., None]
+        y = jnp.sum(pairs.astype(jnp.float32) * w, axis=1)
+        # counted from what the product gave back, not from the mask it was
+        # given: a pair whose expert's output is all nought was not computed
+        computed = jnp.sum(valid & jnp.any(pairs != 0, axis=-1), dtype=jnp.int32)
+    counters = jnp.stack([
+        jnp.sum(token_mask, dtype=jnp.int32) * K,
+        jnp.sum(valid, dtype=jnp.int32),
+        computed,
+        jnp.max(group_sizes),
+        jnp.int32(1),
+    ])
+    return y, counters
+
+
+def apply_layer(p, x, mask, positions, *, s: Shape, cd, routed: bool):
+    """One block. x [B, T, D] float32. Returns (x, counters int32
+    [N_COUNTERS], chosen experts [B*T, top_k] int32): the last two are
+    noughts for a dense layer, so that both kinds have one signature."""
+    B, T, D = x.shape
+    with jax.named_scope(names.SCOPE_ATTN):
+        x = x + latent_attention(
+            p, rms_norm(x, p["rms1_g"], s.rms_eps), mask, positions, s, cd)
+    h = rms_norm(x, p["rms2_g"], s.rms_eps)
+    if not routed:
+        with jax.named_scope(names.SCOPE_DENSE_FFN):
+            x = x + _gated(h.astype(cd), p["g_W"], p["u_W"], p["d_W"], cd)
+        return x, jnp.zeros((N_COUNTERS,), jnp.int32), jnp.zeros((B * T, s.top_k), jnp.int32)
+    h2 = h.reshape(B * T, D)
+    with jax.named_scope(names.SCOPE_MOE_ROUTER):
+        idx, weights = route(p, h2, s)
+    y, counters = routed_experts(p, h2, mask.reshape(B * T), idx, weights, s, cd)
+    with jax.named_scope(names.SCOPE_MOE_SHARED):
+        y = y + _gated(h2.astype(cd), p["sg_W"], p["su_W"], p["sd_W"], cd)
+    return x + y.reshape(B, T, D), counters, idx
+
+
+def trunk_forward(
+    params, ids, mask, positions, s: Shape, *, compute_dtype=jnp.float32,
+    remat: bool = False, scan_layers: bool = True,
+):
+    """ids / mask / positions [B, T] -> (X [B, T, D] float32 with padded
+    positions zeroed, counters int32 [N_COUNTERS] summed over the expert
+    layers, chosen experts [expert layers, B, T, top_k] int32). ``remat``
+    keeps only each layer's input for the backward pass and recomputes the
+    rest (the least memory: the step of the one cell that runs this trunk
+    leaves no room for more)."""
+    B, T = ids.shape
+    with jax.named_scope(names.SCOPE_EMBED):
+        x = params["E"][ids].astype(jnp.float32) * mask[..., None].astype(jnp.float32)
+
+    def layer_fn(routed: bool):
+        fn = partial(apply_layer, s=s, cd=compute_dtype, routed=routed)
+        return jax.checkpoint(fn) if remat else fn
+
+    with jax.named_scope(names.SCOPE_TRUNK):
+        dense, routed = layer_fn(False), layer_fn(True)
+        for i in range(s.first_dense):
+            x, _, _ = dense(params[f"layer_{i}"], x, mask, positions)
+        n_routed = s.depth - s.first_dense
+        counters = jnp.zeros((N_COUNTERS,), jnp.int32)
+        if scan_layers and n_routed > 1:
+            # ONE layer body over stacked parameters (storage stays layer_i)
+            stacked = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs),
+                *[params[f"layer_{i}"] for i in range(s.first_dense, s.depth)])
+
+            def body(carry, lp):
+                x, counters = carry
+                x, c, idx = routed(lp, x, mask, positions)
+                return (x, counters + c), idx
+
+            (x, counters), choices = jax.lax.scan(body, (x, counters), stacked)
+        else:
+            chosen = []
+            for i in range(s.first_dense, s.depth):
+                x, c, idx = routed(params[f"layer_{i}"], x, mask, positions)
+                counters = counters + c
+                chosen.append(idx)
+            choices = (jnp.stack(chosen) if chosen
+                       else jnp.zeros((0, B * T, s.top_k), jnp.int32))
+        x = rms_norm(x, params["rms_f_g"], s.rms_eps)
+        x = x * mask[..., None].astype(x.dtype)
+    return x, counters, choices.reshape(-1, B, T, s.top_k)
+
+
+def init_params(rng, s: Shape, std: float = 0.02):
+    H = s.n_heads
+    rngs = jax.random.split(rng, s.depth + 1)
+    params: Dict[str, Any] = {
+        "E": normal_init(rngs[0], (s.vocab_rows, s.width), std),
+        "rms_f_g": jnp.ones((s.width,)),
+    }
+    for i in range(s.depth):
+        r = jax.random.split(rngs[i + 1], 12)
+        layer = {
+            "rms1_g": jnp.ones((s.width,)),
+            "rms2_g": jnp.ones((s.width,)),
+            "rmskv_g": jnp.ones((s.kv_rank,)),
+            "q_W": normal_init(r[0], (s.width, H * (s.qk_nope + s.qk_rope)), std),
+            "kva_W": normal_init(r[1], (s.width, s.kv_rank + s.qk_rope), std),
+            "kvb_W": normal_init(r[2], (s.kv_rank, H * (s.qk_nope + s.v_head)), std),
+            "ao_W": normal_init(r[3], (H * s.v_head, s.width), std),
+        }
+        if i < s.first_dense:
+            layer.update(
+                g_W=normal_init(r[4], (s.width, s.dense_ffn), std),
+                u_W=normal_init(r[5], (s.width, s.dense_ffn), std),
+                d_W=normal_init(r[6], (s.dense_ffn, s.width), std),
+            )
+        else:
+            shared = s.n_shared * s.expert_ffn
+            layer.update(
+                router_W=normal_init(r[4], (s.width, s.n_experts), std),
+                # selection only; stays at its seeded value (no gradient)
+                router_b=normal_init(r[5], (s.n_experts,), std),
+                eg_W=normal_init(r[6], (s.experts_held, s.width, s.expert_ffn), std),
+                eu_W=normal_init(r[7], (s.experts_held, s.width, s.expert_ffn), std),
+                ed_W=normal_init(r[8], (s.experts_held, s.expert_ffn, s.width), std),
+                sg_W=normal_init(r[9], (s.width, shared), std),
+                su_W=normal_init(r[10], (s.width, shared), std),
+                sd_W=normal_init(r[11], (shared, s.width), std),
+            )
+        params[f"layer_{i}"] = layer
+    return params
+
+
+def word_rows(attr_keys: jnp.ndarray, vocab_rows: int) -> jnp.ndarray:
+    """A word's row of the table: its NORM key, hashed, modulo the rows."""
+    return hash_embed_ids(attr_keys[..., attr_index("NORM"), :], EMBED_SEED, vocab_rows)[..., 0]
+
+
+def word_positions(mask: jnp.ndarray) -> jnp.ndarray:
+    """A word's index in its document: a row of the batch is one document."""
+    B, T = mask.shape
+    return jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
+
+
+@registry.architectures("spacy_ray_tpu.LatentMoETrunk.v1")
+def LatentMoETrunk(
+    width: int = 2048,
+    n_heads: int = 32,
+    qk_nope: int = 128,
+    qk_rope: int = 64,
+    v_head: int = 128,
+    kv_rank: int = 512,
+    dense_ffn: int = 6144,
+    expert_ffn: int = 768,
+    n_experts: int = 128,
+    experts_held: int = 16,
+    expert_rank: int = 0,
+    top_k: int = 6,
+    n_shared: int = 2,
+    route_scale: float = 2.448,
+    first_dense: int = 1,
+    depth: int = 5,
+    vocab_rows: int = 16032,
+    rope_theta: float = 1e6,
+    remat: bool = True,
+    compute_dtype: str = "auto",
+) -> Model:
+    """tok2vec-compatible trunk (module docstring). ``experts_held`` of the
+    ``n_experts`` live here, those of rank ``expert_rank``; the router keeps
+    its width and ``top_k``. ``remat`` keeps only each layer's input for
+    the backward pass."""
+    if n_experts % experts_held or not 0 <= expert_rank < n_experts // experts_held:
+        raise ValueError(
+            f"experts_held {experts_held} must divide n_experts {n_experts}, and "
+            f"expert_rank {expert_rank} be one of its {n_experts // max(experts_held, 1)} ranks")
+    if not 0 <= first_dense <= depth or top_k > n_experts or qk_rope % 2:
+        raise ValueError("need 0 <= first_dense <= depth, top_k <= n_experts, an even qk_rope")
+    s = Shape(
+        width=width, n_heads=n_heads, qk_nope=qk_nope, qk_rope=qk_rope, v_head=v_head,
+        kv_rank=kv_rank, dense_ffn=dense_ffn, expert_ffn=expert_ffn, n_experts=n_experts,
+        experts_held=experts_held, expert_rank=expert_rank, top_k=top_k, n_shared=n_shared,
+        route_scale=float(route_scale), first_dense=first_dense, depth=depth,
+        vocab_rows=vocab_rows, rope_theta=float(rope_theta))
+    n_routed = depth - first_dense
+
+    def forward(params, batch: TokenBatch):
+        return trunk_forward(
+            params, word_rows(batch.attr_keys, vocab_rows), batch.mask,
+            word_positions(batch.mask), s,
+            compute_dtype=_resolve_compute_dtype(compute_dtype),
+            remat=remat)
+
+    def apply_fn(params, batch: TokenBatch, ctx: Context) -> Padded:
+        X, counters, _ = forward(params, batch)
+        if n_routed:
+            ctx.add_metrics(dict(zip(COUNTER_KEYS, counters)))
+        return Padded(X=X, mask=batch.mask)
+
+    def routing_choices(params, batch: TokenBatch) -> jnp.ndarray:
+        """The experts the trunk chose, [expert layers, B, T, top_k]: the same
+        arrays the counters are made from (an evaluation forward)."""
+        return forward(params, batch)[2]
+
+    return Model(
+        "latent_moe_trunk",
+        lambda rng: init_params(rng, s),
+        apply_fn,
+        dims={"nO": width, "depth": depth, "n_heads": n_heads},
+        meta={
+            "compute_dtype_name": compute_dtype,
+            "shape": s,
+            "routing_choices": routing_choices,
+            **({names.SUMMARISE_COUNTERS: partial(
+                moe_summary, experts_held=experts_held, n_experts=n_experts)} if n_routed else {}),
+        },
+    )
+
+
+def moe_summary(totals: Dict[str, float], experts_held: int, n_experts: int) -> Dict[str, Any]:
+    """What a run's summed counters come to, for ``TrainResult.resolved``:
+    the ``moe`` block (``dropped`` is the pairs that landed on a held expert
+    and whose output did not come back; the loads are rows a step and layer)
+    and two flat keys a record's expectations can be held to."""
+    calls = max(int(totals.get(names.MOE_LAYER_CALLS, 0)), 1)
+    held = int(totals.get(names.MOE_ASSIGNMENTS_HELD, 0))
+    moe = {
+        "assignments": int(totals.get(names.MOE_ASSIGNMENTS, 0)),
+        "assignments_held": held,
+        "dropped": held - int(totals.get(names.MOE_COMPUTED, 0)),
+        "max_expert_load": totals.get(names.MOE_MAX_LOAD, 0) / calls,
+        "mean_expert_load": held / experts_held / calls,
+        "layer_calls": calls,
+    }
+    return {"moe": moe, "moe_dropped": str(moe["dropped"]),
+            "moe_dispatch": f"sorted, ragged_dot, {experts_held} of {n_experts} held"}

@@ -23,7 +23,7 @@ worker.py:91/176-189). Differences, deliberate and TPU-native:
 
 from __future__ import annotations
 
-from typing import Any, List, Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +35,18 @@ from ..ops import ops as O
 from ..types import Padded, TokenBatch
 from ..parallel import context as pctx
 from .core import Context, Model, glorot_uniform, normal_init
+from .shadow import (  # noqa: F401  (re-exported: the names' old home)
+    INT8_UNSUPPORTED_LEAF_NAMES,
+    SHADOW_LEAF_NAMES,
+    TRUNK_F32_LEAF_NAMES,
+    _resolve_compute_dtype,
+    build_param_shadow,
+    int8_unsupported_leaves,
+    pipeline_compute_dtype,
+    pipeline_shadow_dtype,
+    register_trunk_leaves,
+    shadow_coverage,
+)
 from .tok2vec import MultiHashEmbed, ATTRS
 
 
@@ -135,107 +147,30 @@ def _moe_ffn(p, h: jnp.ndarray, token_mask: jnp.ndarray, *,
     return y, aux
 
 
-# Leaves the bf16 parameter shadow covers: every weight/bias the layer
-# stack casts to the compute dtype each step (matmul operands + the biases
-# added to matmul outputs). LN params and the router stay f32 (they feed
-# fp32 ops), embeddings/positions are consumed in f32 by the embed path.
-SHADOW_LEAF_NAMES = frozenset({
-    "qkv_W", "qkv_b", "o_W", "o_b",
-    "ffn_W1", "ffn_b1", "ffn_W2", "ffn_b2",
-    "e_W1", "e_b1", "e_W2", "e_b2",
-})
-
-# Trunk leaves that stay f32 BY DESIGN (they feed fp32 ops): layer norms
-# and the MoE router. A layer leaf in neither set is UNKNOWN to the
-# shadow scheme — a serving precision overlay must refuse rather than
-# ship a tree it only half understands (serving/overlay.py).
-TRUNK_F32_LEAF_NAMES = frozenset({
-    "ln1_g", "ln1_b", "ln2_g", "ln2_b", "router_W",
-})
+# The leaf-name sets of the bf16 shadow live in models/shadow.py (one
+# definition for every trunk); this trunk registers its own there. Shadow:
+# every weight/bias the layer stack casts to the compute dtype each step
+# (matmul operands + the biases added to matmul outputs). f32 BY DESIGN: LN
+# params and the router (they feed fp32 ops); embeddings/positions are
+# consumed in f32 by the embed path and are no layer leaves.
+register_trunk_leaves(
+    shadow=(
+        "qkv_W", "qkv_b", "o_W", "o_b",
+        "ffn_W1", "ffn_b1", "ffn_W2", "ffn_b2",
+        "e_W1", "e_b1", "e_W2", "e_b2",
+    ),
+    f32=("ln1_g", "ln1_b", "ln2_g", "ln2_b", "router_W"),
+    # the MoE expert weights flow through einsum contractions the int8
+    # kernel does not implement, and an "int8" label over a trunk whose
+    # parameter mass stays f32 would be a false claim (the overlay REFUSES
+    # MoE trunks instead; test-enforced)
+    int8_unsupported=("e_W1", "e_W2"),
+)
 
 # Leaves the int8 weight-only serving overlay quantizes: the DENSE 2-D
 # matmul weights (the bandwidth-bound operands a small serving batch
-# re-streams from HBM every dispatch). Biases stay f32 (weight-only),
-# and the MoE expert weights are deliberately NOT covered — they flow
-# through einsum contractions the int8 kernel does not implement, and
-# an "int8" label over a trunk whose parameter mass stays f32 would be
-# a false claim (the overlay REFUSES MoE trunks instead; test-enforced).
+# re-streams from HBM every dispatch). Biases stay f32 (weight-only).
 INT8_LEAF_NAMES = frozenset({"qkv_W", "o_W", "ffn_W1", "ffn_W2"})
-INT8_UNSUPPORTED_LEAF_NAMES = frozenset({"e_W1", "e_W2"})
-
-
-def shadow_coverage(params) -> "Tuple[int, List[str]]":
-    """Audit a param tree against the shadow scheme: returns
-    ``(n_eligible, unknown)`` where ``n_eligible`` counts f32 trunk
-    leaves :func:`build_param_shadow` would overlay and ``unknown``
-    lists the paths of ``layer_i`` leaves in neither SHADOW_LEAF_NAMES
-    nor TRUNK_F32_LEAF_NAMES. Non-empty ``unknown`` means the overlay's
-    coverage claim would be false for this model — callers fall back to
-    f32 with an honest label instead of serving a partial overlay."""
-    eligible = 0
-    unknown: List[str] = []
-
-    def rec(node, in_layer, path):
-        nonlocal eligible
-        for k, v in node.items():
-            if isinstance(v, dict):
-                rec(v, in_layer or str(k).startswith("layer_"), path + (str(k),))
-            elif in_layer:
-                if k in SHADOW_LEAF_NAMES:
-                    if jnp.asarray(v).dtype == jnp.float32:
-                        eligible += 1
-                elif k not in TRUNK_F32_LEAF_NAMES:
-                    unknown.append("/".join(path + (str(k),)))
-
-    rec(params, False, ())
-    return eligible, unknown
-
-
-def build_param_shadow(params, dtype=jnp.bfloat16):
-    """Nested sub-tree of ``params`` holding ``dtype`` copies of every
-    transformer matmul weight (SHADOW_LEAF_NAMES under a ``layer_i`` dict).
-
-    The train step overlays this shadow onto the f32 master params for the
-    forward/backward pass: the layer stack's per-step (and, under remat,
-    per-backward) ``astype(compute_dtype)`` of the whole trunk becomes a
-    no-op, replaced by ONE incremental refresh of the shadow inside the
-    same jitted update (parallel/step.py). Returns None when nothing
-    qualifies (no transformer trunk in the tree)."""
-
-    def rec(node, in_layer):
-        out = {}
-        for k, v in node.items():
-            if isinstance(v, dict):
-                sub = rec(v, in_layer or str(k).startswith("layer_"))
-                if sub:
-                    out[k] = sub
-            elif (
-                in_layer
-                and k in SHADOW_LEAF_NAMES
-                and jnp.asarray(v).dtype == jnp.float32
-            ):
-                out[k] = v.astype(dtype)
-        return out
-
-    return rec(params, False) or None
-
-
-def int8_unsupported_leaves(params) -> "List[str]":
-    """Paths of trunk leaves the int8 overlay cannot cover (MoE expert
-    weights). Non-empty means :func:`build_int8_overlay` must not run:
-    the overlay would quantize the dense shell of a model whose weight
-    mass lives in the experts, and the label would lie."""
-    out: List[str] = []
-
-    def rec(node, in_layer, path):
-        for k, v in node.items():
-            if isinstance(v, dict):
-                rec(v, in_layer or str(k).startswith("layer_"), path + (str(k),))
-            elif in_layer and k in INT8_UNSUPPORTED_LEAF_NAMES:
-                out.append("/".join(path + (str(k),)))
-
-    rec(params, False, ())
-    return out
 
 
 def build_int8_overlay(params) -> "Tuple[Any, int]":
@@ -288,55 +223,6 @@ def _wdot(h: jnp.ndarray, leaf, compute_dtype) -> jnp.ndarray:
 
         return int8_matmul(h, leaf["q8"], leaf["scale"]).astype(compute_dtype)
     return h @ leaf.astype(compute_dtype)
-
-
-def _trunk_compute_dtypes(nlp) -> List[Any]:
-    """The resolved compute dtype of every transformer trunk in the
-    pipeline ("auto" depends on the backend), in pipeline order."""
-    out = []
-    for comp in nlp.components.values():
-        model = getattr(comp, "model", None)
-        if model is None:
-            continue
-        for m in model.walk():
-            name = m.meta.get("compute_dtype_name")
-            if name:
-                out.append(_resolve_compute_dtype(name))
-    return out
-
-
-def pipeline_shadow_dtype(nlp) -> Optional[Any]:
-    """bfloat16 when some transformer trunk in the pipeline resolves its
-    compute dtype to bf16 (the only case a bf16 shadow is numerics-
-    preserving), else None — the ``[training] bf16_shadow = "auto"``
-    decision point."""
-    return jnp.bfloat16 if jnp.bfloat16 in _trunk_compute_dtypes(nlp) else None
-
-
-def pipeline_compute_dtype(nlp) -> str:
-    """What ``compute_dtype`` resolved to for THIS pipeline's trunks on
-    this backend, for the run's records — not what "auto" would give."""
-    names = sorted({jnp.dtype(d).name for d in _trunk_compute_dtypes(nlp)})
-    return " + ".join(names) or "n/a (no transformer trunk)"
-
-
-def _resolve_compute_dtype(name: str):
-    """Matmul compute dtype: "auto" picks bfloat16 on accelerators (native
-    MXU dtype) and float32 on CPU, where bf16 buys nothing (the matmul
-    microbench runs at identical GFLOP/s in both dtypes) and the
-    activation/weight casts cost real time (profile_trf.py measured the
-    f32 path 15% faster at B=8/T=64 — PERF.md §MFU)."""
-    if name == "auto":
-        return (
-            jnp.float32 if jax.default_backend() == "cpu" else jnp.bfloat16
-        )
-    table = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
-    if name not in table:
-        raise ValueError(
-            "compute_dtype must be one of ['auto', 'bfloat16', 'float32'], "
-            f"got {name!r}"
-        )
-    return table[name]
 
 
 def apply_transformer_layer(

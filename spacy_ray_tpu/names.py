@@ -49,12 +49,38 @@ SCOPE_TRUNK = "trunk"  # the encoder: CNN window layers or the transformer stack
 SCOPE_LOSS = "loss"  # the sum of the heads' losses and the auxiliary terms
 SCOPE_UPDATE = "update"  # optimizer apply (fused or not), shadow refresh, grad norm
 SCOPE_GRAD_ACCUM = "grad_accum"  # the scan over micro-batches
+# inside SCOPE_TRUNK, the latent-attention / routed-expert trunk
+# (models/latent_moe.py); "/" separates a part from its whole
+SCOPE_ATTN = "attn"  # latent attention: projections, scores, output
+SCOPE_ATTN_ROPE = "attn/rope"  # rotary positions on q_pe and the shared k_pe
+SCOPE_DENSE_FFN = "dense_ffn"  # the leading dense layers' gated FFN
+SCOPE_MOE_ROUTER = "moe/router"  # float32 scores, top-k, weights
+SCOPE_MOE_DISPATCH = "moe/dispatch"  # sort the (word, choice) pairs by held expert, gather rows
+SCOPE_MOE_EXPERTS = "moe/experts"  # the grouped products over the experts held
+SCOPE_MOE_COMBINE = "moe/combine"  # un-sort, weight and sum each word's pairs
+SCOPE_MOE_SHARED = "moe/shared"  # the shared experts, every word
 
 
 def head_scope(name: str) -> str:
     """One head's forward and loss: ``head/<component name>``."""
     return f"head/{name}"
 
+
+# ---- device counters (keys of the step's ``metrics``) --------------------------------
+# A layer may count on the device while the step is traced (``Context.
+# add_metrics``). A key that starts with COUNTER_PREFIX is a SUM: the step adds
+# it up over micro-batches, the loop over steps, and whichever model carries
+# ``meta[SUMMARISE_COUNTERS]`` turns the run's totals into its block of
+# ``TrainResult.resolved`` (docs/OBSERVABILITY.md). Neither the step nor the
+# loop knows whose counters they are.
+COUNTER_PREFIX = "count_"
+SUMMARISE_COUNTERS = "summarise_counters"  # meta key: totals dict -> dict for ``resolved``
+# the routed trunk's (models/latent_moe.py)
+MOE_ASSIGNMENTS = "count_moe_assignments"  # real words x top_k x expert layers
+MOE_ASSIGNMENTS_HELD = "count_moe_assignments_held"  # those that land on an expert held here
+MOE_COMPUTED = "count_moe_computed"  # of those, the pairs whose expert's output came back
+MOE_MAX_LOAD = "count_moe_max_load"  # rows of the fullest held expert, summed over layers
+MOE_LAYER_CALLS = "count_moe_layer_calls"  # expert layers run (micro-batches x layers)
 
 # ---- pallas kernels -------------------------------------------------------------
 KERNEL_FLASH_FWD = "srt_flash_fwd"

@@ -373,8 +373,29 @@ def _sharded_flash_attention(q, k, v, mask, mesh):
     return fn(q, k, v, mask)
 
 
+def xla_attention(
+    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, mask: jnp.ndarray,
+    causal: bool = False,
+) -> jnp.ndarray:
+    """Attention as plain XLA operations: q/k [B, T, H, Dqk], v [B, T, H, Dv]
+    (the two widths may differ), mask [B, T] bool (key padding), ``causal``
+    adds the lower triangle. Scores and softmax in float32; a query with no
+    visible key (a padded row) gets a finite, uniform row. Returns
+    [B, T, H, Dv] in v.dtype."""
+    T = q.shape[1]
+    scale = 1.0 / (q.shape[-1] ** 0.5)
+    scores = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
+    keep = mask[:, None, None, :]
+    if causal:
+        keep = keep & jnp.tril(jnp.ones((T, T), bool))[None, None]
+    p = jax.nn.softmax(jnp.where(keep, scores, NEG), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+
+
 def attention(
-    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, mask: jnp.ndarray
+    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, mask: jnp.ndarray,
+    causal: bool = False,
 ) -> jnp.ndarray:
     """Attention entry point for the trunk: pallas flash kernel when the
     probe enabled it and the shape fits VMEM, else XLA's fused
@@ -382,10 +403,20 @@ def attention(
     runs per-shard inside a shard_map (_sharded_flash_attention); layouts
     that don't divide fall back to XLA attention, which partitions cleanly.
     An armed kernel that gives way says so: each branch notes its path on
-    ``GATE``, and ``flash_attention_status`` reports what was taken."""
+    ``GATE``, and ``flash_attention_status`` reports what was taken.
+
+    ``causal`` and a ``v`` whose width differs from ``q``'s (latent
+    attention: 192 against 128) are outside what the kernels compute (one
+    head width, a key-padding bias): such a call goes through
+    :func:`xla_attention` and says so on ``GATE``."""
     from ..parallel import context as pctx
 
     B, T, H, Dh = q.shape
+    if causal or v.shape[-1] != Dh:
+        GATE.took(
+            f"xla (causal={causal}, q/k width {Dh}, v width {v.shape[-1]}: "
+            "outside the flash kernels)")
+        return xla_attention(q, k, v, mask, causal)
     out = None
     if not flash_attention_enabled():
         pass  # the probe's verdict says why

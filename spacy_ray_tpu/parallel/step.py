@@ -301,7 +301,14 @@ def make_train_step(
                 )
                 grads = jax.tree_util.tree_map(lambda g: g / accum, grads)
                 loss = jnp.mean(losses)
-                metrics = jax.tree_util.tree_map(jnp.mean, metricses)
+                # losses and accuracies are means over the micro-batches;
+                # device counters (names.py) add up
+                metrics = {
+                    k: (jnp.sum(metricses[k], axis=0)
+                        if k.startswith(names.COUNTER_PREFIX)
+                        else jax.tree_util.tree_map(jnp.mean, metricses[k]))
+                    for k in sorted(metricses)
+                }
         with jax.named_scope(names.SCOPE_UPDATE):
             if pin_grads:
                 # pin the all-reduced grads REPLICATED and fence them: XLA must

@@ -455,6 +455,7 @@ class Pipeline:
             total = jnp.float32(0.0)
             t2v_out = None
             aux_sink: List[Any] = []  # e.g. MoE router load-balancing loss
+            counters: Dict[str, Any] = {}  # device counters a trunk makes (names.py)
             if t2v_name is not None:
                 t2v_params = params[t2v_name]
                 if t2v_name in frozen:
@@ -462,7 +463,8 @@ class Pipeline:
                 rng, sub = jax.random.split(rng)
                 t2v_out = components[t2v_name].forward(
                     t2v_params, tokens,
-                    Context(train=True, rng=sub, aux_losses=aux_sink, dropout=drop),
+                    Context(train=True, rng=sub, aux_losses=aux_sink, dropout=drop,
+                            metrics=counters),
                 )
             for name in head_names:
                 comp = components[name]
@@ -478,7 +480,8 @@ class Pipeline:
                 with jax.named_scope(names.head_scope(name)):
                     loss, comp_metrics = comp.loss(
                         comp_params, inputs, targets[name],
-                        Context(train=True, rng=sub, aux_losses=aux_sink, dropout=drop),
+                        Context(train=True, rng=sub, aux_losses=aux_sink, dropout=drop,
+                                metrics=counters),
                     )
                 metrics[f"loss_{name}"] = loss
                 # namespace per component: shared base classes emit the same
@@ -493,6 +496,7 @@ class Pipeline:
                         aux_total = aux_total + a
                     metrics["loss_aux"] = aux_total
                     total = total + aux_total
+            metrics.update(counters)
             return total, metrics
 
         return loss_fn

@@ -154,12 +154,12 @@ def build_params_overlay(params: Any, precision: str = "auto") -> OverlayResult:
             reason=reason, params=params, n_overlaid=0,
         )
 
-    from ..models.transformer import (
-        build_int8_overlay,
+    from ..models.shadow import (
         build_param_shadow,
         int8_unsupported_leaves,
         shadow_coverage,
     )
+    from ..models.transformer import build_int8_overlay
     from ..parallel.step import overlay_shadow
 
     def _refuse(reason: str, level: int = logging.INFO, **extra):
@@ -191,7 +191,7 @@ def build_params_overlay(params: Any, precision: str = "auto") -> OverlayResult:
         moe = int8_unsupported_leaves(params)
         if moe:
             return _refuse(
-                f"overlay refused: {len(moe)} MoE expert weight leaf(s) "
+                f"overlay refused: {len(moe)} trunk weight leaf(s) "
                 "outside int8 coverage "
                 f"({', '.join(moe[:4])}"
                 + (", ..." if len(moe) > 4 else "") + ")"

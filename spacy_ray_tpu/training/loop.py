@@ -902,7 +902,7 @@ def train(
     shadow_mode = str(T.get("bf16_shadow", "auto"))
     shadow = None
     if shadow_mode in ("auto", "on"):
-        from ..models.transformer import build_param_shadow, pipeline_shadow_dtype
+        from ..models.shadow import build_param_shadow, pipeline_shadow_dtype
 
         shadow_dtype = pipeline_shadow_dtype(nlp)
         if shadow_dtype is not None:
@@ -1008,6 +1008,7 @@ def train(
     start_time = time.perf_counter()
     loss_accum: Dict[str, float] = {}
     pending_metrics: List[Tuple[Dict[str, Any], bool]] = []
+    counter_totals: Dict[str, int] = {}  # device counters (names.COUNTER_PREFIX), summed over the run
     words_since_log = 0
     last_log_time = start_time
     stop = False
@@ -1032,6 +1033,8 @@ def train(
                 if key.startswith("loss_"):
                     v = float("nan") if poisoned else float(value)
                     loss_accum[key[5:]] = loss_accum.get(key[5:], 0.0) + v
+                elif key.startswith(names.COUNTER_PREFIX):
+                    counter_totals[key] = counter_totals.get(key, 0) + int(value)
         pending_metrics.clear()
 
     # ---- staged input pipeline (read -> collate -> transfer) ----
@@ -1704,6 +1707,14 @@ def train(
         "fused_update": fused_status(tx, mesh),
         "bf16_shadow": "on" if shadow is not None else "off",
     }
+    if pending_metrics:
+        drain_metrics()  # the steps since the last evaluation
+    if counter_totals:
+        # whichever model made the counters says what they come to
+        for comp in nlp.components.values():
+            for m in (comp.model.walk() if getattr(comp, "model", None) is not None else ()):
+                if names.SUMMARISE_COUNTERS in m.meta:
+                    result.resolved.update(m.meta[names.SUMMARISE_COUNTERS](counter_totals))
     nlp.params = jax.device_get(params)
     if output_path is not None and jax.process_index() == 0:
         nlp.to_disk(Path(output_path) / "last-model")
