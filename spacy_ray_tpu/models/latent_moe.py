@@ -21,11 +21,31 @@ chips or for their exchange.
 **Dispatch without a capacity.** The (word, choice) pairs are sorted by held
 expert (pairs on absent experts and on padding sort last), the rows gathered
 in that order, and ONE grouped product (``jax.lax.ragged_dot``) runs over the
-experts held: group sizes vary from step to step, shapes do not (the buffer
-has the static size ``N x top_k``, which no routing exceeds). No pair is ever
-dropped. Sorting is a permutation, so un-sorting is the inverse permutation:
-dispatch and combine are gathers in both directions (``_permute``), where a
-scatter-add of ``N x top_k`` rows would serialise on the chip.
+experts held: group sizes vary from step to step, shapes do not. No pair is
+ever dropped.
+
+**The live prefix and its bound.** Only the pairs that land on a held expert
+produce anything, and after the sort they are the first ``n_live`` rows. A
+rank that holds ``experts_held`` of ``n_experts`` is sent ``P * held /
+n_experts`` of the ``P = N x top_k`` pairs by an even router, so the rows are
+moved through a buffer of ``C`` = twice that share (``live_bound``: from the
+shapes alone, rounded up to ``BOUND_STEP`` rows), not of ``P``: the ``C`` rows
+are gathered straight from the words (``order[:C] // top_k``), the grouped
+products run on ``[C, .]``, and each word's sum is taken from the ``C`` output
+rows by position, choice by choice (``_rows_in`` / ``_rows_out``: gathers in
+both directions, no array of ``P`` rows forward or backward, where a
+scatter-add of rows would serialise on the chip). The bound is NOT a capacity
+and drops nothing: ``n_live`` is known on the device after the sort, and a
+routing that sends more than ``C`` pairs here takes the full path
+(``lax.cond``, no host round trip), whose buffer has the static size ``P``
+that no routing exceeds; there sorting is a permutation, so dispatch and
+combine are gathers by it and by its inverse (``_permute``). The full path
+stays because the router is free: with its selection bias frozen it has sent
+this rank anything from 1% to 31% of a run's pairs by seed, and single layers
+over half of a batch's (0-15% of a run's layer calls pass the bound: PERF.md
+section 6, PR 28); nothing holds it to twice its share. Where ``C >= P`` (a layer that holds every expert, or a
+batch under ``BOUND_STEP`` pairs) there is one path, the full one, and no
+branch.
 
 Float32 where it decides something: the residual stream, every RMSNorm, the
 router (``h W_r`` at precision ``highest``, sigmoid, top-k, weights), the
@@ -69,7 +89,7 @@ EMBED_SEED = hash_string_u64("latent-moe-embed-NORM") & 0x7FFFFFFF
 # what an expert layer counts, in this order (device counters: names.py)
 COUNTER_KEYS = (
     names.MOE_ASSIGNMENTS, names.MOE_ASSIGNMENTS_HELD, names.MOE_COMPUTED,
-    names.MOE_MAX_LOAD, names.MOE_LAYER_CALLS,
+    names.MOE_MAX_LOAD, names.MOE_LAYER_CALLS, names.MOE_BOUNDED_CALLS,
 )
 N_COUNTERS = len(COUNTER_KEYS)
 
@@ -183,13 +203,164 @@ def route(p, h: jnp.ndarray, s: Shape) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return idx.astype(jnp.int32), weights
 
 
+# the bound moves in steps of this many rows (whole row tiles, whatever tile the
+# compiler gives the grouped product); a batch whose doubled share is under one
+# step has no smaller buffer to gain and takes the full path alone
+BOUND_STEP = 512
+
+
+def live_bound(n_pairs: int, s: Shape) -> int:
+    """Rows of the bounded path's buffer for ``n_pairs`` (word, choice)
+    pairs: twice this rank's even share, rounded up to ``BOUND_STEP``. At
+    ``n_pairs`` or over it there is no bounded path."""
+    share = -(-2 * n_pairs * s.experts_held // s.n_experts)
+    return -(-share // BOUND_STEP) * BOUND_STEP
+
+
+def _live_rows(n_live: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """[rows, 1] bool: the sorted buffer's rows that belong to a held expert.
+    Rows past the last group belong to none: the grouped product owes them
+    nothing, so they are zeroed going in, between and coming out."""
+    return (jnp.arange(rows, dtype=jnp.int32) < n_live)[:, None]
+
+
+def _expert_products(rows, live, group_sizes, eg, eu, ed):
+    """rows [R, D] sorted by held expert -> the experts' outputs [R, D]."""
+    cd = rows.dtype
+    grouped = partial(jax.lax.ragged_dot, group_sizes=group_sizes)
+    gate = grouped(rows, eg).astype(jnp.float32)
+    up = grouped(rows, eu).astype(jnp.float32)
+    inner = jnp.where(live, jax.nn.silu(gate) * up, 0).astype(cd)
+    return jnp.where(live, grouped(inner, ed), 0)
+
+
+def _full_path(h16, w, eg, eu, ed, order, inverse, group_sizes):
+    """Every pair moved: a buffer of ``P = N x top_k`` rows. Returns (the
+    words' sums [N, D] float32, which pairs' output came back [N, K] bool)."""
+    N, D = h16.shape
+    K = w.shape[1]
+    with jax.named_scope(names.SCOPE_MOE_DISPATCH):
+        live = _live_rows(jnp.sum(group_sizes), N * K)
+        rows = _permute(jnp.repeat(h16, K, axis=0), order, inverse)
+        rows = jnp.where(live, rows, 0)
+    with jax.named_scope(names.SCOPE_MOE_EXPERTS):
+        out_rows = _expert_products(rows, live, group_sizes, eg, eu, ed)
+    with jax.named_scope(names.SCOPE_MOE_COMBINE):
+        pairs = _permute(out_rows, inverse, order).reshape(N, K, D)
+        y = jnp.sum(pairs.astype(jnp.float32) * w[..., None], axis=1)
+        return y, jnp.any(pairs != 0, axis=-1)
+
+
+def _sum_choices(rows, pos, w=None) -> jnp.ndarray:
+    """``sum_k w[n, k] * rows[pos[n, k]]`` in float32, choice by choice; a
+    position past the last row reads a row of noughts. rows [C, D], pos
+    [N, K] -> [N, D]: K gathers of N rows, never one of N x K."""
+    total = None
+    for k in range(pos.shape[1]):
+        term = rows.at[pos[:, k]].get(mode="fill", fill_value=0).astype(jnp.float32)
+        if w is not None:
+            term = term * w[:, k, None]
+        total = term if total is None else total + term
+    return total
+
+
+@jax.custom_vjp
+def _rows_in(h16, word, pos):
+    """``h16[word]``: the bounded buffer's rows, gathered from their words.
+    Backward, a word's gradient is the sum of its choices' rows, taken by
+    position (``pos``: where each pair sorted to, the row of noughts for a
+    pair outside the buffer): a gather, where the transpose of a gather is a
+    scatter-add."""
+    return h16[word]
+
+
+def _rows_in_fwd(h16, word, pos):
+    return h16[word], pos
+
+
+def _rows_in_bwd(pos, g):
+    return _sum_choices(g, pos).astype(g.dtype), None, None
+
+
+_rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
+
+
+@jax.custom_vjp
+def _rows_out(out_rows, w, slot, pos):
+    """Each word's weighted sum of its choices' output rows, float32 [N, D].
+    ``slot`` [C]: the flat (word, choice) pair of each row; ``pos`` [N, K] as
+    in ``_rows_in``."""
+    return _sum_choices(out_rows, pos, w)
+
+
+def _rows_out_fwd(out_rows, w, slot, pos):
+    return _sum_choices(out_rows, pos, w), (out_rows, w, slot, pos)
+
+
+def _rows_out_bwd(res, g):
+    out_rows, w, slot, pos = res
+    g_rows = g[slot // w.shape[1]]  # [C, D] float32: each row's word's cotangent
+    d_rows = (g_rows * w.reshape(-1)[slot][:, None]).astype(out_rows.dtype)
+    d_w = jnp.sum(g_rows * out_rows.astype(jnp.float32), axis=-1)  # one dot product a row
+    return d_rows, d_w.at[pos].get(mode="fill", fill_value=0), None, None
+
+
+_rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+
+
+def _bounded_path(bound, h16, w, eg, eu, ed, order, inverse, group_sizes):
+    """``_full_path`` for a routing whose live pairs fit ``bound`` rows."""
+    N, K = w.shape
+    slot = order[:bound]  # the flat (word, choice) pair of each row of the buffer
+    pos = jnp.minimum(inverse, bound).reshape(N, K)
+    with jax.named_scope(names.SCOPE_MOE_DISPATCH):
+        live = _live_rows(jnp.sum(group_sizes), bound)
+        rows = jnp.where(live, _rows_in(h16, slot // K, pos), 0)
+    with jax.named_scope(names.SCOPE_MOE_EXPERTS):
+        out_rows = _expert_products(rows, live, group_sizes, eg, eu, ed)
+    with jax.named_scope(names.SCOPE_MOE_COMBINE):
+        y = _rows_out(out_rows, w, slot, pos)
+        came_back = jnp.any(out_rows != 0, axis=-1).at[pos].get(mode="fill", fill_value=False)
+        return y, came_back
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _by_live_size(bound, fits, h16, w, eg, eu, ed, order, inverse, group_sizes):
+    """The bounded path where the live pairs fit ``bound`` rows (``fits``,
+    known on the device), the full path where they do not. Its own
+    ``custom_vjp`` so that the backward is a branch too and each branch
+    recomputes its forward inside: differentiating the ``cond`` itself would
+    hand every residual of BOTH branches across it, the untaken one's as
+    noughts, and the bounded branch would write the full one's P-row arrays."""
+    return jax.lax.cond(fits, partial(_bounded_path, bound), _full_path,
+                        h16, w, eg, eu, ed, order, inverse, group_sizes)
+
+
+def _by_live_size_fwd(bound, fits, *operands):
+    return _by_live_size(bound, fits, *operands), (fits, operands)
+
+
+def _by_live_size_bwd(bound, res, cotangent):
+    fits, (*floats, order, inverse, group_sizes) = res
+
+    def pull(path, g, *floats):
+        _, vjp = jax.vjp(lambda *f: path(*f, order, inverse, group_sizes)[0], *floats)
+        return vjp(g)
+
+    grads = jax.lax.cond(fits, partial(pull, partial(_bounded_path, bound)),
+                         partial(pull, _full_path), cotangent[0], *floats)
+    return (None, *grads, None, None, None)
+
+
+_by_live_size.defvjp(_by_live_size_fwd, _by_live_size_bwd)
+
+
 def routed_experts(p, h: jnp.ndarray, token_mask, idx, weights, s: Shape, cd):
     """The held experts' part of ``sum_k w_k Expert_k(h)``. h [N, D] float32,
     token_mask [N] bool, idx / weights [N, top_k]. Returns ([N, D] float32,
     counters int32 [N_COUNTERS])."""
-    N, D = h.shape
     K, held = s.top_k, s.experts_held
-    P = N * K
+    P = h.shape[0] * K
     with jax.named_scope(names.SCOPE_MOE_DISPATCH):
         local = idx - s.held_from
         valid = (local >= 0) & (local < held) & token_mask[:, None]  # [N, K]
@@ -199,30 +370,25 @@ def routed_experts(p, h: jnp.ndarray, token_mask, idx, weights, s: Shape, cd):
             jnp.arange(P, dtype=jnp.int32), unique_indices=True)
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :], axis=0, dtype=jnp.int32)
-        # rows past the last group belong to no expert: the grouped product
-        # owes them nothing, so they are zeroed going in, between and coming out
-        live = (jnp.arange(P, dtype=jnp.int32) < jnp.sum(group_sizes))[:, None]
-        rows = _permute(jnp.repeat(h.astype(cd), K, axis=0), order, inverse)
-        rows = jnp.where(live, rows, 0)
-    with jax.named_scope(names.SCOPE_MOE_EXPERTS):
-        grouped = partial(jax.lax.ragged_dot, group_sizes=group_sizes)
-        gate = grouped(rows, p["eg_W"].astype(cd)).astype(jnp.float32)
-        up = grouped(rows, p["eu_W"].astype(cd)).astype(jnp.float32)
-        inner = jnp.where(live, jax.nn.silu(gate) * up, 0).astype(cd)
-        out_rows = jnp.where(live, grouped(inner, p["ed_W"].astype(cd)), 0)
-    with jax.named_scope(names.SCOPE_MOE_COMBINE):
-        pairs = _permute(out_rows, inverse, order).reshape(N, K, D)
-        w = jnp.where(valid, weights, 0.0)[..., None]
-        y = jnp.sum(pairs.astype(jnp.float32) * w, axis=1)
-        # counted from what the product gave back, not from the mask it was
-        # given: a pair whose expert's output is all nought was not computed
-        computed = jnp.sum(valid & jnp.any(pairs != 0, axis=-1), dtype=jnp.int32)
+    operands = (h.astype(cd), jnp.where(valid, weights, 0.0),
+                p["eg_W"].astype(cd), p["eu_W"].astype(cd), p["ed_W"].astype(cd),
+                order, inverse, group_sizes)
+    bound = live_bound(P, s)
+    if bound >= P:  # no smaller buffer to be had: one path, no branch
+        fits = jnp.bool_(False)
+        y, came_back = _full_path(*operands)
+    else:
+        fits = jnp.sum(group_sizes) <= bound
+        y, came_back = _by_live_size(bound, fits, *operands)
     counters = jnp.stack([
         jnp.sum(token_mask, dtype=jnp.int32) * K,
         jnp.sum(valid, dtype=jnp.int32),
-        computed,
+        # counted from what the product gave back, not from the mask it was
+        # given: a pair whose expert's output is all nought was not computed
+        jnp.sum(valid & came_back, dtype=jnp.int32),
         jnp.max(group_sizes),
         jnp.int32(1),
+        fits.astype(jnp.int32),
     ])
     return y, counters
 
@@ -438,6 +604,10 @@ def moe_summary(totals: Dict[str, float], experts_held: int, n_experts: int) -> 
         "max_expert_load": totals.get(names.MOE_MAX_LOAD, 0) / calls,
         "mean_expert_load": held / experts_held / calls,
         "layer_calls": calls,
+        "bounded_calls": int(totals.get(names.MOE_BOUNDED_CALLS, 0)),
     }
+    bound = ("one path: every expert held" if experts_held == n_experts else
+             f"live rows bounded at 2 x {experts_held}/{n_experts} of the pairs "
+             f"(steps of {BOUND_STEP}), the full path past it")
     return {"moe": moe, "moe_dropped": str(moe["dropped"]),
-            "moe_dispatch": f"sorted, ragged_dot, {experts_held} of {n_experts} held"}
+            "moe_dispatch": f"sorted, ragged_dot, {experts_held} of {n_experts} held; {bound}"}

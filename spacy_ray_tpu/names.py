@@ -81,6 +81,7 @@ MOE_ASSIGNMENTS_HELD = "count_moe_assignments_held"  # those that land on an exp
 MOE_COMPUTED = "count_moe_computed"  # of those, the pairs whose expert's output came back
 MOE_MAX_LOAD = "count_moe_max_load"  # rows of the fullest held expert, summed over layers
 MOE_LAYER_CALLS = "count_moe_layer_calls"  # expert layers run (micro-batches x layers)
+MOE_BOUNDED_CALLS = "count_moe_bounded_calls"  # of those, the calls whose live pairs fit the bound
 
 # ---- pallas kernels -------------------------------------------------------------
 KERNEL_FLASH_FWD = "srt_flash_fwd"

@@ -23,3 +23,4 @@ def _cases(file_name: str):
 globals().update(_cases("test_benchmark_files"))
 globals().update(_cases("test_kanana2_a3b"))
 globals().update(_cases("test_kanana2_a3b_faults"))
+globals().update(_cases("test_moe_bounded_share"))

@@ -64,9 +64,37 @@ def batch():
     return ids, mask, positions
 
 
+# a batch wide enough for the routed layer to have two paths: 8 x 64 words x
+# top 3 = 1,536 pairs against a bound of 1,024 rows (twice the even share of 4
+# of 16 experts, 768, rounded up to 512s)
+WIDE_B, WIDE_T = 8, 64
+WIDE_LENGTHS = np.array([64, 57, 33, 64, 48, 60, 64, 51])
+WIDE_PAIRS = WIDE_B * WIDE_T * TINY.top_k
+
+
+@pytest.fixture(scope="module")
+def wide_batch():
+    rng = np.random.default_rng(6)
+    ids = jnp.asarray(rng.integers(0, TINY.vocab_rows, (WIDE_B, WIDE_T)))
+    mask = jnp.asarray(np.arange(WIDE_T)[None] < WIDE_LENGTHS[:, None])
+    positions = jnp.broadcast_to(jnp.arange(WIDE_T)[None], (WIDE_B, WIDE_T))
+    return ids, mask, positions
+
+
 @pytest.fixture(scope="module")
 def params():
     return init_params(jax.random.PRNGKey(3), TINY)
+
+
+def every_word_on_held_experts(params, s=TINY):
+    """The same parameters with a selection bias that lands every word's whole
+    top-k on the first ``top_k`` held experts."""
+    bias = np.zeros((s.n_experts,), np.float32)
+    bias[s.held_from:s.held_from + s.top_k] = 10.0
+    forced = dict(params)
+    for i in range(s.first_dense, s.depth):
+        forced[f"layer_{i}"] = dict(params[f"layer_{i}"], router_b=jnp.asarray(bias))
+    return forced
 
 
 def system(p, ids, mask, positions, s=TINY, **kw):
@@ -168,18 +196,20 @@ def test_c_the_ranks_parts_sum_to_the_whole_layer():
 # ---- (d) no drop, (e) padding -------------------------------------------------------------
 
 
-def test_d_no_pair_is_dropped_when_every_word_lands_on_the_same_experts(params, batch):
-    forced = jax.tree_util.tree_map(lambda a: a, params)
+@pytest.mark.parametrize("which,bounded", [("batch", 0), ("wide_batch", 0)])
+def test_d_no_pair_is_dropped_when_every_word_lands_on_the_same_experts(
+        params, request, which, bounded):
+    """On the small batch the layer has one path; on the wide one the live
+    pairs (1,323) pass the bound (1,024) and the branch taken is the full one."""
+    batch = request.getfixturevalue(which)
+    forced = every_word_on_held_experts(params)
     lo, _ = held(TINY)
-    bias = np.zeros((TINY.n_experts,), np.float32)
-    bias[lo:lo + TINY.top_k] = 10.0  # every word's whole top-k lands on three held experts
-    for i in range(TINY.first_dense, TINY.depth):
-        forced[f"layer_{i}"] = dict(forced[f"layer_{i}"], router_b=jnp.asarray(bias))
     X, counters, choices = system(forced, *batch)
-    words, layers = int(LENGTHS.sum()), TINY.depth - TINY.first_dense
-    assignments, on_held, computed, max_load, calls = (int(c) for c in counters)
+    words, layers = int(np.asarray(batch[1]).sum()), TINY.depth - TINY.first_dense
+    assignments, on_held, computed, max_load, calls, bounded_calls = (int(c) for c in counters)
     assert assignments == on_held == computed == words * TINY.top_k * layers
     assert max_load == words * layers and calls == layers  # one expert holds every word
+    assert bounded_calls == bounded
     assert set(np.asarray(choices)[:, np.asarray(batch[1])].reshape(-1)) == set(
         range(lo, lo + TINY.top_k))
     want = reference(forced, *batch, np.asarray(choices))
@@ -189,6 +219,7 @@ def test_d_no_pair_is_dropped_when_every_word_lands_on_the_same_experts(params, 
     summary = summarise(dict(zip(latent_moe.COUNTER_KEYS, map(int, counters))))
     assert summary["moe"]["dropped"] == 0 and summary["moe_dropped"] == "0"
     assert summary["moe"]["max_expert_load"] == words
+    assert summary["moe"]["bounded_calls"] == bounded and summary["moe"]["layer_calls"] == layers
     # the counter reads what the product gave back: one of the three experts
     # returning nothing is a third of the pairs dropped, in every layer
     for i in range(TINY.first_dense, TINY.depth):
@@ -199,16 +230,184 @@ def test_d_no_pair_is_dropped_when_every_word_lands_on_the_same_experts(params, 
     assert summary["moe"]["dropped"] == words * layers and summary["moe_dropped"] != "0"
 
 
-def test_e_a_padded_position_reaches_no_expert_and_moves_no_output(params, batch):
-    ids, mask, positions = batch
+@pytest.mark.parametrize("which,bounded", [("batch", 0), ("wide_batch", 1)])
+def test_e_a_padded_position_reaches_no_expert_and_moves_no_output(
+        params, request, which, bounded):
+    """``bounded``: of each layer's calls, those that take the bounded path
+    (the wide batch's seeded routing sends about 330 pairs here, under 1,024)."""
+    ids, mask, positions = request.getfixturevalue(which)
     X, counters, _ = system(params, ids, mask, positions)
     other = jnp.where(mask, ids, (ids + 17) % TINY.vocab_rows)  # new words under the padding
     X2, counters2, _ = system(params, other, mask, positions)
     np.testing.assert_array_equal(np.asarray(X), np.asarray(X2))
     np.testing.assert_array_equal(np.asarray(counters), np.asarray(counters2))
     layers = TINY.depth - TINY.first_dense
-    assert int(counters[0]) == int(LENGTHS.sum()) * TINY.top_k * layers
+    assert int(counters[0]) == int(np.asarray(mask).sum()) * TINY.top_k * layers
     assert int(counters[1]) == int(counters[2]) <= int(counters[0])
+    assert int(counters[5]) == bounded * layers
+
+
+# ---- (j) the bounded live prefix and its fall-back (ISSUE 28) ---------------------------------
+
+
+def full_path_only(monkeypatch):
+    """The parent's program: a bound of every pair leaves one path, no branch."""
+    monkeypatch.setattr(latent_moe, "live_bound", lambda n_pairs, s: n_pairs)
+
+
+def assert_leaves_close(got, want, what):
+    """Every leaf within float32 rounding of the other path's, measured against
+    the leaf's own size (the two paths add the same products in another order)."""
+    flat_want = jax.tree_util.tree_leaves_with_path(want)
+    for (path, w), g in zip(flat_want, jax.tree_util.tree_leaves(got)):
+        size = max(float(jnp.max(jnp.abs(w))), 1e-30)
+        err = float(jnp.max(jnp.abs(g - w))) / size
+        assert err <= 2e-5, (what, jax.tree_util.keystr(path), err)
+
+
+def sub_jaxprs(jaxpr):
+    """``jaxpr`` and every jaxpr inside its equations' parameters."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (tuple, list)) else (value,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from sub_jaxprs(inner)
+
+
+def branches_of_the_conds(fn, *args):
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    return [eqn.params["branches"] for sub in sub_jaxprs(jaxpr) for eqn in sub.eqns
+            if eqn.primitive.name == "cond"]
+
+
+@pytest.mark.parametrize("scan,remat", [(True, True), (True, False), (False, True), (False, False)])
+@pytest.mark.parametrize("routing", ["under_the_bound", "over_the_bound"])
+def test_j_the_bounded_path_agrees_with_the_full_path(
+        params, wide_batch, monkeypatch, routing, scan, remat):
+    """The same parameters and batch down the program with the bound and down
+    the parent's (one path, every pair moved): outputs, counters and every
+    gradient leaf. Over the bound both take the full path, one of them through
+    the branch."""
+    ids, mask, positions = wide_batch
+    p = params if routing == "under_the_bound" else every_word_on_held_experts(params)
+    cot = jnp.asarray(np.random.default_rng(8).standard_normal((WIDE_B, WIDE_T, TINY.width)),
+                      jnp.float32) * mask[..., None]
+
+    def run():
+        def loss(p):
+            X, counters, _ = trunk_forward(
+                p, ids, mask, positions, TINY, scan_layers=scan, remat=remat)
+            return jnp.sum(X * cot), (X, counters)
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(p)
+
+    bound = latent_moe.live_bound(WIDE_PAIRS, TINY)
+    (_, (X, counters)), grads = run()
+    full_path_only(monkeypatch)
+    (_, (X_full, counters_full)), grads_full = run()
+    layers = TINY.depth - TINY.first_dense
+    live = int(counters[1]) // layers
+    if routing == "under_the_bound":
+        assert 0 < live < bound and int(counters[5]) == layers
+    else:
+        assert live > bound and int(counters[5]) == 0
+    assert int(counters_full[5]) == 0
+    np.testing.assert_array_equal(np.asarray(counters[:5]), np.asarray(counters_full[:5]))
+    assert int(counters[1]) == int(counters[2])  # nothing dropped on either path
+    assert_leaves_close(X, X_full, "output")
+    assert_leaves_close(grads, grads_full, "gradient")
+
+
+def one_expert_layer(n_live: int):
+    """A routed layer's inputs with exactly ``n_live`` pairs on held experts:
+    512 real words x top 3, the first pairs in flat order sent to the four held
+    experts in turn and every other pair to absent ones."""
+    rng = np.random.default_rng(9)
+    n = WIDE_B * WIDE_T
+    layer = init_params(jax.random.PRNGKey(11), TINY)["layer_1"]
+    h = jnp.asarray(rng.standard_normal((n, TINY.width)), jnp.float32)
+    flat = np.arange(n * TINY.top_k)
+    idx = np.where(flat < n_live, TINY.held_from + flat % TINY.experts_held, flat % TINY.held_from)
+    weights = jnp.asarray(rng.uniform(0.1, 1.0, (n, TINY.top_k)), jnp.float32)
+    return layer, h, jnp.ones((n,), bool), jnp.asarray(idx.reshape(n, TINY.top_k), jnp.int32), weights
+
+
+@pytest.mark.parametrize("past", [0, 1])
+def test_j_at_the_bound_and_one_pair_past_it(monkeypatch, past):
+    bound = latent_moe.live_bound(WIDE_PAIRS, TINY)
+    assert bound == 1024 < WIDE_PAIRS
+    layer, h, real, idx, weights = one_expert_layer(bound + past)
+    cot = jnp.asarray(np.random.default_rng(10).standard_normal(h.shape), jnp.float32)
+
+    def run():
+        def loss(layer, h, weights):
+            y, counters = latent_moe.routed_experts(layer, h, real, idx, weights, TINY, jnp.float32)
+            return jnp.sum(y * cot), (y, counters)
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(layer, h, weights)
+
+    (_, (y, counters)), grads = run()
+    full_path_only(monkeypatch)
+    (_, (y_full, counters_full)), grads_full = run()
+    assert int(counters[1]) == int(counters[2]) == bound + past
+    assert int(counters[5]) == 1 - past and int(counters_full[5]) == 0
+    np.testing.assert_array_equal(np.asarray(counters[:5]), np.asarray(counters_full[:5]))
+    assert float(jnp.max(jnp.abs(grads_full[2]))) > 0  # the weights' gradient is there to compare
+    assert_leaves_close(y, y_full, "output")
+    assert_leaves_close(grads, grads_full, "gradient")
+
+
+def test_j_the_bounded_branch_makes_no_array_of_every_pair(params, wide_batch):
+    """Forward and backward: inside the branch taken under the bound no array
+    has a row for each of the N x top_k pairs (index vectors have: they stay)."""
+    ids, mask, positions = wide_batch
+
+    def loss(p):
+        return jnp.sum(trunk_forward(p, ids, mask, positions, TINY, remat=True)[0])
+
+    def a_row_for_every_pair(branch):
+        return {v.aval.shape for sub in sub_jaxprs(branch.jaxpr) for eqn in sub.eqns
+                for v in eqn.outvars
+                if len(v.aval.shape) >= 2 and v.aval.shape[0] == WIDE_PAIRS}
+
+    conds = branches_of_the_conds(jax.grad(loss), params)
+    assert len(conds) >= 2  # the forward's and the backward's
+    for full, bounded in conds:
+        assert a_row_for_every_pair(bounded) == set()
+        assert a_row_for_every_pair(full)  # the same walk does find the full path's
+
+
+def test_j_a_layer_that_holds_every_expert_has_no_branch(params, wide_batch):
+    ids, mask, positions = wide_batch
+    whole = replace(TINY, experts_held=TINY.n_experts, expert_rank=0)
+    p = init_params(jax.random.PRNGKey(3), whole)
+
+    def loss(s):
+        return lambda p: jnp.sum(trunk_forward(p, ids, mask, positions, s, remat=True)[0])
+
+    assert branches_of_the_conds(jax.grad(loss(whole)), p) == []
+    assert branches_of_the_conds(jax.grad(loss(TINY)), params) != []
+    _, counters, _ = system(p, ids, mask, positions, s=whole)
+    assert int(counters[5]) == 0 and int(counters[1]) == int(counters[2]) == int(counters[0])
+    summary = latent_moe.moe_summary({}, experts_held=16, n_experts=16)
+    assert summary["moe_dispatch"] == "sorted, ragged_dot, 16 of 16 held; one path: every expert held"
+
+
+def test_j_a_live_prefix_cut_one_row_short_reads_as_a_dropped_pair(params, wide_batch, monkeypatch):
+    """A fault planted in the bounded path: its live rows end one before the
+    last pair that landed here. ``moe_dropped`` reads the rows that came back."""
+    real = latent_moe._live_rows
+    bound = latent_moe.live_bound(WIDE_PAIRS, TINY)
+    monkeypatch.setattr(
+        latent_moe, "_live_rows",
+        lambda n_live, rows: real(n_live - 1 if rows == bound else n_live, rows))
+    _, counters, _ = system(params, *wide_batch)
+    layers = TINY.depth - TINY.first_dense
+    assert int(counters[5]) == layers  # the bounded path did run
+    summary = latent_moe.moe_summary(
+        dict(zip(latent_moe.COUNTER_KEYS, map(int, counters))),
+        experts_held=TINY.experts_held, n_experts=TINY.n_experts)
+    assert summary["moe"]["dropped"] == layers and summary["moe_dropped"] != "0"
 
 
 # ---- (f) causal, rotary --------------------------------------------------------------------
@@ -351,7 +550,7 @@ def test_h_a_train_run_through_the_normal_path(trained):
     assert result.resolved["fused_update"].startswith("active")
     moe = result.resolved["moe"]
     assert moe["dropped"] == 0 and result.resolved["moe_dropped"] == "0"
-    assert result.resolved["moe_dispatch"] == "sorted, ragged_dot, 4 of 16 held"
+    assert result.resolved["moe_dispatch"].startswith("sorted, ragged_dot, 4 of 16 held; live rows")
     assert moe["assignments"] == result.words_seen * 3 * 2  # words x top_k x expert layers
     assert 0 < moe["assignments_held"] < moe["assignments"]
     assert moe["max_expert_load"] >= moe["mean_expert_load"] > 0
