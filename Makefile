@@ -1,6 +1,6 @@
 # Convenience targets; the canonical commands live in README.md / PERF.md.
 
-.PHONY: test test-fast test-slow resilience telemetry observability serving fleet multi-model live train-fleet train-fleet-obs train-fleet-chaos bench bench-gate baseline profile step-perf serve-perf serve-perf3 update-shard dryrun
+.PHONY: test test-fast test-slow resilience telemetry observability serving fleet multi-model live train-fleet train-fleet-obs train-fleet-chaos serve-perf3 update-shard dryrun
 
 test:
 	python -m pytest tests/ -q
@@ -35,15 +35,14 @@ observability:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_fleet.py -q -k "sigterm or sigkill"
 
 # online-serving suite: batcher/engine/HTTP correctness under load,
-# SIGTERM graceful drain, SLO telemetry, bench records (docs/SERVING.md);
-# the heavy open-loop load variant is slow-marked and excluded here
+# SIGTERM graceful drain, SLO telemetry (docs/SERVING.md)
 serving:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py -q -m "not slow"
 
 # multi-replica fleet suite: router balancing/health/retry, response
 # cache, metrics aggregation, supervisor restarts, autoscaler hysteresis,
 # whole-fleet SIGTERM drain (docs/SERVING.md "Fleet"); the real-load
-# crash-recovery and bench-record variants are slow-marked and excluded
+# crash-recovery variants are slow-marked and excluded
 fleet:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_fleet.py -q -m "not slow"
 
@@ -53,24 +52,17 @@ fleet:
 # under a fake clock + the typed-429 matrix, residency LRU (pinned
 # default, leader-election cold load, zero post-load compiles),
 # placement hysteresis, per-model cache/merge/top surfaces, the
-# zero-telemetry guard, and the 2-model HTTP end-to-end — then the
-# isolation bench: a saturating quota-metered burst on model alpha must
-# not move model beta's gold-class window p99 past target (zero 5xx;
-# the committed record names per-model p99 / cache hit rate / quota
-# rejects / residency swaps)
+# zero-telemetry guard, and the 2-model HTTP end-to-end
 multi-model:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_multimodel.py -q -m "not slow"
-	JAX_PLATFORMS=cpu python bench.py --cpu --serving --multi-model
 
 # live continuous-learning suite (docs/SERVING.md "Continuous learning"):
 # Checkpoints reader API + writer-protocol contract, watcher torn-skip,
 # swap-at-dispatch-boundary bit-exactness, rollback, canary guard +
 # fleet rollout controller (incl. forced-regression auto-rollback), the
-# train+fleet integration and train-and-serve SIGTERM drain — then the
-# hot-swap tail-latency bench at the committed offered rate
+# train+fleet integration and train-and-serve SIGTERM drain
 live:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_live.py -q -m "not slow"
-	JAX_PLATFORMS=cpu python bench.py --cpu --serving --swap
 
 # asynchronous trainer fleet (docs/TUNING.md §19–20, RESILIENCE.md
 # "Trainer fleet crash semantics"): ownership/wire/quorum/staleness
@@ -79,15 +71,10 @@ live:
 # thread-driven 2-worker integration and v2 owner-part round trip, then
 # the subprocess drills — the real CLI fleet, the SIGKILL
 # crash-and-rejoin recovery, and the bounded-staleness convergence
-# acceptance (S∈{0,1,2} vs the synchronous loop, compression ON) — then
-# the 1/2/4-worker pinned scaling spec and the f32-vs-compressed wire
-# A/B (records land in BENCH_SESSION.jsonl with the per-phase
-# breakdown, the discard-counter ledger, and the wire-byte columns)
+# acceptance (S∈{0,1,2} vs the synchronous loop, compression ON)
 train-fleet:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_training_fleet.py tests/test_fleet_wire.py -q -m "not slow"
 	JAX_PLATFORMS=cpu python -m pytest tests/test_training_fleet.py -q -m slow
-	JAX_PLATFORMS=cpu python bench.py --cpu --training-fleet
-	JAX_PLATFORMS=cpu python bench.py --cpu --fleet-wire-ab
 
 # trainer-fleet observability plane (docs/OBSERVABILITY.md "Training
 # fleet"): srt_training_* dynamics-histogram golden grammar +
@@ -115,77 +102,20 @@ train-fleet-chaos:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_fleet_membership.py -q -m "not slow"
 	JAX_PLATFORMS=cpu python -m pytest tests/test_fleet_membership.py -q -m slow
 
-bench:
-	python bench.py
-
-# regression sentry (docs/OBSERVABILITY.md "Host resources & the run
-# ledger"): one fast bench smoke appends its fresh record to a scratch
-# session (SRT_BENCH_SESSION keeps throwaway runs OUT of committed
-# history), then `telemetry ledger regress` judges it against the latest
-# clean committed record for the same (spec, shape, platform, labels)
-# key. Exits 1 only on a confirmed clean-vs-clean regression beyond the
-# measurement's own noise band; a contended host makes the verdict
-# "untrusted", never red. The JSON verdict is the CI artifact.
-bench-gate:
-	rm -f .bench-gate-fresh.jsonl
-	SRT_BENCH_SESSION=.bench-gate-fresh.jsonl JAX_PLATFORMS=cpu python bench.py --cpu --configs cnn_tagger
-	JAX_PLATFORMS=cpu python -m spacy_ray_tpu telemetry ledger regress \
-		--record .bench-gate-fresh.jsonl --session BENCH_SESSION.jsonl \
-		--json-out bench-gate-verdict.json
-
-baseline:
-	python bench.py --measure-baseline
-
-profile:
-	python bin/profile_trf.py --sweep
-
-# per-step fixed-cost floor (PERF.md round 7): optimizer-update-only bench
-# (naive vs fused) + the MFU-vs-shape profile sweep. Compare two --trace
-# runs with: python bin/profile_trf.py --compare before.json after.json
-step-perf:
-	JAX_PLATFORMS=cpu python bench.py --cpu --update-only
-	JAX_PLATFORMS=cpu python bin/profile_trf.py --sweep
-
-# per-replica serving speed A/Bs (PERF.md rounds 9 + 13): window vs
-# continuous admission, and the f32 vs bf16 vs int8 precision-overlay
-# arms (the int8 arm self-forces SRT_PALLAS_INT8=1 on CPU so the pallas
-# kernel runs interpret-mode with an honest "forced" label), each
-# open-loop at FIXED offered rates (committed baseline + saturation
-# points) — then the Zipfian edge-cache spec through the real fleet at
-# the armed cache default (hit-rate x window p99, zero rejects/5xx).
-# Records append to BENCH_SESSION.jsonl with honest batching/precision
-# labels. The tier-1 smoke of the same harness lives in
-# tests/test_serving.py; interpret-mode int8 kernel tests run in tier-1
-# (tests/test_int8.py, CPU-only, fast) like the other pallas suites.
-serve-perf:
-	JAX_PLATFORMS=cpu python bench.py --cpu --serving-ab
-	JAX_PLATFORMS=cpu python bench.py --cpu --serving
-	JAX_PLATFORMS=cpu python bench.py --cpu --serving --zipfian
-
 # serving data plane (PR 20, docs/SERVING.md "Data plane"): the fast-tier
 # data-plane tests (conditional 304s + ETag/generation interaction,
-# length-affinity policy, pooled-connection stale-retry), then the
-# length-routing A/B through the real 2-replica fleet (pad share must
-# strictly drop), the Zipfian spec whose conditional arm commits 304
-# share + bytes saved, and the router-ceiling spec (pooled vs fresh-dial
-# arms against stub replicas, naming which side bounds the fleet)
+# length-affinity policy, pooled-connection stale-retry)
 serve-perf3:
 	JAX_PLATFORMS=cpu python -m pytest tests/test_fleet.py -q -m 'not slow' \
 		-k "conditional or suppressed or passthrough or length_ or stale_pooled or aux_conns"
 	JAX_PLATFORMS=cpu python -m pytest tests/test_serving.py -q -m 'not slow' \
 		-k "etag or conditional or pad or batch_span"
-	JAX_PLATFORMS=cpu python bench.py --cpu --serving --length-mix
-	JAX_PLATFORMS=cpu python bench.py --cpu --serving --zipfian
-	JAX_PLATFORMS=cpu python bench.py --cpu --serving --router-ceiling
 
-# cross-replica update sharding (PERF.md "Update sharding (round 11)"):
+# cross-replica update sharding (docs/TUNING.md §15):
 # the full==replicated equality suite + v2 owner-shard checkpoint format +
-# elastic (8->4->1) resume bit-exactness, then the sharded update-only A/B
-# (replicated vs zero1 vs full at 1/2/4/8 virtual devices, with the
-# grad-reduce/apply/allgather phase split on every record)
+# elastic (8->4->1) resume bit-exactness
 update-shard:
 	python -m pytest tests/test_update_sharding.py -q
-	python bench.py --update-only --sharded
 
 dryrun:
 	XLA_FLAGS="--xla_force_host_platform_device_count=8" python __graft_entry__.py

@@ -745,7 +745,7 @@ def process_rules(
 ) -> List[AlertRule]:
     """The host-resource leak detectors every role set carries, reading
     the ``process`` block hoststats injects into each role's alert
-    snapshot (docs/OBSERVABILITY.md "Host resources & the run ledger"):
+    snapshot (docs/OBSERVABILITY.md "Host resources"):
 
     * ``process-rss-growth`` — NET RSS growth beyond
       ``rss_growth_bytes`` inside the trailing ``rss_window_s``. The

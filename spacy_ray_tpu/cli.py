@@ -258,13 +258,19 @@ def train_command(argv: List[str]) -> int:
     overrides = parse_cli_overrides(extra)
     config = load_config(args.config_path, overrides, interpolate=False)
 
-    from .training.loop import train
-
     fleet_kwargs = None
     if args.fleet_worker_id is not None:
         if args.fleet_workers <= 0:
             parser.error("--fleet-worker-id requires --fleet-workers N")
-        from .training.fleet.worker import DEFAULT_FLEET_BASE_PORT
+        if args.profile is not None:
+            raise ValueError(
+                "fleet mode does not support --profile (profile one "
+                "worker via its own telemetry trace instead)"
+            )
+        from .training.fleet.worker import (
+            DEFAULT_FLEET_BASE_PORT,
+            train_fleet_worker,
+        )
 
         fleet_kwargs = {
             "worker_id": args.fleet_worker_id,
@@ -281,16 +287,29 @@ def train_command(argv: List[str]) -> int:
             "peer_lease_s": args.peer_lease_s,
         }
 
-    nlp, result = train(
-        config,
-        output_path=args.output,
-        n_workers=args.n_workers,
-        resume=args.resume,
-        profile_dir=args.profile,
-        metrics_dir=args.metrics_dir,
-        metrics_port=args.metrics_port,
-        fleet=fleet_kwargs,
-    )
+    if fleet_kwargs is not None:
+        # this process is ONE worker of the asynchronous trainer fleet
+        # (training/fleet/), not the in-mesh synchronous loop
+        nlp, result = train_fleet_worker(
+            config,
+            args.output,
+            resume=args.resume,
+            metrics_dir=args.metrics_dir,
+            metrics_port=args.metrics_port,
+            **fleet_kwargs,
+        )
+    else:
+        from .training.loop import train
+
+        nlp, result = train(
+            config,
+            output_path=args.output,
+            n_workers=args.n_workers,
+            resume=args.resume,
+            profile_dir=args.profile,
+            metrics_dir=args.metrics_dir,
+            metrics_port=args.metrics_port,
+        )
     if result.interrupted:
         from .training.resilience import RC_PREEMPTED
 
@@ -1539,9 +1558,8 @@ def debug_profile_command(argv: List[str]) -> int:
 def benchmark_command(argv: List[str]) -> int:
     """``benchmark speed`` / ``benchmark accuracy`` — spaCy's `spacy
     benchmark` surface. `speed` times bulk inference on a corpus with
-    warmup, reporting median words/s over N repetitions with min/max (the
-    same dispersion discipline as bench.py); `accuracy` is `evaluate`
-    under its spaCy-CLI name."""
+    warmup, reporting median words/s over N repetitions with min/max;
+    `accuracy` is `evaluate` under its spaCy-CLI name."""
     import time
 
     if argv and argv[0] == "accuracy":
@@ -1623,159 +1641,19 @@ def telemetry_command(argv: List[str]) -> int:
       per-worker loss trajectories, the phase-share table,
       staleness/discard histograms, quorum-wait/apply timing, and the
       alert/anomaly timeline (docs/OBSERVABILITY.md "Training fleet").
-    * ``ledger list|show|diff|regress`` — the run ledger: cross-run
-      performance history from BENCH_SESSION.jsonl (and run dirs),
-      normalized by (spec, platform, shape, config labels). ``diff``
-      compares two records against their own noise evidence and
-      refuses cross-platform pairs; ``regress`` judges fresh records
-      against the latest clean committed baseline and exits nonzero
-      only on a confirmed regression (docs/OBSERVABILITY.md "Host
-      resources & the run ledger").
     """
     usage = ("Usage: spacy_ray_tpu telemetry "
              "{summarize <metrics.jsonl-or-run-dir> | top <url>... | "
              "collect-trace [<url>...] [--fleet-base-port N --workers K] "
              "--out FILE | "
              "postmortem <bundle-or-incidents-dir> | "
-             "report <run-dir> [--out FILE] | "
-             "ledger {list|show|diff|regress} [--session FILE] ...}")
+             "report <run-dir> [--out FILE]}")
     if not argv or argv[0] not in (
         "summarize", "top", "collect-trace", "postmortem", "report",
-        "ledger",
     ):
         print(usage, file=sys.stderr)
         return 1
     sub, rest = argv[0], argv[1:]
-    if sub == "ledger":
-        parser = argparse.ArgumentParser(
-            prog="spacy_ray_tpu telemetry ledger"
-        )
-        parser.add_argument("action",
-                            choices=("list", "show", "diff", "regress"))
-        parser.add_argument("selectors", nargs="*", metavar="SEL",
-                            help="show: a record NAME; diff: exactly two "
-                            "selectors, each NAME[@IDX] (chronological "
-                            "index into that name's history, default -1 "
-                            "= newest) or a path to a records .jsonl "
-                            "(its last record); list: optional NAME "
-                            "filters")
-        parser.add_argument("--session", type=Path,
-                            default=Path("BENCH_SESSION.jsonl"),
-                            help="the committed bench session file — the "
-                            "ledger's history (default "
-                            "./BENCH_SESSION.jsonl)")
-        parser.add_argument("--run-dir", type=Path, action="append",
-                            default=[], dest="run_dirs",
-                            help="also ingest a telemetry run directory "
-                            "as ledger rows (repeatable)")
-        parser.add_argument("--record", type=Path, default=None,
-                            help="regress: fresh record file (jsonl) to "
-                            "judge against the session history; without "
-                            "it, each key's newest session record is "
-                            "judged against its own predecessors")
-        parser.add_argument("--floor", type=float, default=None,
-                            help="noise-band floor as a ratio (default "
-                            "0.05): deltas inside max(floor, rep "
-                            "dispersion, reprobe slack) are never "
-                            "verdicts")
-        parser.add_argument("--json-out", type=Path, default=None,
-                            help="diff/regress: also write the verdict "
-                            "as JSON (the bench-gate CI artifact)")
-        args = parser.parse_args(rest)
-
-        from .training import runledger as rl
-
-        floor = args.floor if args.floor is not None else rl.NOISE_FLOOR
-
-        def _write_json(payload: dict) -> None:
-            if args.json_out is None:
-                return
-            args.json_out.parent.mkdir(parents=True, exist_ok=True)
-            args.json_out.write_text(
-                json.dumps(payload, indent=2, sort_keys=True) + "\n",
-                encoding="utf8",
-            )
-            print(f"verdict written to {args.json_out}", file=sys.stderr)
-
-        def _pick(rows, sel: str):
-            # a selector is either a records file (take its last row)
-            # or NAME[@IDX] into the loaded history
-            p = Path(sel)
-            if p.is_file():
-                file_rows, _ = rl.ingest_session(p)
-                if not file_rows:
-                    raise rl.LedgerError(f"no ledger rows in {sel}")
-                return file_rows[-1]
-            name, _, idx_s = sel.partition("@")
-            hist = [r for r in rows if r["name"] == name]
-            if not hist:
-                raise rl.LedgerError(
-                    f"no ledger rows named {name!r} "
-                    f"(try: telemetry ledger list --session {args.session})"
-                )
-            try:
-                return hist[int(idx_s) if idx_s else -1]
-            except (IndexError, ValueError):
-                raise rl.LedgerError(
-                    f"bad index {idx_s!r} for {name!r} "
-                    f"({len(hist)} record(s) in history)"
-                )
-
-        try:
-            rows, skipped = rl.ingest_session(args.session)
-            for rd in args.run_dirs:
-                rows.extend(rl.ingest_run_dir(rd))
-            if args.action == "list":
-                if args.selectors:
-                    rows = [r for r in rows if r["name"] in args.selectors]
-                print(rl.render_rows(rows, skipped=skipped))
-                return 0
-            if args.action == "show":
-                if len(args.selectors) != 1:
-                    parser.error("show takes exactly one record NAME")
-                print(rl.render_history(rows, args.selectors[0]))
-                return 0
-            if args.action == "diff":
-                if len(args.selectors) != 2:
-                    parser.error("diff takes exactly two selectors "
-                                 "(NAME[@IDX] or a records file)")
-                d = rl.diff_rows(
-                    _pick(rows, args.selectors[0]),
-                    _pick(rows, args.selectors[1]),
-                    floor=floor,
-                )
-                print(rl.render_diff(d))
-                _write_json(d)
-                return 0
-            # regress
-            if args.record is not None:
-                fresh, _ = rl.ingest_session(args.record)
-                pool = rows
-            else:
-                by_key: dict = {}
-                for r in rows:
-                    by_key.setdefault(rl.row_key(r), []).append(r)
-                fresh = [h[-1] for h in by_key.values()]
-                pool = [r for h in by_key.values() for r in h[:-1]]
-            if not fresh:
-                print("no fresh records to judge", file=sys.stderr)
-                return 2
-            verdicts = rl.regress(fresh, pool, floor=floor)
-            print(rl.render_verdicts(verdicts))
-            _write_json({
-                "floor": floor,
-                "session": str(args.session),
-                "verdicts": verdicts,
-            })
-            return 1 if any(
-                v["verdict"] == "regression" for v in verdicts
-            ) else 0
-        except rl.LedgerError as e:
-            print(str(e), file=sys.stderr)
-            return 2
-        except OSError as e:
-            print(str(e), file=sys.stderr)
-            return 2
     if sub == "report":
         parser = argparse.ArgumentParser(
             prog="spacy_ray_tpu telemetry report"

@@ -2,8 +2,8 @@
 
 This module imports nothing of the package at import time, so callers that
 must run before anything else — test conftests, the multichip dryrun,
-``bench.py``, ``chip_smoke.py`` children — can import it without pulling
-the full package.
+``chip_smoke.py`` children — can import it without pulling the full
+package.
 """
 
 from __future__ import annotations
@@ -101,8 +101,8 @@ def enable_compile_cache() -> str:
     directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads
     it, and no directory is set in code at all; otherwise the cache lives
     at the fixed ``<checkout>/.xla_cache``. Every process of a run — CLI
-    commands, fleet workers, replicas, ``bench.py``, ``chip_smoke.py``
-    children — calls this, so they share one cache."""
+    commands, fleet workers, replicas, ``chip_smoke.py`` children —
+    calls this, so they share one cache."""
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

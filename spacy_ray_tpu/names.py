@@ -94,5 +94,4 @@ KERNEL_INT8_MATMUL = "srt_int8_matmul"
 PROGRAM_TRAIN_STEP = "srt_train_step"
 PROGRAM_TRAIN_STEP_MULTI = "srt_train_step_multi"  # steps_per_dispatch > 1
 PROGRAM_EVAL_FORWARD = "srt_eval_forward"
-PROGRAM_UPDATE_ONLY = "srt_update_only"  # bench.py's optimizer-only program
 PROGRAM_SHARD_APPLY = "srt_shard_apply"  # the trainer fleet's owner-shard apply

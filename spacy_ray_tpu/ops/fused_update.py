@@ -17,8 +17,8 @@ lr) into the step; this module provides the same math as ONE update:
   — probe-gated exactly like the flash-attention kernel: compiled and
   numerically validated against the XLA math at startup, forced with
   SRT_PALLAS_FUSED=1/0, auto-enabled on TPU only. CPU tests run it in
-  interpret mode. Its perf claim is only as good as bench records that say
-  ``"fused_update": "active (pallas)"``.
+  interpret mode. Its perf claim is only as good as a ``runtime`` line that
+  says ``"fused_update": "active (pallas)"``.
 
 Numerical contract: the fused math mirrors the installed optax's exact
 expressions (optax 0.2.3: ``scale_by_adam``/``scale_by_radam`` moment and
@@ -241,7 +241,7 @@ def fused_kernel_status() -> str:
 
 
 def fused_status(tx: Any, mesh: Any = None) -> str:
-    """Honest-labeling string for bench records: what the optimizer update
+    """Honest-labeling string for the ``runtime`` line: what the optimizer update
     path ACTUALLY is (a CPU fallback must not masquerade as the kernel).
 
     ``mesh`` is the mesh the update was compiled under: the kernel gate

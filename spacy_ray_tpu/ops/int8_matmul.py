@@ -25,8 +25,8 @@ Honesty rules (the gate every kernel goes through, ops/probe.py):
 
 * enabled ONLY by :func:`int8_probe` — compile + numeric validation vs
   the f32-dequant reference on the current backend; ``SRT_PALLAS_INT8=1``
-  forces on (interpret-mode on non-TPU backends, so CPU tests and the
-  forced bench arm run the REAL kernel body, interpreted), ``=0`` forces
+  forces on (interpret-mode on non-TPU backends, so CPU tests run the
+  REAL kernel body, interpreted), ``=0`` forces
   off; default auto-enables on TPU only, where a failed probe raises.
 * the probe's reason string is the overlay label's source of truth:
   "active (pallas)" only when the compiled kernel runs, "active (pallas
@@ -193,7 +193,7 @@ def _int8_matmul_raw(
     the block grid (zero rows/columns contribute nothing; padded scale
     columns are sliced away with their outputs)."""
     if interpret is None:
-        # forced-on non-TPU backends (CPU tests, the forced bench arm)
+        # forced-on non-TPU backends (CPU tests)
         # run the same kernel body through the pallas interpreter — the
         # numbers are the kernel's, only the execution engine differs
         interpret = _INTERPRET or jax.default_backend() != "tpu"

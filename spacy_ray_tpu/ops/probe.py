@@ -139,7 +139,7 @@ class Gate:
 
     def reset(self) -> None:
         """Forget the verdict and the paths: the next caller probes again
-        (a bench spec that changes the kernel's env, a second run in one
+        (a test that changes the kernel's env, a second run in one
         process whose status must be its own)."""
         self.armed = None
         self.paths.clear()

@@ -95,7 +95,7 @@ def resolve_update_sharding(
 
 
 def update_sharding_status(mode: str, mesh: Optional[Mesh] = None) -> str:
-    """Honest-labeling string for bench records / ``info --probe``: what
+    """Honest-labeling string for the ``runtime`` line / ``info --probe``: what
     the update phase ACTUALLY does, the same discipline as
     ``fused_update``'s label — a single-replica mesh must not masquerade
     as a sharded update."""
@@ -448,8 +448,7 @@ def make_train_step(
 
     def lower(*args):
         # same mesh install as ``run``: model code consults the mesh at
-        # trace time, and lowering traces without executing (used by
-        # bench.py for XLA cost analysis — FLOPs/step for MFU accounting)
+        # trace time, and lowering traces without executing
         with pctx.use_mesh(mesh):
             return jitted.lower(*args)
 
@@ -464,92 +463,20 @@ def make_train_step(
     return run
 
 
-def make_update_only(
-    tx: Any,
-    mesh: Mesh,
-    update_sharding: Any,
-    opt_state_template: Any,
-    *,
-    donate: bool = True,
-    gather: bool = True,
-) -> Callable:
-    """Jitted optimizer-update-ONLY program (no forward/backward): takes
-    (params, opt_state, grads) and returns (params, opt_state).
-
-    This is the microbench path (``bench.py --update-only --sharded``)
-    and it shares the exact mode semantics of :func:`make_train_step`'s
-    update section — pin-the-grads barrier, owner-shard apply, final
-    allgather — so the A/B measures the program the training loop runs,
-    not a bench-only approximation. ``gather=False`` (only meaningful
-    under "full") stops BEFORE the params allgather and returns
-    owner-sharded params: the bench's isolated "apply" phase.
-    """
-    mode = _mode_of(update_sharding)
-    multi_replica = int(mesh.shape["data"]) > 1
-    full_sharded = mode == "full" and multi_replica
-    pin_grads = multi_replica and mode in ("replicated", "full")
-    applies_updates = bool(getattr(tx, "applies_updates", False))
-
-    def update(params, opt_state, grads):
-        if pin_grads:
-            grads = jax.lax.optimization_barrier(
-                _constrain_replicated(grads, mesh)
-            )
-        upd_params = (
-            _constrain_owner_shards(params, mesh) if full_sharded else params
-        )
-        if applies_updates:
-            new_params, new_opt_state = tx.update(grads, opt_state, upd_params)
-        else:
-            import optax as _optax
-
-            updates, new_opt_state = tx.update(grads, opt_state, upd_params)
-            if full_sharded:
-                updates = _constrain_owner_shards(updates, mesh)
-            new_params = _optax.apply_updates(upd_params, updates)
-        if full_sharded:
-            new_params = _constrain_owner_shards(new_params, mesh)
-            if gather:
-                new_params = _constrain_replicated(new_params, mesh)
-        return new_params, new_opt_state
-
-    repl = replicated(mesh)
-    opt_sh = opt_state_shardings(opt_state_template, mesh, mode)
-    jit_kwargs: Dict[str, Any] = {
-        "in_shardings": (repl, opt_sh, repl),
-    }
-    if gather or not full_sharded:
-        jit_kwargs["out_shardings"] = (repl, opt_sh)
-    # gather=False: no out_shardings — the in-program owner-shard
-    # constraints fully pin the (sharded) output placement
-    if donate:
-        jit_kwargs["donate_argnums"] = (0, 1)
-    update.__name__ = names.PROGRAM_UPDATE_ONLY
-    jitted = jax.jit(update, **jit_kwargs)
-
-    def run(*args):
-        with pctx.use_mesh(mesh):
-            return jitted(*args)
-
-    run.mesh = mesh
-    run.update_sharding = mode
-    run.gather = gather
-    return run
-
-
 def make_shard_apply(tx: Any, *, donate: bool = True) -> Callable:
     """Jitted single-shard optimizer apply: ``(params, opt_state, grads)
     -> (params, opt_state)`` over ONE owner's slice tree, no mesh.
 
     This is the trainer fleet's apply entry point (training/fleet/): the
-    cross-process analogue of ``make_update_only`` where the "shard" is
-    the nested slice tree a fleet worker owns (ownership.py) rather than
-    a mesh-sharded leaf — the owner applies the optimizer to exactly the
-    parameters it owns, at quorum, and nothing else (PAPER.md §L3
-    owner-applies-the-update). ``tx`` may be the fused transformation
-    (``applies_updates`` — ops/fused_update.py on the owned slice, as in
-    the in-mesh "full" mode) or a plain optax chain. State and params
-    are donated: the owner holds exactly one live copy of its shard.
+    cross-process analogue of :func:`make_train_step`'s update section,
+    where the "shard" is the nested slice tree a fleet worker owns
+    (ownership.py) rather than a mesh-sharded leaf — the owner applies
+    the optimizer to exactly the parameters it owns, at quorum, and
+    nothing else (PAPER.md §L3 owner-applies-the-update). ``tx`` may be
+    the fused transformation (``applies_updates`` — ops/fused_update.py
+    on the owned slice, as in the in-mesh "full" mode) or a plain optax
+    chain. State and params are donated: the owner holds exactly one
+    live copy of its shard.
 
     Wire compression is invisible here: compressed gradient pushes are
     dequantized to f32 at the wire boundary (fleet/wire.decode_grads)

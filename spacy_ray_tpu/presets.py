@@ -1,4 +1,4 @@
-"""Preset config strings: canonical pipeline shapes used by bench,
+"""Preset config strings: canonical pipeline shapes used by
 __graft_entry__, tests, and the ``init-config`` CLI command (the role of
 ``spacy init config`` templates in the reference ecosystem)."""
 

@@ -15,8 +15,7 @@ Warmup (:func:`warmup_buckets`) compiles the forward program for every
 bucket shape the admission rules can produce, so steady-state serving
 never pays a compile on a live request — the same reasoning as the
 trainer's shape bucketing (SURVEY.md §7), and the bucket tables are the
-trainer's own (``training/batcher.py``). ``bench.py --serving`` imports
-the same sweep, so load tests exercise exactly the warmed shapes.
+trainer's own (``training/batcher.py``).
 
 Telemetry is a nullable :class:`ServingTelemetry` facade over
 ``training/telemetry.py``'s registry + trace buffer: request-latency
@@ -57,9 +56,9 @@ __all__ = [
     "SERVING_DEFAULTS",
 ]
 
-# One place for the serving knob defaults: the CLI, the bench load specs,
-# and the tests read these — a bench that "agrees with serve" must not
-# restate numbers that can drift. ``batching`` defaults to continuous
+# One place for the serving knob defaults: the CLI and the tests read
+# these, so neither restates numbers that can drift. ``batching``
+# defaults to continuous
 # admission (the window discipline survives behind the knob for A/Bs and
 # for operators who want to trade latency for bigger batches);
 # ``max_wait_s`` only applies in window mode. ``precision`` is the
@@ -89,9 +88,7 @@ def warmup_buckets(
     1..max_doc_len tokens — table buckets up to the cap plus, beyond the
     table's top, each multiple of the top bucket (that is
     ``bucket_length``'s overflow rule). Completeness is the contract: a
-    live request must never meet a shape this sweep did not compile.
-    Shared by the engine's warmup sweep and ``bench.py --serving`` so
-    warmup and load tests agree on shapes by construction."""
+    live request must never meet a shape this sweep did not compile."""
     b_cap = bucket_batch_size(int(max_batch_docs))
     t_cap = bucket_length(int(max_doc_len), length_buckets)
     bs: List[int] = []
@@ -160,8 +157,8 @@ class ServingTelemetry:
         self.trace = TraceBuffer(
             clock=clock, pid=int(process_index), max_events=trace_max_events
         )
-        # host-resource truth (docs/OBSERVABILITY.md "Host resources &
-        # the run ledger"): inside the facade so telemetry-off serving
+        # host-resource truth (docs/OBSERVABILITY.md "Host
+        # resources"): inside the facade so telemetry-off serving
         # constructs no sampler; rate-limited internally, so the
         # observer loop, /metrics scrapes and router polls share one
         # cached /proc read
@@ -496,7 +493,7 @@ class InferenceEngine:
         # (warmup sweep included, so warmed programs match live traffic's
         # param dtypes) consumes self.serve_params, never nlp.params
         # directly. overlay.resolved/label are the honest story /healthz
-        # and the bench records carry.
+        # carries.
         from .overlay import build_serving_overlay
 
         self.precision = precision

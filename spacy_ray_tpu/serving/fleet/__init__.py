@@ -9,8 +9,7 @@ subprocess per replica, backoff restarts on crash, drain-aware stops),
 and the :class:`~.autoscaler.AutoscalerPolicy` (hysteresis scaling
 between min/max replicas driven by the engines' own SLO telemetry).
 
-Entry point: ``spacy-ray-tpu serve-fleet <model_dir>`` (cli.py);
-load-tested by ``bench.py --serving --replicas N``.
+Entry point: ``spacy-ray-tpu serve-fleet <model_dir>`` (cli.py).
 """
 
 from .autoscaler import (

@@ -49,7 +49,7 @@ logger = logging.getLogger("spacy_ray_tpu.serving")
 
 @dataclass
 class FleetConfig:
-    """Everything a fleet needs; CLI flags and bench specs both build
+    """Everything a fleet needs; CLI flags and tests both build
     one of these (one knob surface, no drift)."""
 
     model_path: str
@@ -222,8 +222,8 @@ class FleetConfig:
 
 class Fleet:
     """One fleet lifecycle: ``run()`` for the CLI (signal handlers +
-    banner), ``start()``/``request_shutdown()``/``wait()`` for tests and
-    the bench — the same drain code either way, mirroring ``Server``."""
+    banner), ``start()``/``request_shutdown()``/``wait()`` for tests —
+    the same drain code either way, mirroring ``Server``."""
 
     def __init__(self, config: FleetConfig) -> None:
         self.config = config

@@ -558,7 +558,7 @@ def build_serve_cmd(
     extra_args: Sequence[str] = (),
 ) -> List[str]:
     """The canonical replica argv: one place building the ``serve`` line
-    so the CLI, the bench, and the tests can't drift on flag names."""
+    so the CLI and the tests can't drift on flag names."""
     cmd = [
         sys.executable, "-m", "spacy_ray_tpu", "serve", str(model_path),
         "--host", host, "--port", str(int(port)), "--device", device,

@@ -635,8 +635,8 @@ class Router:
     def cache_stats(self) -> Optional[Dict[str, Any]]:
         """The cache's own counters plus the router-side mixed-generation
         bypass count — ONE ledger for every surface (JSON /metrics,
-        the Prometheus ``srt_router_cache_*`` series, ``telemetry top``,
-        and the Zipfian bench record all read this)."""
+        the Prometheus ``srt_router_cache_*`` series and ``telemetry
+        top`` all read this)."""
         if self.cache is None:
             return None
         stats = self.cache.stats()

@@ -62,8 +62,8 @@ DEFAULT_CLASS = "default"
 class ClassSpec:
     """One SLO class: ``weight`` is the fair-queuing share (docs
     dispatched under saturation converge to the weight ratio), and
-    ``p99_target_ms`` is the window-p99 bound the placement policy and
-    the bench isolation contract judge this class against."""
+    ``p99_target_ms`` is the window-p99 bound the placement policy
+    judges this class against."""
 
     name: str
     weight: float = 1.0

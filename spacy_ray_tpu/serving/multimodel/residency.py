@@ -71,8 +71,8 @@ class ResidencyManager:
         self._last_used: Dict[str, float] = {}
         self._loading: Dict[str, threading.Event] = {}
         self._load_errors: Dict[str, str] = {}
-        # residency churn ledger (plain ints; /healthz and the bench
-        # record read them — no telemetry objects constructed here)
+        # residency churn ledger (plain ints; /healthz reads them —
+        # no telemetry objects constructed here)
         self.loads = 0
         self.evictions = 0
 

@@ -31,8 +31,8 @@ Honesty rules (the same discipline as every pallas kernel claim):
   int8 weight-only overlay only where the pallas dequant-in-kernel
   matmul (ops/int8_matmul.py) compiles AND validates on the current
   backend — auto-armed on TPU, OFF on CPU unless ``SRT_PALLAS_INT8=1``
-  forces the interpret-mode kernel (tests, drills, the forced bench
-  arm), the same auto policy shape as bf16. The overlay quantizes the
+  forces the interpret-mode kernel (tests, drills), the same auto
+  policy shape as bf16. The overlay quantizes the
   trunk's dense matmul weights per-output-channel
   (``models/transformer.py build_int8_overlay``) and REFUSES — f32
   served, refusal in the label — on unknown trunk leaves, trunk-less
@@ -40,8 +40,8 @@ Honesty rules (the same discipline as every pallas kernel claim):
   coverage; an "int8" label over mostly-f32 weight mass would lie).
 
 Every refusal/downgrade is also a structured ``log_event`` row, and the
-resolved label travels into ``/healthz``, bench records, and PERF.md —
-a record can never claim a precision the device is not actually using.
+resolved label travels into ``/healthz`` — a reply can never claim a
+precision the device is not actually using.
 """
 
 from __future__ import annotations

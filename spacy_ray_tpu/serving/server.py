@@ -208,7 +208,7 @@ class _Handler(BaseHTTPRequestHandler):
                     "max_doc_len": self.server.engine.max_doc_len,
                     # the engine's honest labels: admission discipline
                     # and the precision the device actually runs —
-                    # operators and bench records read them here
+                    # operators read them here
                     "batching": self.server.engine.batching,
                     "precision": self.server.engine.overlay.resolved,
                     "precision_label": self.server.engine.overlay.label,

@@ -157,8 +157,8 @@ class TopModel:
                 for k, v in (sub.get("counters") or {}).items():
                     if isinstance(v, (int, float)):
                         counters[f"model.{mname}.{k}"] = v
-            # the edge cache's ledger (router /metrics "cache" block —
-            # the same surface the Zipfian bench record reads): lifetime
+            # the edge cache's ledger (router /metrics "cache" block):
+            # lifetime
             # hit rate over hits+misses; None when the cache is off
             cache = payload.get("cache")
             cache_hit_rate = None

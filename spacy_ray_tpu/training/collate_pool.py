@@ -28,8 +28,7 @@ Three pieces, composable and individually inert when disabled:
 * :class:`PipelineStats` — thread-safe per-stage timers (read /
   collate / transfer / queue-wait, the stages inside a collate call, and
   the loop's own host time: the keys are in ``spacy_ray_tpu/names.py``)
-  + cache counters, surfaced in the training log at every eval row and
-  stamped into bench records (``bench.py --input-pipeline``). Its
+  + cache counters, surfaced in the training log at every eval row. Its
   ``timer`` is the one span call of the training path: stage seconds, a
   Chrome-trace span when telemetry is on, and a profiler annotation.
 """
@@ -315,17 +314,14 @@ def cached_collate(
     collate: Callable[[List[Any], int, int], Any],
     stats: Optional[PipelineStats] = None,
 ) -> Any:
-    """The one get-else-collate-and-put sequence, shared by the training
-    loop's collate stage and ``bench.py --input-pipeline`` so the
-    benchmark measures the exact pipeline training runs (cache semantics
-    can't drift between the two). ``cache=None`` degrades to a plain
+    """The one get-else-collate-and-put sequence of the training loop's
+    collate stage. ``cache=None`` degrades to a plain
     ``collate`` call; stats (when given) count hits/misses only while a
     cache is active.
 
     Also the ``collate`` fault-injection site (training/resilience.py):
-    living here, an injected collation failure exercises the SAME path —
-    including pool-worker → consumer re-raise — for the loop and the
-    bench."""
+    living here, an injected collation failure exercises the loop's own
+    path, pool-worker → consumer re-raise included."""
     maybe_fail("collate")
     value = cache.get(examples, B, T) if cache is not None else None
     if value is None:

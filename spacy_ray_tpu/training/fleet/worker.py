@@ -29,7 +29,7 @@ their full chain per-shard (per-shard clip — documented caveat,
 TUNING.md §19).
 
 Per-phase wall time (data / pull / grad / push / apply_wait) is
-accounted every step and lands on the bench record and the per-worker
+accounted every step and lands on the per-worker
 result file ``fleet-worker-{k}.json`` (which doubles as the CI failure
 artifact's discard-counter ledger).
 """
@@ -204,7 +204,8 @@ def train_fleet_worker(
     grad_error_feedback: bool = True,
 ) -> Tuple[Any, Any]:
     """Run ONE fleet worker process; returns ``(nlp, TrainResult)`` like
-    :func:`~..loop.train` (whose ``fleet=`` mode delegates here).
+    :func:`~..loop.train` (``train --fleet-worker-id`` calls this in its
+    place).
 
     ``metrics_port`` is unused (the peer server IS the telemetry
     endpoint — one port per worker, ``base_port + worker_id``); accepted

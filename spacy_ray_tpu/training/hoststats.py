@@ -1,8 +1,6 @@
 """Host-resource truth: ``/proc``-based process sampling plus
 cgroup-aware core accounting — the live resource signals every role in
-the system exports and the bench's machine-derived contention stamp.
-
-Two consumers, one module:
+the system exports.
 
 * **Live surfaces.** A :class:`ProcessSampler` owned by each role's
   telemetry facade (trainer ``Telemetry``, fleet peer, serving
@@ -16,14 +14,12 @@ Two consumers, one module:
   so the leak rules read ``process.rss_bytes`` / ``process.open_fds``
   with the same dotted-path grammar as every other rule.
 
-* **The bench stamp.** ``bench.py`` used to hand-maintain
-  ``cores_available`` / ``contended`` constants; :func:`effective_cores`
-  (min of cpu_count, sched affinity, and the cgroup cpu quota — v2
-  ``cpu.max`` or v1 ``cfs_quota_us``/``cfs_period_us``) and
-  :func:`contention_probe` (core arithmetic + a short busy-spin
-  efficiency check) mechanize them, and :func:`host_block` is the
-  ``host`` dict every bench record now carries for the run ledger
-  (``runledger.py``) to ingest.
+* **Core accounting.** :func:`effective_cores` (min of cpu_count,
+  sched affinity, and the cgroup cpu quota — v2 ``cpu.max`` or v1
+  ``cfs_quota_us``/``cfs_period_us``), :func:`contention_probe` (core
+  arithmetic + a short busy-spin efficiency check) and
+  :func:`host_block`, the ``host`` dict that gathers both with the
+  process RSS peak.
 
 Honesty rules, same as the exposition layer: a field whose ``/proc``
 file is missing or unparsable is ``None`` (no-signal), never a fake 0 —
@@ -34,8 +30,8 @@ construction, so the first sample reports utilization since the facade
 came up (never a meaningless since-boot average), and stays ``None``
 only when no wall time has passed or ``stat`` is unreadable.
 
-Stdlib-only and jax-free: importable by the router, ``telemetry top``,
-and the ledger CLI without dragging in an accelerator runtime.
+Stdlib-only and jax-free: importable by the router and ``telemetry top``
+without dragging in an accelerator runtime.
 """
 
 from __future__ import annotations
@@ -296,7 +292,7 @@ def effective_cores(
 ) -> Dict[str, Any]:
     """The cores this process can ACTUALLY burn: min of the visible CPU
     count, the sched affinity mask, and the cgroup cpu quota — with
-    provenance, because the bench's ``host`` block records not just the
+    provenance, because the ``host`` block records not just the
     number but why (a ``cores: 1`` from a cgroup quota on a 64-core box
     is a very different run from a real single-core host)."""
     if cpu_count is None:
@@ -398,10 +394,9 @@ def host_block(
     sampler: Optional[ProcessSampler] = None,
     cgroup_root: str = "/sys/fs/cgroup",
 ) -> Dict[str, Any]:
-    """The ``host`` dict a bench record carries: machine-derived core
-    accounting (+ the contention verdict when the caller says how many
-    cores the arm wants) and the process RSS peak — everything the run
-    ledger needs to decide whether a record is baseline-worthy."""
+    """The ``host`` dict: machine-derived core accounting (+ the
+    contention verdict when the caller says how many cores it wants)
+    and the process RSS peak."""
     cores = effective_cores(cgroup_root=cgroup_root)
     out: Dict[str, Any] = dict(cores)
     if cores_needed is not None:

@@ -489,7 +489,6 @@ def train(
     profile_dir: Optional[Path] = None,
     metrics_dir: Optional[Path] = None,
     metrics_port: Optional[int] = None,
-    fleet: Optional[Dict[str, Any]] = None,
 ) -> Tuple[Pipeline, TrainResult]:
     """Run config-driven training. Returns (pipeline, result).
 
@@ -503,33 +502,7 @@ def train(
     ``metrics_dir``: override for ``[training] metrics_dir`` — enables the
     telemetry subsystem (metrics.jsonl + Chrome trace + anomaly
     detectors, training/telemetry.py).
-
-    ``fleet``: asynchronous trainer-fleet worker mode (training/fleet/ —
-    the paper's cross-process parameter-ownership scheme). A dict of
-    :func:`~.fleet.worker.train_fleet_worker` keywords (at least
-    ``worker_id`` and ``n_workers``); this process becomes ONE fleet
-    worker exchanging gradients/params with its peers over HTTP instead
-    of running the in-mesh synchronous loop. Mutually exclusive with
-    multi-host jax and ``profile_dir``.
     """
-    if fleet:
-        if profile_dir is not None:
-            raise ValueError(
-                "fleet mode does not support --profile (profile one "
-                "worker via its own telemetry trace instead)"
-            )
-        from .fleet.worker import train_fleet_worker
-
-        return train_fleet_worker(
-            config,
-            output_path,
-            resume=resume,
-            stdout_log=stdout_log,
-            metrics_dir=metrics_dir,
-            metrics_port=metrics_port,
-            max_steps_override=max_steps_override,
-            **fleet,
-        )
     config = config.interpolate()
     T = resolve_training(config)
     seed = int(T.get("seed") or 0)

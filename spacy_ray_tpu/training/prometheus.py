@@ -2,8 +2,8 @@
 registries — the bridge from the bespoke JSON ``/metrics`` payloads to
 any off-the-shelf scraper.
 
-The JSON snapshots stay the in-repo contract (the autoscaler, the canary
-guard, ``bench.py`` all read them); this module renders the SAME
+The JSON snapshots stay the in-repo contract (the autoscaler and the
+canary guard read them); this module renders the SAME
 snapshot dicts as standard exposition text, so ``GET
 /metrics?format=prometheus`` on a replica, the router, or the trainer
 needs no second bookkeeping path that could drift from the JSON one.

@@ -5,17 +5,15 @@ rows with per-step loss, eval rows, anomaly rows, the ``kind: "fleet"``
 exit row carrying the dynamics histograms), and the alert-transition
 ``alerts.jsonl`` sinks.
 
-This is the committed-evidence artifact of a fleet round: the bench
-harness writes it next to its records, CI uploads it next to the ledger
-artifacts on failure, and the future ``tune`` subcommand reads the same
-queryable record (ROADMAP item 4). Stdlib-only and jax-free — it runs
+This is the committed-evidence artifact of a fleet round: CI uploads it
+next to the ledger artifacts on failure. Stdlib-only and jax-free — it runs
 anywhere the ledgers can be copied to.
 
 Layout expectations (what the trainer-fleet writers produce):
 
 * ``<run-dir>/fleet-worker-{k}.json`` — exit ledger per worker;
 * ``<run-dir>/metrics/fleet-worker-{k}/metrics.jsonl`` + ``alerts.jsonl``
-  (``--metrics-dir <run-dir>/metrics``, the bench/test convention) — an
+  (``--metrics-dir <run-dir>/metrics``, the tests' convention) — an
   explicit ``metrics_dir`` can point elsewhere;
 * a single-process run (``metrics.jsonl`` directly under the run dir or
   its ``metrics/``) gets the same report minus the fleet-only sections.
@@ -152,8 +150,8 @@ def fleet_exit_rows(run: Dict[str, Any]) -> Dict[int, Dict[str, Any]]:
 def sum_staleness(rows: Any) -> Optional[Dict[str, Any]]:
     """Cross-worker staleness histogram from fleet exit rows: cumulative
     buckets on the SHARED table sum exactly per ``le``. The one
-    aggregation rule, used by the report's totals column and the bench
-    record's ``staleness`` block. None when no row carries counts."""
+    aggregation rule, used by the report's totals column. None when no
+    row carries counts."""
     buckets: Dict[float, int] = {}
     count = 0
     mx: Optional[float] = None
@@ -217,16 +215,11 @@ def _sample(series: List[Tuple[int, float]], n: int = 8) -> List[Tuple[int, floa
 def build_run_report(
     run_dir: Path,
     metrics_dir: Optional[Path] = None,
-    *,
-    run: Optional[Dict[str, Any]] = None,
 ) -> str:
     """The markdown run report (see module docstring). Sections appear
     only when their evidence exists — an honest report of what the run
-    recorded, not a template of dashes. Pass an already-:func:`load_run`
-    result via ``run`` to skip the second read (the bench harness loads
-    once for its record AND its report)."""
-    if run is None:
-        run = load_run(run_dir, metrics_dir)
+    recorded, not a template of dashes."""
+    run = load_run(run_dir, metrics_dir)
     workers = run["workers"]
     ids = sorted(workers)
     ledgers = {
@@ -491,8 +484,8 @@ def build_run_report(
             "## Host resources",
             "",
             "Per-worker `/proc` truth sampled at eval boundaries "
-            "(training/hoststats; docs/OBSERVABILITY.md \"Host resources "
-            "& the run ledger\"). High involuntary ctx switches with low "
+            "(training/hoststats; docs/OBSERVABILITY.md \"Host "
+            "resources\"). High involuntary ctx switches with low "
             "cpu% = the host is contended, not the model slow.",
             "",
             "| worker | cpu% last | cpu% max | rss | rss peak | threads "

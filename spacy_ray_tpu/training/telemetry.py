@@ -7,7 +7,7 @@ busy, which cannot be verified without per-stage visibility — SURVEY.md
 citizens, and Ray (Moritz et al., arXiv:1712.05889) ships system-wide
 timeline tracing as a core primitive precisely because distributed
 training stalls are invisible in aggregate throughput numbers. This
-module is the one layer a dashboard, a bench run, or a post-mortem
+module is the one layer a dashboard, the benchmark, or a post-mortem
 consumes; every later perf PR reports through it.
 
 Four pieces, individually inert and composable:
@@ -22,14 +22,12 @@ Four pieces, individually inert and composable:
   (Perfetto-loadable JSON): host stages (read / collate / transfer /
   queue-wait, emitted through :class:`~.collate_pool.PipelineStats`),
   eval, checkpoint save/load, preemption drains, and device-step
-  boundaries. ``bench.py --input-pipeline`` attaches the same emitter —
-  bench spans and training spans can never drift apart.
+  boundaries.
 * device sampling (:func:`sample_device_telemetry`) at eval boundaries:
   HBM usage via ``device.memory_stats()`` (None off-TPU), live-buffer
   counts, and a cumulative compile counter fed by a ``jax.monitoring``
   listener (:func:`install_compile_hook`) — the recompilation-storm
-  signal. :func:`program_flops` is the XLA cost-analysis path bench.py's
-  MFU accounting reuses.
+  signal.
 * :class:`AnomalyDetectors` — NaN/Inf loss, loss spike vs rolling
   median, step-time regression vs rolling p50, recompile-after-warmup.
   Every firing goes through ``resilience.log_event`` (so it lands in the
@@ -77,7 +75,6 @@ __all__ = [
     "install_compile_hook",
     "compile_count",
     "sample_device_telemetry",
-    "program_flops",
     "device_peak_flops",
     "sanitize_json",
     "summarize_metrics",
@@ -815,8 +812,7 @@ class TraceBuffer:
 # (libtpu 0.0.34) reports — a substring match would hand any future kind
 # containing "v5" the v5e's number. A kind that is not here has no peak:
 # None, never a default. Only generations whose JAX device is a whole chip
-# are listed (a v2/v3 device is one of a chip's two cores). The single
-# source — bench.py reads this table for its MFU denominators.
+# are listed (a v2/v3 device is one of a chip's two cores).
 TPU_PEAK_BF16 = {
     "TPU v5 lite": 197e12,  # v5e
     "TPU v5": 459e12,  # v5p
@@ -906,35 +902,13 @@ def sample_device_telemetry() -> Dict[str, Any]:
     return out
 
 
-def program_flops(
-    jit_fn: Any,
-    *args: Any,
-    on_error: Optional[Callable[[str], None]] = None,
-) -> Optional[float]:
-    """FLOPs of one compiled step from XLA cost analysis of the lowered
-    program (a trace, not a compile). None when the backend can't say —
-    callers (bench.py's ``_program_flops``) choose their own
-    fallback/labeling; ``on_error`` receives the failure
-    reason so a missing-MFU record stays debuggable."""
-    try:
-        cost = jit_fn.lower(*args).cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0]
-        flops = float(cost.get("flops", 0.0))
-        return flops if flops > 0 else None
-    except Exception as e:
-        if on_error is not None:
-            on_error(f"{type(e).__name__}: {e}")
-        return None
-
-
 def device_peak_flops() -> Tuple[Optional[float], str]:
     """(datasheet peak FLOP/s per chip, provenance) — None off-TPU.
 
-    Deliberately datasheet-only: the training loop must never run
-    bench.py's matmul microbench mid-run (it would steal the very step
-    time being measured). Without a datasheet number the peak stays
-    None — an honest absence, not a made-up denominator.
+    Deliberately datasheet-only: the training loop must never run a
+    matmul microbench mid-run (it would steal the very step time being
+    measured). Without a datasheet number the peak stays None — an
+    honest absence, not a made-up denominator.
     """
     try:
         import jax
@@ -948,65 +922,6 @@ def device_peak_flops() -> Tuple[Optional[float], str]:
         return None, f"unknown TPU kind {dev.device_kind!r}"
     except Exception as e:
         return None, f"device query failed: {type(e).__name__}"
-
-
-# ----------------------------------------------------------------------
-# Update-phase attribution (grad-reduce / apply / allgather)
-# ----------------------------------------------------------------------
-
-# the three phases the weight-update step decomposes into under
-# cross-replica update sharding (arXiv 2004.13336): sum the per-replica
-# partial gradients, apply the optimizer to the owned shard, gather the
-# updated params back to the replicated layout
-UPDATE_PHASES = ("grad_reduce", "apply", "allgather")
-
-
-def update_phase_block(
-    grad_reduce_s: Optional[float],
-    apply_s: Optional[float],
-    allgather_s: Optional[float],
-    *,
-    trace: Optional["TraceBuffer"] = None,
-    t0: Optional[float] = None,
-) -> Dict[str, Any]:
-    """The canonical update-phase attribution block bench records carry.
-
-    HONESTY CONTRACT: inside the fused one-program train step the three
-    phases are not separately host-observable (XLA overlaps them); these
-    numbers come from separately-jitted phase programs (bench.py
-    ``--update-only --sharded``), so they are an attribution of where a
-    mode's time CAN go, measured in isolation — the one-program
-    ``update_seconds`` on the same record is the end-to-end truth. A
-    ``None`` phase means the mode has no such phase (e.g. no allgather
-    under replicated) and stays None rather than a fake zero.
-
-    When ``trace``/``t0`` are given, each phase is also emitted as a
-    back-to-back Chrome-trace span so a Perfetto view can show the split.
-    """
-    secs = {
-        "grad_reduce": grad_reduce_s,
-        "apply": apply_s,
-        "allgather": allgather_s,
-    }
-    block: Dict[str, Any] = {
-        f"{name}_s": (round(float(v), 6) if v is not None else None)
-        for name, v in secs.items()
-    }
-    total = sum(float(v) for v in secs.values() if v is not None)
-    block["total_s"] = round(total, 6)
-    if total > 0:
-        block["apply_share"] = round(float(secs["apply"] or 0.0) / total, 4)
-    if trace is not None and t0 is not None:
-        at = t0
-        for name in UPDATE_PHASES:
-            v = secs[name]
-            if v is None:
-                continue
-            trace.add_span(
-                f"update_{name}", at, float(v), cat="update", force=True
-            )
-            at += float(v)
-    return block
 
 
 # ----------------------------------------------------------------------
@@ -1394,8 +1309,8 @@ class Telemetry:
         self.trace_steps = (int(trace_steps[0]), int(trace_steps[1]))
         self.registry = MetricsRegistry(clock=clock)
         self.trace = TraceBuffer(clock=clock, pid=int(process_index))
-        # host-resource truth (docs/OBSERVABILITY.md "Host resources &
-        # the run ledger"): lives INSIDE the facade so the disabled
+        # host-resource truth (docs/OBSERVABILITY.md "Host
+        # resources"): lives INSIDE the facade so the disabled
         # path constructs no sampler and reads no /proc (zero-telemetry
         # contract). Internally rate-limited — the alert ticker and
         # every /metrics scrape share one cached sample, no new thread.
@@ -1746,7 +1661,7 @@ class Telemetry:
         if input_pipeline is not None:
             row["input_pipeline"] = input_pipeline
         # host truth in the run record: the report's host-resource
-        # section and the run ledger's run-dir ingest both read this
+        # section reads this
         row["process"] = self.hoststats.sample()
         self._append_row(row)
         self._flush_rows()
@@ -1953,7 +1868,7 @@ def _summarize_run_dir(run_dir: Path) -> str:
     metrics.jsonl``) gets a fleet digest; a plain run directory holding
     one ``metrics.jsonl`` falls through to the file summary. Discovery
     is :func:`~.report.load_run` — the ONE definition of the run-dir
-    layout, shared with ``telemetry report`` and the bench harness."""
+    layout, shared with ``telemetry report``."""
     from .report import load_run
 
     run_dir = Path(run_dir)

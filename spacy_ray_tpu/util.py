@@ -1,62 +1,16 @@
-"""Utilities: timers + synthetic data generation.
+"""Synthetic data generation.
 
-Timer/ManyTimer mirror the reference's instrumentation scaffolding
-(reference util.py:9-38) but are actually wired: the loop and bench use them.
-The synthetic corpus generator backs tests and bench.py (the reference pulls
-a fashion-brands NER corpus in bin/get-data.sh; tests here must run
-hermetically with zero egress).
+The synthetic corpus generator backs tests and chip_smoke.py (the
+reference pulls a fashion-brands NER corpus in bin/get-data.sh; tests
+here must run hermetically with zero egress).
 """
 
 from __future__ import annotations
 
 import random
-import time
-from contextlib import contextmanager
-from typing import Dict, Iterator, List, Optional
+from typing import List
 
 from .pipeline.doc import Doc, Example, Span
-
-
-class Timer:
-    """Accumulating context-manager timer (reference util.py:9-29)."""
-
-    def __init__(self, name: str = ""):
-        self.name = name
-        self.total = 0.0
-        self.n = 0
-        self._start: Optional[float] = None
-
-    def __enter__(self) -> "Timer":
-        self._start = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        assert self._start is not None
-        self.total += time.perf_counter() - self._start
-        self.n += 1
-        self._start = None
-
-    @property
-    def mean(self) -> float:
-        return self.total / self.n if self.n else 0.0
-
-
-class ManyTimer:
-    """Keyed timer registry (reference util.py:32-38)."""
-
-    def __init__(self):
-        self.timers: Dict[str, Timer] = {}
-
-    def __call__(self, name: str) -> Timer:
-        if name not in self.timers:
-            self.timers[name] = Timer(name)
-        return self.timers[name]
-
-    def report(self) -> str:
-        return "; ".join(
-            f"{t.name}: total={t.total:.3f}s mean={t.mean*1000:.1f}ms n={t.n}"
-            for t in self.timers.values()
-        )
 
 
 # ----------------------------------------------------------------------

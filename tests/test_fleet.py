@@ -4,7 +4,7 @@ the hot path), response-cache behaviour, fleet /metrics aggregation,
 supervisor crash-restart/scale with stub scripts, autoscaler hysteresis
 under a fake clock, the disabled-telemetry zero-calls contract, and the
 whole-fleet SIGTERM drain through the real ``serve-fleet`` CLI in a
-subprocess (heavy crash-under-load and bench variants are slow-marked).
+subprocess (heavy crash-under-load variants are slow-marked).
 """
 
 import json
@@ -17,11 +17,8 @@ import sys
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
-from pathlib import Path
 
 import pytest
-
-sys.path.insert(0, str(Path(__file__).parent.parent))  # for `import bench`
 
 from spacy_ray_tpu.serving.fleet import (
     AutoscalerPolicy,
@@ -1703,29 +1700,3 @@ def test_fleet_sigkill_replica_writes_incident_postmortem(
             except subprocess.TimeoutExpired:
                 proc.kill()
                 proc.wait(timeout=10.0)
-
-
-@pytest.mark.slow
-def test_bench_fleet_appends_session_records(tmp_path, monkeypatch):
-    """bench.py --serving --replicas drives the real fleet topology and
-    appends closed/open records tagged with the replica count."""
-    import bench
-
-    session = tmp_path / "session.jsonl"
-    monkeypatch.setattr(bench, "SESSION_FILE", session)
-    records = bench.run_serving_fleet(
-        "cpu", replica_counts=[1], duration_s=0.6, clients=4,
-        max_batch=4, max_wait_ms=3.0,
-    )
-    assert [r["name"] for r in records] == [
-        "serving_fleet_closed", "serving_fleet_open"
-    ]
-    for rec in records:
-        assert rec["replicas"] == 1
-        assert rec["value"] > 0 and rec["unit"] == "req/s"
-        assert rec["failed"] == 0
-        assert rec["latency_ms_p50"] is not None
-    on_disk = [json.loads(l) for l in session.read_text().splitlines()]
-    assert [r["name"] for r in on_disk] == [
-        "serving_fleet_closed", "serving_fleet_open"
-    ]

@@ -145,7 +145,7 @@ def test_fused_status_labels():
     assert "pallas" not in fu.fused_status(fused) or fu.GATE.armed is True
     # multi-device mesh: the kernel gate (single_device) keeps pallas off,
     # so the label must downgrade even when the probe passed — a multi-chip
-    # bench record must never claim "active (pallas)" (honest labeling)
+    # run must never claim "active (pallas)" (honest labeling)
     import jax
 
     from spacy_ray_tpu.parallel.mesh import build_mesh
@@ -417,29 +417,3 @@ tolerance = 0.2
         assert step_rows == list(range(1, res.final_step + 1))
         hist[K] = [(h["step"], h["score"], h["losses"]) for h in res.history]
     assert hist[1] == hist[3]
-
-
-@pytest.mark.slow
-def test_update_only_bench_records(tmp_path, monkeypatch):
-    """bench.py --update-only appends naive + fused records with the
-    honest fused_update label and a reprobe stamp."""
-    import bench
-
-    from spacy_ray_tpu.presets import CNN_TAGGER_CFG
-
-    monkeypatch.setattr(bench, "SESSION_FILE", tmp_path / "session.jsonl")
-    monkeypatch.setattr(bench, "MIN_REP_SECONDS", 0.05)
-    monkeypatch.setattr(bench, "N_REPS", 1)
-    tiny = [("tiny", CNN_TAGGER_CFG.format(width=32, depth=1, embed_size=200),
-             ["tagger"])]
-    bench.run_update_only("cpu", configs=tiny)
-    recs = [json.loads(line)
-            for line in (tmp_path / "session.jsonl").read_text().splitlines()]
-    names = {r["name"] for r in recs}
-    assert names == {"update_only_tiny", "update_only_tiny_fused"}
-    for r in recs:
-        assert r["unit"] == "seconds/update" and r["value"] > 0
-        assert r["peak_reprobe_ratio"] is not None
-        assert r["fused_update"].startswith(
-            "active" if r["name"].endswith("_fused") else "off"
-        )

@@ -10,15 +10,12 @@ single-model /v1/parse contract must stay bit-identical."""
 
 import json
 import http.client
-import sys
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from pathlib import Path
 
 import pytest
-
-sys.path.insert(0, str(Path(__file__).parent.parent))  # for `import bench`
 
 from spacy_ray_tpu.config import Config
 from spacy_ray_tpu.pipeline.language import Pipeline

@@ -105,7 +105,6 @@ def _run_steps(nlp, n_data, n_steps=2, zero1=False, B=16):
     return jax.device_get(params), losses
 
 
-@pytest.mark.slow
 def test_dp8_matches_single_device(small_nlp):
     """Gradient all-reduce over 8 devices == single-device step (the
     correctness property the reference's async quorum only approximates)."""
@@ -116,7 +115,6 @@ def test_dp8_matches_single_device(small_nlp):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-2, atol=1e-5)
 
 
-@pytest.mark.slow
 def test_zero1_matches_replicated(small_nlp):
     """ZeRO-1 sharded optimizer state must be a pure layout change."""
     p_repl, l_repl = _run_steps(small_nlp, n_data=8, zero1=False)
@@ -152,7 +150,6 @@ def test_zero1_opt_state_is_sharded(small_nlp, mesh8):
     assert len(sharded) > 0
 
 
-@pytest.mark.slow
 def test_grad_accumulation_equivalence(small_nlp):
     """accum=2 over two equal microbatches == one step over their union."""
     examples = _fixed_len_examples(32, seed=3)
@@ -198,7 +195,6 @@ def test_grad_accumulation_equivalence(small_nlp):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4, atol=1e-6)
 
 
-@pytest.mark.slow
 def test_train_loop_non_power_of_two_workers(tagger_config_text, tmp_path):
     """B padding must round to a multiple of the data-axis size (n=3)."""
     from spacy_ray_tpu.training.loop import train
@@ -218,7 +214,6 @@ def test_train_loop_non_power_of_two_workers(tagger_config_text, tmp_path):
     assert result.final_step == 4
 
 
-@pytest.mark.slow
 def test_train_loop_8_workers_learns(tagger_config_text, tmp_path):
     from spacy_ray_tpu.training.loop import train
     from spacy_ray_tpu.util import write_synth_jsonl

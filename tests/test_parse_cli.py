@@ -11,8 +11,6 @@ from spacy_ray_tpu.cli import main as cli_main
 from spacy_ray_tpu.config import Config
 from spacy_ray_tpu.util import write_synth_jsonl
 
-pytestmark = pytest.mark.slow  # trains a model first
-
 
 @pytest.fixture(scope="module")
 def trained_model(tagger_config_text, tmp_path_factory):

@@ -2,8 +2,8 @@
 admission/coalescing/deadlines, engine warmup + dispatch correctness
 under concurrent load (responses == single-request predict_docs, and
 occupancy > 1 proves coalescing), HTTP API surface, SIGTERM graceful
-drain in a real subprocess, the telemetry-disabled zero-calls contract,
-and the bench.py --serving load spec's session records."""
+drain in a real subprocess, and the telemetry-disabled zero-calls
+contract."""
 
 import json
 import http.client
@@ -13,11 +13,8 @@ import subprocess
 import sys
 import threading
 import time
-from pathlib import Path
 
 import pytest
-
-sys.path.insert(0, str(Path(__file__).parent.parent))  # for `import bench`
 
 from spacy_ray_tpu.config import Config
 from spacy_ray_tpu.pipeline.language import Pipeline
@@ -844,166 +841,3 @@ def test_sigterm_graceful_drain_subprocess(model_dir):
         if proc.poll() is None:
             proc.kill()
             proc.wait(timeout=10.0)
-
-
-# ----------------------------------------------------------------------
-# bench.py --serving session records
-# ----------------------------------------------------------------------
-
-
-def test_bench_serving_appends_session_records(tmp_path, monkeypatch):
-    """Acceptance: --serving appends closed- and open-loop records with
-    req/s, occupancy, and p50/p95/p99 latency to BENCH_SESSION.jsonl."""
-    import bench
-
-    session = tmp_path / "session.jsonl"
-    monkeypatch.setattr(bench, "SESSION_FILE", session)
-    records = bench.run_serving(
-        "cpu", duration_s=0.6, clients=4, max_batch=4, max_wait_ms=3.0
-    )
-    assert [r["name"] for r in records] == ["serving_closed", "serving_open"]
-    on_disk = [json.loads(l) for l in session.read_text().splitlines()]
-    assert [r["name"] for r in on_disk] == ["serving_closed", "serving_open"]
-    for rec in on_disk:
-        assert rec["value"] > 0 and rec["unit"] == "req/s"
-        assert rec["requests_ok"] > 0
-        assert rec["latency_ms_p50"] is not None
-        assert rec["latency_ms_p95"] is not None
-        assert rec["latency_ms_p99"] is not None
-        assert rec["batches"] and rec["occupancy_mean"] is not None
-    closed, open_ = on_disk
-    assert closed["clients"] == 4
-    assert open_["offered_rps"] > 0
-
-
-def test_committed_session_value_selects_matching_record(tmp_path, monkeypatch):
-    """The open-loop offered rate derives from the matching committed
-    record for the spec being run (latest wins, skips and mismatched
-    shapes filtered) — never from a cross-methodology record. This is
-    the PERF.md cross-round caveat closed in code."""
-    import bench
-
-    session = tmp_path / "session.jsonl"
-    rows = [
-        {"name": "serving_open", "offered_rps": 40.0,
-         "max_batch_docs": 16, "texts_per_request": 2},
-        {"name": "serving_open", "offered_rps": 99.0,
-         "max_batch_docs": 8, "texts_per_request": 2},   # wrong shape
-        {"name": "serving_open", "skipped": True, "offered_rps": 77.0,
-         "max_batch_docs": 16, "texts_per_request": 2},  # skip record
-        {"name": "serving_open", "offered_rps": 47.3,
-         "max_batch_docs": 16, "texts_per_request": 2},  # newest match
-        {"name": "serving_fleet_open", "offered_rps": 18.1, "replicas": 1,
-         "max_batch_docs": 16, "texts_per_request": 2},
-    ]
-    session.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-    monkeypatch.setattr(bench, "SESSION_FILE", session)
-    assert bench._committed_session_value(
-        "serving_open", max_batch_docs=16, texts_per_request=2
-    ) == (47.3, "committed:serving_open.offered_rps")
-    # the fleet spec at n=1 matches ITS pinned record, not the
-    # single-engine one
-    assert bench._committed_session_value(
-        "serving_fleet_open", replicas=1, max_batch_docs=16,
-        texts_per_request=2,
-    ) == (18.1, "committed:serving_fleet_open.offered_rps")
-    assert bench._committed_session_value(
-        "serving_fleet_open", replicas=4, max_batch_docs=16,
-        texts_per_request=2,
-    ) is None
-    monkeypatch.setattr(bench, "SESSION_FILE", tmp_path / "missing.jsonl")
-    assert bench._committed_session_value("serving_open") is None
-
-
-def test_bench_serving_ab_smoke(tmp_path, monkeypatch):
-    """--serving-ab smoke: both admission arms run open-loop AT THE SAME
-    committed offered rates (baseline + saturation), records carry the
-    honest batching/precision labels and the rate's provenance."""
-    import bench
-
-    session = tmp_path / "session.jsonl"
-    seed_rows = [
-        {"name": "serving_open", "platform": "cpu", "offered_rps": 10.0,
-         "max_batch_docs": 4, "texts_per_request": 2},
-        {"name": "serving_closed", "platform": "cpu", "value": 18.0,
-         "max_batch_docs": 4, "texts_per_request": 2},
-        # a closed-loop record from ANOTHER backend must never set this
-        # platform's operating point
-        {"name": "serving_closed", "platform": "tpu", "value": 500.0,
-         "max_batch_docs": 4, "texts_per_request": 2},
-    ]
-    session.write_text("\n".join(json.dumps(r) for r in seed_rows) + "\n")
-    monkeypatch.setattr(bench, "SESSION_FILE", session)
-    records = bench.run_serving_ab(
-        "cpu", duration_s=0.5, max_batch=4, max_doc_len=32,
-        skip_precision=True,
-    )
-    assert [(r["batching"], r["rate_point"]) for r in records] == [
-        ("window", "baseline"), ("window", "saturation"),
-        ("continuous", "baseline"), ("continuous", "saturation"),
-    ]
-    for rec in records:
-        assert rec["name"] == "serving_ab_open"
-        assert rec["precision"] == "f32"  # CPU: auto resolves OFF
-        assert rec["requests_ok"] > 0
-        assert rec["latency_ms_p99"] is not None
-        assert rec["dispatch_wait_ms_p99"] is not None
-    # both arms measured at the SAME fixed points, from committed records
-    baselines = [r for r in records if r["rate_point"] == "baseline"]
-    assert {r["offered_rps"] for r in baselines} == {10.0}
-    assert {r["offered_rate_source"] for r in baselines} == {
-        "committed:serving_open.offered_rps"
-    }
-    sats = [r for r in records if r["rate_point"] == "saturation"]
-    assert {r["offered_rps"] for r in sats} == {18.0}
-    # saturation pinning: once the A/B's own saturation record exists, a
-    # newer closed-loop record (e.g. measured under continuous admission,
-    # which saturates far higher) can no longer move the operating point
-    with open(session, "a") as f:
-        f.write(json.dumps({
-            "name": "serving_closed", "platform": "cpu", "value": 99.0,
-            "max_batch_docs": 4, "texts_per_request": 2,
-        }) + "\n")
-    assert bench._committed_session_value(
-        "serving_ab_open", rate_point="saturation", platform="cpu",
-        max_batch_docs=4, texts_per_request=2,
-    ) == (18.0, "committed:serving_ab_open.offered_rps")
-
-
-@pytest.mark.slow
-def test_bench_serving_ab_with_precision_arms(tmp_path, monkeypatch):
-    """Heavy variant: the full A/B including the trf precision arms —
-    on CPU the f32 arm is auto-resolved and the bf16 arm carries the
-    forced-overlay label (the honest-labeling acceptance)."""
-    import bench
-
-    session = tmp_path / "session.jsonl"
-    monkeypatch.setattr(bench, "SESSION_FILE", session)
-    records = bench.run_serving_ab(
-        "cpu", duration_s=1.0, max_batch=4, max_doc_len=32,
-    )
-    precision = [r for r in records if r["name"] == "serving_precision_open"]
-    assert [r["requested_precision"] for r in precision] == ["f32", "bf16"]
-    f32_rec, bf16_rec = precision
-    assert f32_rec["precision"] == "f32"
-    assert bf16_rec["precision"] == "bf16"
-    assert "forced" in bf16_rec["precision_label"]
-    assert f32_rec["offered_rps"] == bf16_rec["offered_rps"]  # fixed rate
-    for rec in precision:
-        assert rec["requests_ok"] > 0
-
-
-@pytest.mark.slow
-def test_bench_serving_sustained_load(tmp_path, monkeypatch):
-    """Heavy open/closed-loop variant at the real default shape (16-doc
-    batches, 8 clients, 3s per loop) — the tier-2 version of the smoke
-    above; occupancy must exceed 1 under saturation or dynamic batching
-    is not actually batching."""
-    import bench
-
-    session = tmp_path / "session.jsonl"
-    monkeypatch.setattr(bench, "SESSION_FILE", session)
-    records = bench.run_serving("cpu", duration_s=3.0, clients=8)
-    closed = records[0]
-    assert closed["requests_ok"] >= 8
-    assert closed["occupancy_max"] > 1, closed

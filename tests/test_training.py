@@ -30,7 +30,6 @@ def _config(tagger_config_text, data_dir, **over):
     return cfg
 
 
-@pytest.mark.slow
 def test_train_tagger_learns(tagger_config_text, data_dir, tmp_path):
     cfg = _config(tagger_config_text, data_dir)
     nlp, result = train(cfg, output_path=tmp_path / "out", n_workers=1, stdout_log=False)
@@ -41,7 +40,6 @@ def test_train_tagger_learns(tagger_config_text, data_dir, tmp_path):
     assert (tmp_path / "out" / "last-model" / "train_meta.json").exists()
 
 
-@pytest.mark.slow
 def test_model_roundtrip_and_predict(tagger_config_text, data_dir, tmp_path):
     cfg = _config(tagger_config_text, data_dir, **{"training.max_steps": 20})
     nlp, _ = train(cfg, output_path=tmp_path / "out", n_workers=1, stdout_log=False)
@@ -54,7 +52,6 @@ def test_model_roundtrip_and_predict(tagger_config_text, data_dir, tmp_path):
     assert doc.tags is not None and len(doc.tags) == 4
 
 
-@pytest.mark.slow
 def test_resume_continues_from_checkpoint(tagger_config_text, data_dir, tmp_path):
     cfg = _config(tagger_config_text, data_dir, **{"training.max_steps": 20})
     _, r1 = train(cfg, output_path=tmp_path / "out", n_workers=1, stdout_log=False)
@@ -81,7 +78,6 @@ def test_weighted_score():
     assert weighted_score({"a": 0.5, "b": 0.9}, {"a": 1.0, "b": None}) == pytest.approx(0.5)
 
 
-@pytest.mark.slow
 def test_frozen_component_not_updated(tagger_config_text, data_dir):
     cfg = _config(
         tagger_config_text,
@@ -109,7 +105,6 @@ def test_frozen_component_not_updated(tagger_config_text, data_dir):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-7)
 
 
-@pytest.mark.slow
 def test_resume_is_exact(tagger_config_text, data_dir, tmp_path):
     """Resume must continue the EXACT run: same shuffle order, same data
     position within the epoch, same rng chain — so straight-through and
@@ -148,7 +143,6 @@ def test_resume_is_exact(tagger_config_text, data_dir, tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.slow
 def test_sharded_eval_matches_replicated(tagger_config_text, data_dir):
     """Eval with dev batches sharded over the data axis must score
     identically to plain single-device eval (VERDICT r1 weak #10)."""

@@ -23,10 +23,9 @@ import numpy as np
 import pytest
 
 from spacy_ray_tpu.config import Config
-from spacy_ray_tpu.parallel.mesh import build_mesh, owner_shard_spec
+from spacy_ray_tpu.parallel.mesh import build_mesh
 from spacy_ray_tpu.parallel.step import (
     make_train_step,
-    make_update_only,
     place_batch,
     place_replicated,
     resolve_update_sharding,
@@ -215,43 +214,6 @@ def test_zero1_program_is_unpinned_but_close(cnn_setup):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-2, atol=1e-5
         )
-
-
-def test_update_only_full_matches_replicated(mesh8):
-    """make_update_only (the bench's microbench program) shares the train
-    step's mode semantics: full == replicated to equality on synthetic
-    grads, and gather=False really leaves params in owner shards."""
-    key = jax.random.PRNGKey(3)
-    params = {
-        "w": jax.random.normal(key, (256, 32), jnp.float32),
-        "b": jax.random.normal(key, (7,), jnp.float32),
-    }
-    grads = jax.tree_util.tree_map(lambda p: p * 1e-3 + 1e-4, params)
-    out = {}
-    for mode in ("replicated", "full"):
-        tx = fuse_optimizer(
-            registry.get("optimizers", "Adam.v1")(learn_rate=0.01)
-        )
-        p = place_replicated(params, mesh8)
-        s = shard_opt_state(tx.init(p), mesh8, mode)
-        g = place_replicated(grads, mesh8)
-        step = make_update_only(tx, mesh8, mode, s, donate=False)
-        out[mode] = jax.device_get(step(p, s, g))
-    _assert_tree_equal(out["full"], out["replicated"], "update-only")
-    # gather=False: the apply-phase program returns owner-sharded params
-    tx = fuse_optimizer(registry.get("optimizers", "Adam.v1")(learn_rate=0.01))
-    p = place_replicated(params, mesh8)
-    s = shard_opt_state(tx.init(p), mesh8, "full")
-    g = place_replicated(grads, mesh8)
-    step_ng = make_update_only(tx, mesh8, "full", s, donate=False, gather=False)
-    p2, _s2 = step_ng(p, s, g)
-    # owner-sharded output: first axis carries "data", as owner_shard_spec says
-    assert tuple(p2["w"].sharding.spec)[:1] == tuple(
-        owner_shard_spec(p2["w"], mesh8).spec
-    )[:1] == ("data",)
-    _assert_tree_equal(
-        jax.device_get(p2), out["replicated"][0], "apply-phase values"
-    )
 
 
 def test_full_update_donates_state(cnn_setup):
@@ -557,52 +519,3 @@ def test_train_loop_elastic_resume_across_worker_counts(
     meta2 = json.loads((out / "last-model" / "train_meta.json").read_text())
     assert meta2["extra"]["mesh"]["n_data"] == 2
     assert meta2["opt_shards"] == 2
-
-
-# ------------------------------------------------------ telemetry + bench
-
-
-def test_update_phase_block_schema():
-    from spacy_ray_tpu.training.telemetry import (
-        TraceBuffer,
-        update_phase_block,
-    )
-
-    block = update_phase_block(0.004, 0.008, None)
-    assert block["grad_reduce_s"] == 0.004
-    assert block["apply_s"] == 0.008
-    assert block["allgather_s"] is None  # honest absence, not a fake zero
-    assert block["total_s"] == pytest.approx(0.012)
-    assert block["apply_share"] == pytest.approx(0.6667, abs=1e-3)
-    # span emission: back-to-back phase spans on the trace
-    trace = TraceBuffer(clock=lambda: 0.0)
-    trace.set_recording(True)
-    update_phase_block(0.004, 0.008, 0.002, trace=trace, t0=1.0)
-    assert len(trace) == 3
-
-
-@pytest.mark.slow
-def test_bench_sharded_records(tmp_path, monkeypatch):
-    """--update-only --sharded child-mode records: schema + honest labels
-    on a tiny config (the committed A/B runs the real trees)."""
-    import bench
-
-    monkeypatch.setattr(bench, "SESSION_FILE", tmp_path / "session.jsonl")
-    monkeypatch.setattr(bench, "MIN_REP_SECONDS", 0.05)
-    tiny = [("tiny", CNN_CFG, ["tagger"])]
-    bench.run_update_sharded("cpu", len(jax.devices()), configs=tiny)
-    recs = [
-        json.loads(line)
-        for line in (tmp_path / "session.jsonl").read_text().splitlines()
-    ]
-    assert {r["name"] for r in recs} == {
-        f"update_sharded_tiny_n8_{m}"
-        for m in ("replicated", "zero1", "full")
-    }
-    by_mode = {r["name"].rsplit("_", 1)[-1]: r for r in recs}
-    full = by_mode["full"]
-    assert full["update_sharding"].startswith("full (")
-    assert full["update_phases"]["allgather_s"] is not None
-    assert by_mode["replicated"]["update_phases"]["allgather_s"] is None
-    assert all(r["update_phases"]["grad_reduce_s"] is not None for r in recs)
-    assert all(r["fused_update"].startswith("active (") for r in recs)
