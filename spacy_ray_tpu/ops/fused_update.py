@@ -13,7 +13,14 @@ lr) into the step; this module provides the same math as ONE update:
   delegates to it), so checkpoints, ZeRO-1 shardings, and the
   ``fused_update`` knob can be flipped without invalidating resume state.
 * a pallas TPU kernel for the per-leaf elementwise update (params, grads,
-  mu, nu in; params', mu', nu' out, HBM-aliased via input_output_aliases)
+  mu, nu in; params', mu', nu' and, where the leaf has one, its bf16
+  shadow out). It takes each leaf WHERE IT LIES: the leaf is viewed as
+  [product of the leading dimensions, last dimension], which keeps the
+  device's (8, 128) tiles (a bitcast, never a copy), the grid walks row
+  blocks of the whole width, and input_output_aliases updates the donated
+  params and moments themselves. A leaf that cannot be walked without a
+  copy goes through the same math under XLA (``leaf_blocking`` says
+  which, and why; ``FusedTransformation.in_place`` keeps the tally)
   — probe-gated exactly like the flash-attention kernel: compiled and
   numerically validated against the XLA math at startup, forced with
   SRT_PALLAS_FUSED=1/0, auto-enabled on TPU only. CPU tests run it in
@@ -31,6 +38,7 @@ reference chain to 1 ulp — asserted by tests/test_fused_update.py.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -42,10 +50,12 @@ from jax.experimental.pallas import tpu as pltpu
 from ..names import KERNEL_FUSED_ADAM
 from . import probe as _probe
 
-# kernel block: BR rows x 128 lanes of f32 per grid step (1 MB/operand —
-# well under VMEM with 5 inputs + 3 outputs resident)
-LANES = 128
-BLOCK_ROWS = 2048
+# kernel block: whole rows of a leaf, about this many bytes of f32 per
+# operand and grid step (4 inputs + 4 outputs, double-buffered, stay under
+# the 16 MiB of VMEM a kernel may use)
+BLOCK_BYTES = 512 * 1024
+# block rows come in multiples of a bf16 tile's 16 (f32's 8 divides it)
+ROW_ALIGN = 16
 # leaves smaller than this skip the pallas path: a kernel launch per tiny
 # bias buys nothing (the XLA fallback fuses those fine)
 MIN_KERNEL_SIZE = 16 * 1024
@@ -129,11 +139,13 @@ def _leaf_math(
 
 
 def _update_kernel(scal_ref, p_ref, g_ref, m_ref, v_ref, op_ref, om_ref,
-                   ov_ref, *, hyper: FusedHyper):
+                   ov_ref, *shadow_ref, hyper: FusedHyper):
     # scal [6] SMEM: gnorm, bc1, bc2, step_size, ro, rect
     p2, m2, v2 = _leaf_math(
         p_ref[...],
-        g_ref[...],
+        # a shadowed leaf's cotangent arrives in the shadow's dtype: widened
+        # here, in VMEM (exact), instead of as a float32 copy in HBM
+        g_ref[...].astype(p_ref.dtype),
         m_ref[...],
         v_ref[...],
         scal_ref[0],
@@ -148,49 +160,75 @@ def _update_kernel(scal_ref, p_ref, g_ref, m_ref, v_ref, op_ref, om_ref,
     op_ref[...] = p2
     om_ref[...] = m2
     ov_ref[...] = v2
+    if shadow_ref:
+        # the leaf's bf16 shadow, refreshed from the block already in VMEM
+        shadow_ref[0][...] = p2.astype(shadow_ref[0].dtype)
 
 
 _INTERPRET = False  # tests flip this to run the kernel on CPU
 
 
-def _kernel_leaf(p, g, m, v, scal, hyper: FusedHyper, interpret=None):
-    """Run one leaf through the pallas kernel: ravel, zero-pad to a whole
-    number of (BLOCK_ROWS, 128) blocks, grid over row blocks, un-pad."""
+def leaf_blocking(
+    shape: Tuple[int, ...], dtype: Any, block_bytes: int = BLOCK_BYTES
+) -> Tuple[Optional[Tuple[int, int, int]], str]:
+    """How the kernel walks a leaf without copying it, decided from what the
+    leaf shows: ``((rows, width, block_rows), "")``, or ``(None, why)`` for
+    a leaf it cannot walk so, whose update goes through XLA (which fuses it
+    in place; so does a leaf under ``MIN_KERNEL_SIZE``, the caller's rule).
+
+    The view ``[rows, width]`` merges the leading dimensions; the device
+    keeps an array in (8, 128) tiles of its last two ((16, 128) for a bf16
+    gradient or shadow), so the merge is a bitcast only while the
+    second-to-last is a whole number of ``ROW_ALIGN``-row tiles.
+    A block is ``block_rows`` whole rows (a width that is no multiple of 128
+    lowers as a full-width block) of about ``block_bytes`` of float32; the
+    grid masks a last block that is not full."""
+    if dtype != jnp.float32:
+        return None, f"dtype {jnp.dtype(dtype).name}"
+    if len(shape) < 2:
+        return None, "one dimension"
+    if len(shape) > 2 and shape[-2] % ROW_ALIGN:
+        return None, f"rows {shape[-2]} (merging the leading dimensions would copy)"
+    width = int(shape[-1])
+    rows = math.prod(int(d) for d in shape[:-1])
+    row_bytes = 4 * (-(-width // 128) * 128)  # a row in VMEM, lanes padded
+    if ROW_ALIGN * row_bytes > 2 * block_bytes:
+        return None, f"width {width}"
+    block_rows = max(block_bytes // row_bytes // ROW_ALIGN, 1) * ROW_ALIGN
+    return (rows, width, min(block_rows, rows)), ""
+
+
+def _kernel_leaf(p, g, m, v, scal, hyper: FusedHyper, shadow_dtype=None,
+                 interpret=None, block_bytes: int = BLOCK_BYTES):
+    """Run one leaf through the pallas kernel where it lies (the caller has
+    asked ``leaf_blocking``): ``(p', m', v')`` and, given ``shadow_dtype``,
+    the cast of ``p'`` to it as a fourth output."""
     interpret = _INTERPRET if interpret is None else interpret
-    n = p.size
-    shape = p.shape
-    tile = BLOCK_ROWS * LANES
-    padded = ((n + tile - 1) // tile) * tile
-    rows = padded // LANES
-
-    def prep(x):
-        x = jnp.ravel(x)
-        if padded != n:
-            x = jnp.pad(x, (0, padded - n))
-        return x.reshape(rows, LANES)
-
+    blocking, why = leaf_blocking(p.shape, p.dtype, block_bytes)
+    if blocking is None:
+        raise ValueError(f"leaf {p.shape} cannot take the kernel in place: {why}")
+    rows, width, block_rows = blocking
     kernel = functools.partial(_update_kernel, hyper=hyper)
-    bspec = pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0),
+    bspec = pl.BlockSpec((block_rows, width), lambda i: (i, 0),
                          memory_space=pltpu.VMEM)
     sspec = pl.BlockSpec(memory_space=pltpu.SMEM)
-    out = jax.ShapeDtypeStruct((rows, LANES), p.dtype)
-    p2, m2, v2 = pl.pallas_call(
+    out = jax.ShapeDtypeStruct((rows, width), p.dtype)
+    outs = (out, out, out)
+    if shadow_dtype is not None:
+        outs += (jax.ShapeDtypeStruct((rows, width), shadow_dtype),)
+    res = pl.pallas_call(
         kernel,
-        out_shape=(out, out, out),
-        grid=(rows // BLOCK_ROWS,),
+        out_shape=outs,
+        grid=(pl.cdiv(rows, block_rows),),
         in_specs=[sspec, bspec, bspec, bspec, bspec],
-        out_specs=(bspec, bspec, bspec),
+        out_specs=(bspec,) * len(outs),
         # alias p/m/v buffers into the outputs: the update is in-place in
         # HBM, the same no-new-allocation contract the donated XLA path has
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
         name=KERNEL_FUSED_ADAM,
-    )(scal, prep(p), prep(g), prep(m), prep(v))
-
-    def unprep(x):
-        return jnp.ravel(x)[:n].reshape(shape)
-
-    return unprep(p2), unprep(m2), unprep(v2)
+    )(scal, *(x.reshape(rows, width) for x in (p, g, m, v)))
+    return tuple(x.reshape(p.shape) for x in res)
 
 
 # ------------------------------------------------------------------- probe
@@ -203,20 +241,35 @@ def _probe_kernel(interpret=None) -> Optional[str]:
         l2_grad=0.0, l2_decay=0.01,
     )
     r = jax.random.split(jax.random.PRNGKey(7), 4)
-    n = 4321  # deliberately not a tile multiple: exercises the padding
-    p = jax.random.normal(r[0], (n,), jnp.float32)
-    g = jax.random.normal(r[1], (n,), jnp.float32) * 0.1
-    m = jax.random.normal(r[2], (n,), jnp.float32) * 0.01
-    v = jnp.abs(jax.random.normal(r[3], (n,), jnp.float32)) * 0.01
+    # three dimensions (merged), a width that is no multiple of 128, and
+    # more rows than whole blocks hold (32 + 16: the last block is ragged);
+    # small blocks, so that the probe's arrays (30 KB each) are nothing
+    # beside a small model's own on the device
+    shape, block_bytes = (3, 16, 160), 32 * 1024
+    p = jax.random.normal(r[0], shape, jnp.float32)
+    g = jax.random.normal(r[1], shape, jnp.float32) * 0.1
+    m = jax.random.normal(r[2], shape, jnp.float32) * 0.01
+    v = jnp.abs(jax.random.normal(r[3], shape, jnp.float32)) * 0.01
     scal = jnp.asarray([2.3, 0.1, 0.001, -0.001, 6.0, 0.8], jnp.float32)
-    got = jax.jit(
-        lambda *a: _kernel_leaf(*a, hyper=hyper, interpret=interpret)
-    )(p, g, m, v, scal)
-    want = _leaf_math(p, g, m, v, *scal, hyper)
-    for name, a, b in zip(("params", "mu", "nu"), got, want):
-        bad = _probe.mismatch(name, a, b, atol=1e-6, rtol=1e-6)
-        if bad:
-            return bad
+    # both forms the step uses: a float32 gradient, and a shadowed leaf's
+    # (bf16 gradient in, bf16 shadow out beside the three)
+    for g_in, shadow_dtype in ((g, None), (g.astype(jnp.bfloat16), jnp.bfloat16)):
+        got = jax.jit(
+            lambda *a: _kernel_leaf(*a, hyper=hyper, shadow_dtype=shadow_dtype,
+                                    interpret=interpret, block_bytes=block_bytes)
+        )(p, g_in, m, v, scal)
+        want = _leaf_math(p, g_in.astype(p.dtype), m, v, *scal, hyper)
+        for name, a, b in zip(("params", "mu", "nu"), got, want):
+            bad = _probe.mismatch(name, a, b, atol=1e-6, rtol=1e-6)
+            if bad:
+                return bad
+        if shadow_dtype is not None:
+            # the shadow IS the cast of the params the kernel wrote
+            bad = _probe.mismatch(
+                "shadow", got[3], got[0].astype(shadow_dtype), atol=0.0
+            )
+            if bad:
+                return bad
     return None
 
 
@@ -326,11 +379,18 @@ class FusedTransformation:
         self.lr_fn = lr_fn
         self.adam_idx = adam_idx
         self.sched_idx = sched_idx
+        self.in_place: Optional[dict] = None
 
     def init(self, params):
         return self.reference_tx.init(params)
 
-    def update(self, grads, state, params=None):
+    def update(self, grads, state, params=None, shadow=None):
+        """``(new_params, new_state)``; given ``shadow`` (the bf16 copies of
+        some of the leaves, a sub-tree of ``params``: parallel/step.py),
+        ``(new_params, new_state, new_shadow)`` with each shadow leaf the
+        cast of its new params. A gradient leaf may arrive in a narrower
+        float dtype than its parameter (a shadowed leaf's cotangent): it is
+        widened where it is read, the norm over the same values."""
         if params is None:
             raise ValueError("fused update needs params (applies in place)")
         from optax._src import numerics
@@ -346,9 +406,14 @@ class FusedTransformation:
         # partitioner-proof norm: the clip scale must be the same VALUE in
         # every update-sharding mode and at every mesh shape, or the fused
         # update can never be bit-compared across them (see the function's
-        # docstring; single-device this IS optax.global_norm)
+        # docstring; single-device this IS optax.global_norm). The widening
+        # of a narrow leaf fuses into its reduction.
         gnorm = (
-            stable_global_norm(grads)
+            stable_global_norm(
+                jax.tree_util.tree_map(
+                    lambda g, p: g.astype(p.dtype), grads, params
+                )
+            )
             if hyper.grad_clip > 0
             else jnp.float32(0.0)
         )
@@ -385,36 +450,92 @@ class FusedTransformation:
                 ]
             )
 
-        def leaf(p, g, m, v):
-            if (
-                use_kernel
-                and p.dtype == jnp.float32
-                and p.size >= MIN_KERNEL_SIZE
-            ):
-                return _kernel_leaf(p, g, m, v, scal, hyper)
-            return _leaf_math(
-                p, g, m, v, gnorm, bc1, bc2, step_size, ro, rect, hyper
-            )
-
-        out = jax.tree_util.tree_map(leaf, params, grads, adam_state.mu,
-                                     adam_state.nu)
-        is_triple = lambda x: isinstance(x, tuple)  # noqa: E731
-        new_params = jax.tree_util.tree_map(
-            lambda t: t[0], out, is_leaf=is_triple
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(params)
+        shadow_leaves, shadow_def = (
+            jax.tree_util.tree_flatten_with_path(shadow)
+            if shadow is not None else ([], None)
         )
-        new_mu = jax.tree_util.tree_map(lambda t: t[1], out, is_leaf=is_triple)
-        new_nu = jax.tree_util.tree_map(lambda t: t[2], out, is_leaf=is_triple)
+        shadow_at = dict(shadow_leaves)
+        new_p, new_m, new_v, new_shadow = [], [], [], {}
+        in_kernel, fell = [], {}
+        for (path, p), g, m, v in zip(
+            leaves,
+            treedef.flatten_up_to(grads),
+            treedef.flatten_up_to(adam_state.mu),
+            treedef.flatten_up_to(adam_state.nu),
+        ):
+            old_shadow = shadow_at.get(path)
+            blocking, why = (
+                (None, "small") if p.size < MIN_KERNEL_SIZE
+                else leaf_blocking(p.shape, p.dtype)
+            )
+            if use_kernel and blocking is not None:
+                in_kernel.append(p.size)
+                out = _kernel_leaf(
+                    p, g, m, v, scal, hyper,
+                    shadow_dtype=None if old_shadow is None else old_shadow.dtype,
+                )
+            else:
+                name = jax.tree_util.keystr(path, simple=True, separator="/")
+                fell[name] = (p.size, why)
+                out = _leaf_math(
+                    p, g.astype(p.dtype), m, v, gnorm, bc1, bc2, step_size,
+                    ro, rect, hyper,
+                )
+                if old_shadow is not None:
+                    out += (out[0].astype(old_shadow.dtype),)
+            new_p.append(out[0])
+            new_m.append(out[1])
+            new_v.append(out[2])
+            if old_shadow is not None:
+                new_shadow[path] = out[3]
+        if use_kernel:
+            # what the traced update did, for the ``runtime`` report (the
+            # shapes decide it, so every trace of one model agrees)
+            self.in_place = _in_place_record(in_kernel, fell)
 
         from optax._src.transform import ScaleByAdamState, ScaleByScheduleState
 
         new_state = list(state)
         new_state[self.adam_idx] = ScaleByAdamState(
-            count=count_inc, mu=new_mu, nu=new_nu
+            count=count_inc,
+            mu=treedef.unflatten(new_m),
+            nu=treedef.unflatten(new_v),
         )
         new_state[self.sched_idx] = ScaleByScheduleState(
             count=numerics.safe_int32_increment(sched_state.count)
         )
-        return new_params, tuple(new_state)
+        if shadow is None:
+            return treedef.unflatten(new_p), tuple(new_state)
+        return (
+            treedef.unflatten(new_p),
+            tuple(new_state),
+            shadow_def.unflatten([new_shadow[path] for path, _ in shadow_leaves]),
+        )
+
+
+def _in_place_record(in_kernel, fell) -> dict:
+    """The tally ``FusedTransformation.in_place`` keeps: the share of the
+    parameters' elements whose leaf the kernel updated where it lay, how
+    many leaves that was, how many fell to XLA for being small, and the
+    others that fell, by name and reason (the first nine)."""
+    total = sum(in_kernel) + sum(n for n, _ in fell.values())
+    other = {k: why for k, (_, why) in fell.items() if why != "small"}
+    record = {
+        "share": sum(in_kernel) / max(total, 1),
+        "leaves": len(in_kernel),
+        "small": len(fell) - len(other),
+        "xla": dict(list(other.items())[:9]),
+    }
+    if len(other) > 9:
+        record["xla"]["..."] = f"{len(other) - 9} more"
+    return record
+
+
+def in_place_status(tx: Any) -> Optional[dict]:
+    """``FusedTransformation.in_place`` of a (wrapped) transformation: None
+    where no update was traced through the kernel (off a TPU, on a mesh)."""
+    return getattr(getattr(tx, "tx", tx), "in_place", None)
 
 
 def make_fused_transformation(

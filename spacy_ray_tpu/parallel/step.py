@@ -179,7 +179,10 @@ def overlay_shadow(params: Any, shadow: Any) -> Any:
 def refresh_shadow(new_params: Any, shadow: Any) -> Any:
     """Re-derive the shadow from freshly updated master params — ONE cast
     per shadowed leaf, fused into the same jitted update (the donated old
-    shadow buffer is reused; no second host-visible traversal)."""
+    shadow buffer is reused; no second host-visible traversal). The fused
+    update on one device writes the shadow itself, beside the params
+    (ops/fused_update.py): this is the optax chain's and the sharded
+    update's."""
     if not isinstance(shadow, dict):
         return new_params.astype(shadow.dtype)
     return {k: refresh_shadow(new_params[k], v) for k, v in shadow.items()}
@@ -271,14 +274,20 @@ def make_train_step(
         return loss, metrics, grads
 
     applies_updates = bool(getattr(tx, "applies_updates", False))
+    # the fused update on ONE device takes the shadow with the params: it
+    # reads a shadowed leaf's bf16 cotangent as it is (widened where it is
+    # read: the same values, no float32 copy) and writes the new shadow
+    # beside the new params, so neither cast is a pass of its own
+    update_owns_shadow = applies_updates and int(mesh.size) == 1
 
     def step_once(params, opt_state, shadow_t, tokens, targets, rng):
         fwd_params = (
             overlay_shadow(params, shadow_t) if shadow_t is not None else params
         )
+        shadow_in_update = update_owns_shadow and shadow_t is not None
         if accum == 1:
             loss, metrics, grads = grads_of(fwd_params, tokens, targets, rng)
-            if shadow_t is not None:
+            if shadow_t is not None and not shadow_in_update:
                 # bf16 cotangents at shadow leaves -> f32 master grads (the
                 # same values the cast-per-step path produces via the
                 # cast's transpose)
@@ -319,7 +328,12 @@ def make_train_step(
                 # full==replicated equality test stands on
                 grads = jax.lax.optimization_barrier(_to_replicated(grads))
             upd_params = _to_owner_shards(params) if full_sharded else params
-            if applies_updates:
+            new_shadow = None
+            if shadow_in_update:
+                new_params, new_opt_state, new_shadow = tx.update(
+                    grads, opt_state, upd_params, shadow=shadow_t
+                )
+            elif applies_updates:
                 # fused path (ops/fused_update.py): the whole optimizer chain
                 # plus apply_updates in one traversal
                 new_params, new_opt_state = tx.update(grads, opt_state, upd_params)
@@ -334,18 +348,13 @@ def make_train_step(
                 # bf16 bytes); then the ONE allgather returns the updated
                 # params to the replicated data-parallel layout
                 new_params = _to_owner_shards(new_params)
-                new_shadow = None
                 if shadow_t is not None:
                     new_shadow = _to_replicated(
                         _to_owner_shards(refresh_shadow(new_params, shadow_t))
                     )
                 new_params = _to_replicated(new_params)
-            else:
-                new_shadow = (
-                    refresh_shadow(new_params, shadow_t)
-                    if shadow_t is not None
-                    else None
-                )
+            elif shadow_t is not None and not shadow_in_update:
+                new_shadow = refresh_shadow(new_params, shadow_t)
             if pin_grads:
                 # same partitioner-proof reduction the fused clip uses, so the
                 # reported norm is identical across modes and mesh shapes (the
@@ -355,7 +364,8 @@ def make_train_step(
 
                 grad_norm = stable_global_norm(grads)
             else:
-                grad_norm = optax.global_norm(grads)
+                # (a leaf the update took narrow is widened inside the sum)
+                grad_norm = optax.global_norm(_cast_like(grads, params))
         metrics = dict(metrics)
         metrics["grad_norm"] = grad_norm
         return new_params, new_opt_state, new_shadow, loss, metrics

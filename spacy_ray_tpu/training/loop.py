@@ -1672,7 +1672,7 @@ def train(
     # as of the last CONSUMED group (matching the no-prefetch behavior of
     # "completed epochs" when the stream ran dry, else the current epoch)
     result.epoch = epoch if not stop else last_consumed_epoch
-    from ..ops.fused_update import fused_status
+    from ..ops.fused_update import fused_status, in_place_status
 
     result.resolved = {
         "update_sharding": update_sharding_status(update_sharding, mesh),
@@ -1680,6 +1680,10 @@ def train(
         "fused_update": fused_status(tx, mesh),
         "bf16_shadow": "on" if shadow is not None else "off",
     }
+    in_place = in_place_status(tx)
+    if in_place is not None:
+        # which leaves the kernel updated where they lay, from their shapes
+        result.resolved["fused_update_in_place"] = in_place
     if pending_metrics:
         drain_metrics()  # the steps since the last evaluation
     if counter_totals:

@@ -149,8 +149,9 @@ class OptimizerWrapper:
     def init(self, params):
         return self.tx.init(params)
 
-    def update(self, grads, state, params=None):
-        return self.tx.update(grads, state, params)
+    def update(self, grads, state, params=None, **extra):
+        # ``extra``: the fused transformation's ``shadow`` (parallel/step.py)
+        return self.tx.update(grads, state, params, **extra)
 
 
 def fuse_optimizer(tx) -> Optional["OptimizerWrapper"]:

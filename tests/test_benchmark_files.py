@@ -8,9 +8,18 @@ rehearsal widths); and the code behind every number in the ledger
 held to hand-made inputs. The cases live with the benchmark (the files are
 neither moved nor edited) and are imported here by path, fixtures included,
 so a later PR that adds a cell, or a test file beside these, is held to it
-by the driver's suite too."""
+by the driver's suite too.
+
+One case is restated here and not taken as it stands:
+``test_moe_bounded_share.py`` (PR 28) holds its metric to being the LAST
+entry of ``per_layer`` ("appended: nothing before it moved"), which the next
+PR to append a metric (PR 31, ``update_in_place_share``) cannot keep, and a
+PR that is not a ``benchmark`` PR may edit no file of ``benchmark/``. What the
+line meant is kept: the entry as PR 28 wrote it, at the place PR 28 gave it.
+The next ``benchmark`` PR should say so in that file (PERF.md section 7)."""
 
 import importlib.util
+import json
 from pathlib import Path
 
 from _pytest.fixtures import getfixturemarker
@@ -33,3 +42,11 @@ for _path in sorted(BENCH_TESTS.glob("test_*.py")):
     _found = _cases(_path)
     assert not _found.keys() & globals().keys(), (_path.name, sorted(_found.keys() & globals().keys()))
     globals().update(_found)
+
+
+def test_the_metric_is_declared_for_the_routed_cell_alone():
+    per_layer = json.loads((BENCH_TESTS.parent.parent / "BENCHMARK.json").read_text())["per_layer"]
+    assert per_layer[22] == {"name": "moe_bounded_share", "unit": "%", "better": "higher",
+                             "source": "program_counter", "layer": "models", "moves": "train_wps_chip",
+                             "workloads": ["kanana2_a3b_train"]}
+    assert [m["name"] for m in per_layer].count("moe_bounded_share") == 1

@@ -11,6 +11,9 @@ still exact.
 """
 
 import json
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +41,9 @@ from spacy_ray_tpu.registry import registry
 from spacy_ray_tpu.training import optimizers as O
 from spacy_ray_tpu.training.loop import train, validate_training
 from spacy_ray_tpu.util import synth_corpus, write_synth_jsonl
+
+
+ROOT = Path(__file__).resolve().parent.parent
 
 
 def _tree(seed=0):
@@ -132,8 +138,187 @@ def test_frozen_masked_optimizer_is_not_fusable():
 
 def test_pallas_kernel_matches_xla_math_interpret():
     """The pallas kernel (CPU interpret mode) reproduces the XLA leaf math
-    — the same probe that gates the kernel on TPU at startup."""
+    — the same probe that gates the kernel on TPU at startup. Its leaf has
+    three dimensions, a width that is no multiple of 128 and a last block
+    that is not full."""
     assert fu._probe_kernel(interpret=True) is None
+    blocking, why = fu.leaf_blocking((3, 16, 160), jnp.float32, 32 * 1024)
+    assert (blocking, why) == ((48, 160, 32), "")  # 32 rows, then 16
+
+
+_HYPER = fu.FusedHyper(
+    kind="adam", b1=0.9, b2=0.999, eps=1e-8, grad_clip=1.0, l2_grad=0.0,
+    l2_decay=0.01,
+)
+_SCAL = (2.3, 0.1, 0.001, -0.001, 6.0, 0.8)  # gnorm bc1 bc2 step ro rect
+
+
+def _leaf_operands(shape, g_dtype, seed=3):
+    r = jax.random.split(jax.random.PRNGKey(seed), 4)
+    p = jax.random.normal(r[0], shape, jnp.float32)
+    g = (jax.random.normal(r[1], shape, jnp.float32) * 0.1).astype(g_dtype)
+    m = jax.random.normal(r[2], shape, jnp.float32) * 0.01
+    v = jnp.abs(jax.random.normal(r[3], shape, jnp.float32)) * 0.01
+    return p, g, m, v
+
+
+@pytest.mark.parametrize(
+    "shape,g_dtype,shadow_dtype,ragged",
+    [
+        ((16, 64, 384), jnp.float32, None, True),  # three dimensions, merged
+        ((1000, 256), jnp.float32, None, True),  # 512 rows a block: 488 left
+        ((300, 96), jnp.float32, None, False),  # sm's width: a full-width block
+        ((96, 96), jnp.float32, None, False),  # under the floor, but walkable
+        ((700, 576), jnp.float32, jnp.bfloat16, True),  # 4.5 x 128 lanes
+        ((16, 64, 384), jnp.bfloat16, jnp.bfloat16, True),  # a shadowed leaf
+        ((1000, 256), jnp.bfloat16, jnp.bfloat16, True),
+        ((1000, 256), jnp.bfloat16, None, True),  # narrow gradient, no shadow
+    ],
+    ids=lambda x: "x".join(map(str, x)) if isinstance(x, tuple) else getattr(
+        x, "__name__", str(x)),
+)
+def test_kernel_takes_a_leaf_where_it_lies(shape, g_dtype, shadow_dtype, ragged):
+    """The kernel on a leaf in its own shape and dtypes (interpret mode)
+    against ``_leaf_math`` on the same leaf, at the probe's tolerances; the
+    shadow it writes is exactly the cast of the params it wrote."""
+    (rows, _, block_rows), _ = fu.leaf_blocking(shape, jnp.float32)
+    assert bool(rows % block_rows) == ragged
+    p, g, m, v = _leaf_operands(shape, g_dtype)
+    scal = jnp.asarray(_SCAL, jnp.float32)
+    got = jax.jit(
+        lambda *a: fu._kernel_leaf(
+            *a, hyper=_HYPER, shadow_dtype=shadow_dtype, interpret=True
+        )
+    )(p, g, m, v, scal)
+    want = fu._leaf_math(p, g.astype(jnp.float32), m, v, *scal, _HYPER)
+    assert len(got) == (4 if shadow_dtype else 3)
+    for a, b in zip(got, want):
+        assert a.shape == shape and a.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6, rtol=1e-6)
+    if shadow_dtype:
+        assert got[3].dtype == shadow_dtype and got[3].shape == shape
+        np.testing.assert_array_equal(
+            np.asarray(got[3], np.float32),
+            np.asarray(got[0].astype(shadow_dtype), np.float32),
+        )
+
+
+@pytest.mark.parametrize(
+    "shape,dtype,why",
+    [
+        ((4321,), jnp.float32, "one dimension"),
+        ((40000,), jnp.float32, "one dimension"),
+        ((3, 12, 1024), jnp.float32, "rows 12"),  # 12 rows: no whole tiles
+        ((2, 1 << 20), jnp.float32, "width 1048576"),  # no 16 rows in VMEM
+        ((256, 256), jnp.bfloat16, "dtype bfloat16"),
+    ],
+    ids=["small_flat", "flat", "odd_rows", "too_wide", "not_f32"],
+)
+def test_a_leaf_the_kernel_cannot_walk_in_place_says_why(shape, dtype, why):
+    blocking, reason = fu.leaf_blocking(shape, dtype)
+    assert blocking is None and reason.startswith(why)
+    with pytest.raises(ValueError, match="cannot take the kernel in place"):
+        fu._kernel_leaf(*[jnp.zeros(shape, dtype)] * 4, jnp.zeros(6), _HYPER)
+
+
+@pytest.fixture
+def kernel_armed(monkeypatch):
+    """The kernel on the CPU, in the interpreter, as the probe would arm it."""
+    monkeypatch.setattr(fu, "_INTERPRET", True)
+    monkeypatch.setattr(fu.GATE, "armed", True)
+
+
+_MIXED = {  # one tree, every way a leaf can go
+    "merged": (16, 64, 384), "ragged": (1000, 256), "narrow": (300, 96),
+    "tiny": (96, 96), "flat_small": (4321,), "flat": (40000,),
+    "odd_rows": (3, 12, 1024),
+}
+_SHADOWED = ("merged", "ragged", "tiny")
+
+
+def _mixed_tree():
+    params = {"trunk": {}, "head": {}}
+    grads = {"trunk": {}, "head": {}}
+    for i, (name, shape) in enumerate(_MIXED.items()):
+        where = "trunk" if name in _SHADOWED else "head"
+        p, g, _, _ = _leaf_operands(
+            shape, jnp.bfloat16 if name in _SHADOWED else jnp.float32, seed=i
+        )
+        params[where][name], grads[where][name] = p, g
+    shadow = {"trunk": {k: v.astype(jnp.bfloat16) for k, v in params["trunk"].items()}}
+    return params, grads, shadow
+
+
+def test_update_sends_each_leaf_by_its_shape(kernel_armed, monkeypatch):
+    """One tree through ``FusedTransformation.update`` with the kernel armed
+    and with it off: the same params, moments and shadow (the probe's
+    tolerances; the shadow to one bf16 step), and a tally that names what
+    fell to XLA and why. bf16 gradients at the shadowed leaves."""
+    fused = O.fuse_optimizer(O.Adam(learn_rate=0.01, L2=0.01)).tx
+    params, grads, shadow = _mixed_tree()
+    state = fused.init(params)
+    step = lambda: jax.jit(  # noqa: E731
+        lambda g, s, p, sh: fused.update(g, s, p, shadow=sh)
+    )(grads, state, params, shadow)
+    p_k, s_k, sh_k = step()
+    tally = fused.in_place
+    sizes = {k: int(np.prod(v)) for k, v in _MIXED.items()}
+    taken = sizes["merged"] + sizes["ragged"] + sizes["narrow"]
+    assert tally == {
+        "share": taken / sum(sizes.values()), "leaves": 3, "small": 2,
+        "xla": {
+            "head/flat": "one dimension",
+            "head/odd_rows": "rows 12 (merging the leading dimensions would copy)",
+        },
+    }
+    monkeypatch.setattr(fu.GATE, "armed", False)
+    fused.in_place = None
+    p_x, s_x, sh_x = step()
+    assert fused.in_place is None  # nothing went through the kernel
+    for a, b in zip(_leaves((p_k, s_k)), _leaves((p_x, s_x))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6, rtol=1e-6)
+    for a, b, p in zip(_leaves(sh_k), _leaves(sh_x), _leaves(p_k["trunk"])):
+        assert a.dtype == b.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(  # the kernel's shadow is ITS params' cast
+            np.asarray(a, np.float32), np.asarray(p.astype(jnp.bfloat16), np.float32)
+        )
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), rtol=2 ** -7
+        )
+    # without a shadow the call keeps optax's shape
+    assert len(jax.jit(lambda g, s, p: fused.update(g, s, p))(grads, state, params)) == 2
+
+
+def test_kernel_operands_are_the_leaves_themselves(kernel_armed):
+    """No ``pad`` anywhere in the update, and whatever reaches the kernel is
+    a leaf as it arrived or that leaf with its LEADING dimensions merged
+    (rows in whole tiles: a bitcast on the device); what comes back is put
+    in the leaf's shape the same way. The old wrapper ravelled, padded and
+    re-cut every operand to 128 lanes: seven copies a leaf on the chip."""
+    fused = O.fuse_optimizer(O.Adam(learn_rate=0.01)).tx
+    params, grads, shadow = _mixed_tree()
+    jaxpr = jax.make_jaxpr(lambda g, s, p, sh: fused.update(g, s, p, shadow=sh))(
+        grads, fused.init(params), params, shadow
+    ).jaxpr
+    made_by = {v: e for e in jaxpr.eqns for v in e.outvars}
+    assert not [e for e in jaxpr.eqns if e.primitive.name == "pad"]
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 3
+    inputs = set(jaxpr.invars)
+    for call in calls:
+        for var in call.invars[1:]:  # [0] is the six scalars
+            eqn = made_by.get(var)
+            if eqn is None:
+                assert var in inputs
+                continue
+            assert eqn.primitive.name == "reshape" and eqn.invars[0] in inputs
+            before, after = eqn.invars[0].aval.shape, var.aval.shape
+            assert after == (int(np.prod(before[:-1])), before[-1])
+            assert before[-2] % fu.ROW_ALIGN == 0
+    reshapes = [e for e in jaxpr.eqns if e.primitive.name == "reshape"]
+    # 4 in + 4 out for the one three-dimensional leaf; the scalars' stack
+    assert len([e for e in reshapes if e.outvars[0].aval.size > 6]) == 8
+
 
 
 def test_fused_status_labels():
@@ -332,6 +517,70 @@ def test_update_donates_params_opt_state_and_shadow(trf_setup):
     for leaf in _leaves(s):
         if leaf.dtype == jnp.float32 and leaf.size > 1:
             assert leaf.is_deleted(), "opt-state moment buffer not donated"
+    # ... and the compiled step writes each of them where it lay: every
+    # parameter, both moments and the shadow are aliased to an output (a
+    # copy round the optimizer's kernel broke exactly this on the chip)
+    p, s = _fresh(host_params, mesh, tx)
+    sh = build_param_shadow(p)
+    compiled = upd.lower(p, s, sh, tokens, targets, jax.random.PRNGKey(0)).compile()
+    aliased = {
+        int(n) for n in re.findall(
+            r"\(\s*(\d+), \{\}, (?:may|must)-alias\)", compiled.as_text()
+        )
+    }
+    state_leaves = _leaves((p, s, sh))  # the order jit flattens its arguments in
+    wanted = {
+        i for i, leaf in enumerate(state_leaves)
+        if leaf.dtype in (jnp.float32, jnp.bfloat16) and leaf.size > 1
+    }
+    assert len(wanted) == 3 * len(_leaves(p)) + len(_leaves(sh))
+    assert wanted <= aliased, sorted(wanted - aliased)
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        state_leaves[i].nbytes for i in wanted
+    )
+
+
+@pytest.mark.parametrize("config,taken,fell", [
+    ("trf", 18, {}),  # with 27 small ones: 99.4% of the elements
+    ("kanana2_a3b", 29, {}),  # with 23 small ones: 98.2%
+])
+def test_train_reports_which_leaves_the_kernel_took_in_place(
+    config, taken, fell, tmp_path, monkeypatch
+):
+    """``train`` at the benchmark's rehearsal widths with the kernel armed
+    (interpreter): ``resolved`` carries ``fused_update_in_place`` beside
+    ``fused_update``, worked out from the leaves' shapes. The widths are
+    tiny, so the floor under which a leaf is left to XLA is lowered with
+    them; nothing else of the rule moves."""
+    sys.path.insert(0, str(ROOT / "benchmark"))
+    import common
+    from spacy_ray_tpu.config import load_config
+
+    monkeypatch.setenv("SRT_PALLAS_FUSED", "1")
+    monkeypatch.setattr(fu, "_INTERPRET", True)
+    monkeypatch.setattr(fu, "MIN_KERNEL_SIZE", 2048)
+    monkeypatch.setattr(fu.GATE, "armed", None)
+    config_file = common.load_json(ROOT / "benchmark" / "configs" / f"{config}.json")
+    docs = common.load_json(ROOT / "benchmark" / "traffic" / "ewt10_b3k5.json")["docs"]
+    generate = common.load_module("generators", docs["generator"]).generate
+    common.write_jsonl(tmp_path / "train.jsonl", generate(24, 5, docs))
+    common.write_jsonl(tmp_path / "dev.jsonl", generate(4, 6, docs))
+    cfg = load_config(ROOT / config_file["program_config"], {
+        **config_file["overrides"], **config_file["rehearse_overrides"],
+        "paths.train": str(tmp_path / "train.jsonl"),
+        "paths.dev": str(tmp_path / "dev.jsonl"),
+        "training.batcher.size": 600, "training.accumulate_gradient": 1,
+        "training.max_steps": 2, "training.eval_frequency": 10 ** 9,
+        # what "auto" resolves to on the chip
+        "training.fused_update": "on", "training.bf16_shadow": "on",
+        "components.transformer.model.compute_dtype": "bfloat16",
+    }, interpolate=False)
+    _, result = train(cfg, n_workers=1, stdout_log=False)
+    assert result.resolved["fused_update"] == "active (pallas interpret-mode)"
+    tally = result.resolved["fused_update_in_place"]
+    assert set(tally) == {"share", "leaves", "small", "xla"}
+    assert (tally["leaves"], tally["xla"]) == (taken, fell), tally
+    assert 0.98 < tally["share"] < 1.0 and tally["small"] > 0
 
 
 def test_avg_step_donates_accumulator():

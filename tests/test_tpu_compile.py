@@ -75,12 +75,30 @@ SINGLE_CHIP_CASES = [
      [((4, 512, 12, 64), jnp.bfloat16)] * 3 + [((4, 512), jnp.bool_)]),
     ("flash_grad_4x512x12x64", jax.grad(_flash_loss, (0, 1, 2)),
      [((4, 512, 12, 64), jnp.bfloat16)] * 3 + [((4, 512), jnp.bool_)]),
-    ("fused_update_768x3072",
-     lambda p, g, m, v, s: fu._kernel_leaf(p, g, m, v, s, _HYPER, interpret=False),
-     [((768, 3072), jnp.float32)] * 4 + [((6,), jnp.float32)]),
-    ("fused_update_20000x768",
-     lambda p, g, m, v, s: fu._kernel_leaf(p, g, m, v, s, _HYPER, interpret=False),
-     [((20000, 768), jnp.float32)] * 4 + [((6,), jnp.float32)]),
+] + [
+    # the fused update takes each leaf where it lies: trf's widths, the
+    # routed trunk's (three dimensions merged; a table whose rows are no
+    # multiple of a block; 576 = 4.5 x 128 lanes), sm's width 96; a
+    # shadowed leaf takes its gradient as bf16 and writes its shadow
+    (f"fused_update_{'x'.join(map(str, shape))}_{jnp.dtype(g_dtype).name}"
+     + ("_shadow" if shadow else ""),
+     lambda p, g, m, v, s, shadow=shadow, block=block: fu._kernel_leaf(
+         p, g, m, v, s, _HYPER, shadow_dtype=shadow, interpret=False,
+         block_bytes=block),
+     [(shape, jnp.float32), (shape, g_dtype)] + [(shape, jnp.float32)] * 2
+     + [((6,), jnp.float32)])
+    for shape, g_dtype, shadow, block in (
+        ((768, 3072), jnp.float32, jnp.bfloat16, fu.BLOCK_BYTES),
+        ((20000, 768), jnp.float32, None, fu.BLOCK_BYTES),
+        ((16, 768, 2048), jnp.bfloat16, jnp.bfloat16, fu.BLOCK_BYTES),
+        ((16032, 2048), jnp.float32, None, fu.BLOCK_BYTES),
+        ((2048, 576), jnp.bfloat16, jnp.bfloat16, fu.BLOCK_BYTES),
+        ((512, 8192), jnp.bfloat16, jnp.bfloat16, fu.BLOCK_BYTES),
+        ((2000, 96), jnp.float32, None, fu.BLOCK_BYTES),
+        # the probe's own leaf, both forms: what a chip compiles first
+        ((3, 16, 160), jnp.float32, None, 32 * 1024),
+        ((3, 16, 160), jnp.bfloat16, jnp.bfloat16, 32 * 1024),
+    )
 ] + [
     # bf16 activations are what a trunk hands over under compute_dtype
     # "auto" on a TPU; f32 ones take the kernel's HIGHEST-precision dot
