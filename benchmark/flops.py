@@ -1,6 +1,7 @@
 """Operations the algorithm needs, from the configuration's shapes: the
-numerator of ``model_flops_util``. Kept with the benchmark so that no change
-to the program can move it. One multiply-add is two operations.
+numerator of ``step_mfu`` (``model_flops_util`` until PR 32). Kept with the
+benchmark so that no change to the program can move it. One multiply-add is
+two operations.
 
 What counts: the matrix products of the forward pass for one REAL word, and
 twice that again for the backward pass. What does not: padding, recomputation

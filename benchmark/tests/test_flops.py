@@ -63,11 +63,11 @@ FIXED_RECORDS = [
 
 
 @pytest.mark.parametrize("name,wps,context,per_word,expected", FIXED_RECORDS)
-def test_model_flops_util_of_a_fixed_record_did_not_move(name, wps, context, per_word, expected):
+def test_step_mfu_of_a_fixed_record_did_not_move(name, wps, context, per_word, expected):
     record = {"kind": "train", "train_wps_chip": wps, "config": config(name),
               "window": {"attention_context_words": context}, "device_kind": "TPU v5 lite"}
     assert flops.train_flops_per_word(config(name), context) == per_word
-    assert load_module("layer_metrics", "model_flops_util").read(record) == expected
+    assert load_module("layer_metrics", "step_mfu").read(record) == expected
 
 
 STUB_KIND = '''

@@ -110,6 +110,46 @@ def test_one_leaf_of_the_gradient_off_by_a_hundredth_fails(pipeline, stub_refere
     assert out["grad_rel_err"] == pytest.approx(0.01, rel=0.05)
 
 
+def test_the_gradient_program_is_one_cache_entry_for_every_seed(pipeline, stub_reference, tmp_path):
+    """The cotangent is an argument of the gradient program, not a constant
+    in its text: a second seed finds every program of the check in the
+    persistent compile cache and writes none (closed over, each seed was a new
+    program of the cotangent's size and more, that nothing read again: PERF.md
+    section 6, PR 32). Every run of a cell is a new process, so the persistent
+    cache's entries are what counts; the program's compile hook counts requests,
+    hits included."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    nlp, docs = pipeline
+    cache = tmp_path / "xla_cache"
+    settings = {"jax_compilation_cache_dir": str(cache), "jax_enable_compilation_cache": True,
+                "jax_persistent_cache_min_compile_time_secs": 0.0,
+                "jax_persistent_cache_min_entry_size_bytes": -1}
+    before = {key: getattr(jax.config, key) for key in settings}
+
+    def entries():
+        return sorted(p.name for p in cache.iterdir() if not p.name.endswith("-atime"))
+
+    try:
+        for key, value in settings.items():
+            jax.config.update(key, value)
+        compilation_cache.reset_cache()
+        first = trunk_check.check(nlp, nlp.params, "stub", docs, seed=3)
+        after_first = entries()
+        second = trunk_check.check(nlp, nlp.params, "stub", docs, seed=4)
+        after_second = entries()
+    finally:
+        for key, value in before.items():
+            jax.config.update(key, value)
+        compilation_cache.reset_cache()
+    assert first["ok"] and second["ok"]
+    assert first["grad_rel_err"] != second["grad_rel_err"]  # another cotangent
+    assert first["rel_err"] == second["rel_err"]  # the same forward
+    assert len([name for name in after_first if "system_loss" in name]) == 1
+    assert after_second == after_first
+
+
 def test_gradient_errors_by_hand():
     import numpy as np
 

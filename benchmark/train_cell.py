@@ -1,6 +1,6 @@
 """A training cell: ``training.loop.train`` — the function the ``train``
 command calls — on a corpus made from the seed, observed at its step boundary
-from outside. Nothing in the program is changed; the harness puts four
+from outside. Nothing in the program is changed; the harness puts five
 wrappers round names the loop looks up when it runs:
 
 * ``loop.make_train_step``: the step boundary (window edges, losses, compiles);
@@ -8,7 +8,9 @@ wrappers round names the loop looks up when it runs:
   words and padded cells, counted from the mask);
 * ``collate_pool.PipelineStats``: a handle on the loop's own stage clocks;
 * ``prefetch.prefetch_iter`` (traced run only): a ``TraceAnnotation`` round the
-  loop's wait for its next batch, on the profiler's clock.
+  loop's wait for its next batch, on the profiler's clock;
+* ``loop.resolve_dot_name``: a handle on the corpus the loop resolved, to ask
+  it whether its epochs hand out fresh examples.
 """
 
 from __future__ import annotations
@@ -27,11 +29,18 @@ from common import ROOT, BenchError, WorkDir, load_module, memory_peaks, write_j
 # ISSUE 22: an update call that compiles inside the window is cut out, and
 # more than this many in one window fail ``correct``
 MAX_COMPILES_IN_WINDOW = 2
-# batches the input pipeline can hold ready: the prefetch queue's two and the
-# one in the producer's hand. While ``stop_trace`` writes the trace the loop
-# stands still and they pile up, so the first blocked steps after it are not
-# at the pipeline's pace and are left out of ``train_step_ms``
-QUEUED_AHEAD = 3
+# blocked steps left out of ``train_step_ms``: while ``stop_trace`` writes the
+# trace the loop stands still and the input pipeline fills up (the prefetch
+# queue's two batches and the one in the producer's hand), so the first blocked
+# steps after it run at the device's pace, not the pipeline's. 3 until PR 32;
+# trf's traced run had five piled up (PERF.md sections 6 and 7)
+QUEUED_AHEAD = 5
+# a mix that says what its documents do ``on_repeat`` may pass its corpus's
+# end; no later pass may then run faster than the first by more than this
+# (words/s between update calls, passes of ``PASS_MIN_STEPS`` intervals or
+# more). Between two readings on the chip (PERF.md section 6, PR 32)
+LATER_PASS_OVER_FIRST = 1.5
+PASS_MIN_STEPS = 3
 
 
 class StepSpy:
@@ -81,8 +90,7 @@ class StepSpy:
         self.cut_sq_words = 0
         self.compiles_in_window = 0
         self.cuts: List[Tuple[float, float]] = []  # seconds from the window's opening
-        self.blocked_done_at: List[float] = []
-        self.queued_ahead = QUEUED_AHEAD
+        self.blocked_done_at: List[float] = []  # stop_trace's return, then each blocked step
         self.t_run_open: Optional[float] = None
         self.slice_note: Any = None
 
@@ -146,7 +154,7 @@ class StepSpy:
                     self._finish()
             elif self.phase == "blocked" and (
                 time.perf_counter() - self.t_run_open >= self.seconds
-                and len(self.blocked_done_at) >= 4
+                and len(self.paced_done_at()) >= 4
             ):
                 self._finish()
 
@@ -165,7 +173,7 @@ class StepSpy:
             loss = out[-2]
             self.losses.append(loss)
             self.steps.append({"words": words, "cells": cells, "sq_words": sq_words,
-                               "phase": self.phase})
+                               "phase": self.phase, "t_call": t_call})
             compiled = self.loop_thread_compiles() - before
             if compiled and self.phase == "open":
                 # a shape the warm-up did not meet: block on this call and cut
@@ -179,19 +187,21 @@ class StepSpy:
                 self.cut_cells += cells
                 self.cut_sq_words += sq_words
                 self.compiles_in_window += compiled
+                self.steps[-1]["cut"] = True
             if self.phase == "blocked":
                 jax.block_until_ready(loss)
-                if self.queued_ahead > 0:
-                    # collated while the loop was busy writing the trace: these
-                    # complete at the device's pace, not the pipeline's
-                    self.queued_ahead -= 1
-                    self.blocked_done_at = [time.perf_counter()]
-                else:
-                    self.blocked_done_at.append(time.perf_counter())
+                self.blocked_done_at.append(time.perf_counter())
             return out
 
         run.__dict__.update(getattr(update, "__dict__", {}))
         return run
+
+    def paced_done_at(self) -> List[float]:
+        """When each blocked step completed, from the last of the
+        ``QUEUED_AHEAD`` on: those were collated while the loop was busy
+        writing the trace and complete at the device's pace, not the
+        pipeline's."""
+        return self.blocked_done_at[QUEUED_AHEAD:]
 
     def _finish(self) -> None:
         """The loop polls for a shutdown request after each step and stops
@@ -283,6 +293,86 @@ def _loss_rule(losses: List[float], rule: str) -> Tuple[bool, Dict[str, float]]:
     raise BenchError(f"unknown loss rule {rule!r} in the traffic file")
 
 
+def runtime_mismatches(runtime: Dict[str, Any], expected: Dict[str, Any]) -> List[str]:
+    """``expect_runtime`` of the configuration's file against the program's
+    ``runtime`` report: for each key a prefix, or a list of prefixes any of
+    which the report may start with (the path the parent takes, or a kernel
+    that a later PR brings). Nothing else is admitted: a reference path, a
+    disabled kernel or a failed probe starts with none of them."""
+    out = []
+    for key, allowed in expected.items():
+        prefixes = (allowed,) if isinstance(allowed, str) else tuple(allowed)
+        if not str(runtime.get(key, "")).startswith(prefixes):
+            out.append(f"runtime {key}: {runtime.get(key)!r}, expected " + (
+                repr(allowed) if isinstance(allowed, str) else f"one of {list(allowed)!r}"))
+    return out
+
+
+def corpus_rule(counted: int, corpus_words: int, on_repeat: Optional[str], train_corpus: Any,
+                by_pass: Dict[int, Dict[str, float]]) -> Tuple[List[str], Dict[str, List[Any]]]:
+    """May the run have passed its corpus's end? The loop keeps each
+    document's targets on its ``Example``, so a repeated epoch collates many
+    times faster than the first and is another workload: a mix that does not
+    say what happens ``on_repeat`` may not take more words than its corpus
+    has. A mix that names a file of ``benchmark/on_repeat`` (fresh examples on
+    a repeated epoch, every pass at the first one's cost) may. It is held to
+    the corpus the loop resolved reporting ``augmented``, and to what the
+    window shows (``by_pass``: ``rates_by_pass``): no later pass of
+    ``PASS_MIN_STEPS`` steps or more between update calls faster than the
+    first by more than ``LATER_PASS_OVER_FIRST``."""
+    problems: List[str] = []
+    augmented = bool(getattr(train_corpus, "augmented", False))
+    first = by_pass.get(1)
+    later = [group["words_per_s"] for n, group in by_pass.items()
+             if n > 1 and group["steps"] >= PASS_MIN_STEPS]
+    over_first = (max(later) / first["words_per_s"]
+                  if later and first and first["steps"] >= PASS_MIN_STEPS else None)
+    if on_repeat is None:
+        if counted > corpus_words:
+            problems.append(f"the run took {counted} words of a corpus of {corpus_words}: "
+                            "an epoch repeated; the mix needs a larger n_train, or to say "
+                            "what its documents do on_repeat")
+    else:
+        if not augmented:
+            problems.append(f"the mix asks for {on_repeat!r} on a repeated epoch, and the corpus "
+                            f"the loop resolved ({type(train_corpus).__name__}) does not report "
+                            "`augmented`: a second pass would find the first one's targets kept")
+        if over_first is not None and over_first > LATER_PASS_OVER_FIRST:
+            problems.append(f"a later pass over the corpus ran at {over_first:.3f} times the "
+                            f"first one's words/s between update calls (limit "
+                            f"{LATER_PASS_OVER_FIRST}): it found targets kept, {by_pass}")
+    return problems, {
+        "words_taken_of_corpus": [counted, corpus_words],
+        "corpus_passes": [counted / max(corpus_words, 1), 1.0 if on_repeat is None else None],
+        "corpus_hands_out_fresh_examples": [augmented, on_repeat is not None],
+        "later_pass_rate_over_first": [over_first, LATER_PASS_OVER_FIRST],
+    }
+
+
+def rates_by_pass(steps: List[Dict[str, Any]], first: int, last: int,
+                  corpus_words: int) -> Dict[int, Dict[str, float]]:
+    """Words a second between update calls, for each pass over the corpus
+    that the window's steps ``[first, last)`` belong to (a step belongs to the
+    pass in which its batch begins; the warm-up's words count towards where
+    the corpus ends). In a cell whose pace the input pipeline sets, a pass
+    that found the first one's targets kept would read several times the
+    first. A call that was cut out for compiling is blocked on, so the
+    interval that follows it is left out."""
+    taken = sum(s["words"] for s in steps[:first + 1])
+    out: Dict[int, Dict[str, float]] = {}
+    for before, step in zip(steps[first:last], steps[first + 1:last]):
+        if not before.get("cut"):
+            group = out.setdefault(1 + taken // max(corpus_words, 1),
+                                   {"steps": 0, "words": 0, "seconds": 0.0})
+            group["steps"] += 1
+            group["words"] += step["words"]
+            group["seconds"] += step["t_call"] - before["t_call"]
+        taken += step["words"]
+    for group in out.values():
+        group["words_per_s"] = group["words"] / group["seconds"]
+    return out
+
+
 def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
     import jax
     import jax.monitoring
@@ -292,6 +382,7 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
     import spacy_ray_tpu.training.prefetch as prefetch
     from spacy_ray_tpu.config import load_config
     from spacy_ray_tpu.devices import runtime_report
+    from spacy_ray_tpu.registry import registry
     from spacy_ray_tpu.training.telemetry import compile_count, install_compile_hook
 
     config_file, traffic = cell["config_file"], cell["traffic_file"]
@@ -304,6 +395,12 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
     if rehearsal:
         warm.update({k: v for k, v in traffic.get("rehearse", {}).items() if k in warm})
     generator = load_module("generators", docs_spec["generator"])
+    # what a repeated epoch does to a document: nothing said, and the run may
+    # not pass its corpus's end; or a file of ``benchmark/on_repeat``, by name
+    on_repeat = docs_spec.get("on_repeat")
+    repeat = None if on_repeat is None else load_module("on_repeat", on_repeat)
+    if repeat is not None:
+        repeat.register(registry)
     install_compile_hook()
     # the program's hook counts compilations on every thread (the collate
     # stage compiles small programs on the prefetch thread); to know that an
@@ -346,8 +443,10 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
         }
         config = load_config(ROOT / config_file["program_config"], overrides,
                              interpolate=False)
+        if repeat is not None:
+            config = config.apply_overrides(repeat.overrides(config))
 
-        # ---- the four wrappers --------------------------------------------
+        # ---- the five wrappers --------------------------------------------
         words_fifo: Deque[Tuple[int, int, int]] = collections.deque()
         stats_handles: List[Any] = []
         trace_dir = (work / "trace") if args.trace else None
@@ -357,7 +456,13 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
                       loop_thread_compiles=lambda: on_loop_thread[0],
                       stats_handles=stats_handles)
         real = {"step": loop.make_train_step, "place": loop.place_batch,
-                "stats": collate_pool.PipelineStats, "prefetch": prefetch.prefetch_iter}
+                "stats": collate_pool.PipelineStats, "prefetch": prefetch.prefetch_iter,
+                "corpus": loop.resolve_dot_name}
+        corpora: Dict[str, Any] = {}  # the corpora the loop resolved, by dotted name
+
+        def resolving(config_: Any, resolved: Any, dot_name: str) -> Any:
+            corpora[dot_name] = real["corpus"](config_, resolved, dot_name)
+            return corpora[dot_name]
 
         def counting_place(tree: Any, *a: Any, **k: Any) -> Any:
             mask = getattr(tree, "mask", None)
@@ -390,6 +495,7 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
 
         loop.make_train_step = lambda *a, **k: spy.wrap(real["step"](*a, **k))
         loop.place_batch = counting_place
+        loop.resolve_dot_name = resolving
         collate_pool.PipelineStats = HandledStats
         if args.trace:
             prefetch.prefetch_iter = lambda it, size=2: AnnotatedWait(
@@ -399,6 +505,7 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
         finally:
             loop.make_train_step = real["step"]
             loop.place_batch = real["place"]
+            loop.resolve_dot_name = real["corpus"]
             collate_pool.PipelineStats = real["stats"]
             prefetch.prefetch_iter = real["prefetch"]
 
@@ -420,11 +527,8 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
         if residency.get("params", {}).get("devices") != chips:
             problems.append(f"the step ran on {residency.get('params', {}).get('devices')} "
                             f"devices, the cell asks for {chips}")
-        expected = config_file["expect_runtime"][str(chips)]
-        mismatched = [] if rehearsal else [
-            f"runtime {key}: {runtime.get(key)!r}, expected {prefix!r}"
-            for key, prefix in expected.items()
-            if not str(runtime.get(key, "")).startswith(prefix)]
+        mismatched = [] if rehearsal else runtime_mismatches(
+            runtime, config_file["expect_runtime"][str(chips)])
         problems.extend(mismatched)
         non_finite = sum(1 for x in losses if not math.isfinite(x))
         if non_finite:
@@ -437,11 +541,13 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
             problems.append(f"harness counted {counted} words, the loop {result.words_seen}")
         if window["compiles"] > MAX_COMPILES_IN_WINDOW:
             problems.append(f"{window['compiles']} compilations inside the window")
-        if counted > corpus_words:
-            # the loop keeps each document's targets: a second epoch collates
-            # several times faster than the first, and is another workload
-            problems.append(f"the run took {counted} words of a corpus of {corpus_words}: "
-                            "an epoch repeated; the mix needs a larger n_train")
+        train_corpus = corpora.get(
+            config.get("training", {}).get("train_corpus", "corpora.train"))
+        by_pass = rates_by_pass(spy.steps, spy.edges["open"]["step"],
+                                spy.edges["close"]["step"], corpus_words)
+        corpus_problems, corpus_compared = corpus_rule(
+            counted, corpus_words, on_repeat, train_corpus, by_pass)
+        problems.extend(corpus_problems)
         import trunk_check
 
         trunk = trunk_check.check(
@@ -460,23 +566,25 @@ def run(cell: Dict[str, Any], args: Any) -> Dict[str, Any]:
                                             loss_means["first_third_mean"]],
             "words_counted_equal_loop": [counted, result.words_seen],
             "compiles_in_window": [window["compiles"], MAX_COMPILES_IN_WINDOW],
-            "words_taken_of_corpus": [counted, corpus_words],
+            **corpus_compared,
             **trunk_check.compared(trunk, cell["config"]),
         }
 
     wps_chip = window["words"] / window["seconds"] / chips
-    blocked = spy.blocked_done_at
+    blocked, paced = spy.blocked_done_at, spy.paced_done_at()
     record = {
         "kind": "train", "chips": chips, "window": window, "runtime": runtime,
         "trace": trace_summary, "memory_peaks": peaks, "step_memory": step_mem,
         "train_wps_chip": wps_chip,
-        "step_intervals_s": [b - a for a, b in zip(blocked, blocked[1:])],
+        "step_intervals_s": [b - a for a, b in zip(paced, paced[1:])],
         "config": config_file, "device_kind": jax.devices()[0].device_kind,
     }
     print(f"window {window}", flush=True)
     print(f"memory peaks {peaks}; step memory (compiler) {step_mem}; corpus words "
-          f"{corpus_words}, taken {counted}; blocked step intervals (s) "
-          f"{[round(x, 3) for x in record['step_intervals_s']]}", flush=True)
+          f"{corpus_words}, taken {counted}; words/s between update calls by pass of the "
+          f"corpus {by_pass}; blocked step intervals (s), the first {QUEUED_AHEAD} left out "
+          f"of train_step_ms {[round(b - a, 3) for a, b in zip(blocked, blocked[1:])]}",
+          flush=True)
     print(f"runtime {runtime}", flush=True)
     if trace_summary:
         print("trace " + str({k: trace_summary[k] for k in (

@@ -15,7 +15,11 @@ program's own forward under ``jax.grad`` (its kernels' backward, its remat,
 its scan) against ``jax.grad`` of ``reference.forward``. The error of a leaf
 is max |difference| over max |reference| of that leaf or of the median leaf,
 whichever is larger (a leaf whose gradient is all but nought would otherwise
-be judged on its rounding); the worst leaf has to meet the tolerance.
+be judged on its rounding); the worst leaf has to meet the tolerance. R is an
+ARGUMENT of both losses, never a constant they close over: the gradient
+program's text is then the same for every seed, one entry of the compile
+cache for all runs of a cell (closed over, each run compiled a new program
+of 40 MiB that nothing read again; PERF.md section 6, PR 32).
 """
 
 from __future__ import annotations
@@ -100,12 +104,12 @@ def check(nlp: Any, params: Any, config_name: str,
     def trunk_forward(p: Any, t: Any) -> Any:
         return trunk.forward(p, t, Context(train=False)).X
 
-    def system(fn: Any, precision: Any) -> Any:
+    def system(fn: Any, precision: Any, *more: Any) -> Any:
         # a new jit for each precision: the context is read when it traces
         scope = jax.default_matmul_precision(precision) if precision else nullcontext()
         with scope:
             return jax.tree_util.tree_map(lambda a: np.asarray(a, np.float32),
-                                          jax.jit(fn)(params[name], tokens))
+                                          jax.jit(fn)(params[name], tokens, *more))
 
     master = jax.tree_util.tree_map(np.asarray, params[name])
     inputs = getattr(reference, "make_inputs", hash_inputs)(nlp, master, tokens)
@@ -130,16 +134,16 @@ def check(nlp: Any, params: Any, config_name: str,
         cotangent = jnp.asarray(
             np.random.default_rng(seed).standard_normal(want.shape).astype(np.float32) * real)
 
-        def system_loss(p: Any, t: Any) -> Any:
-            return jnp.sum(trunk_forward(p, t).astype(jnp.float32) * cotangent)
+        def system_loss(p: Any, t: Any, r: Any) -> Any:
+            return jnp.sum(trunk_forward(p, t).astype(jnp.float32) * r)
 
-        def reference_loss(p: Any) -> Any:
-            return jnp.sum(reference.forward(p, *inputs) * cotangent)
+        def reference_loss(p: Any, r: Any) -> Any:
+            return jnp.sum(reference.forward(p, *inputs) * r)
 
-        got = system(jax.grad(system_loss), precision)
+        got = system(jax.grad(system_loss), precision, cotangent)
         wanted = jax.tree_util.tree_map(
             lambda a: np.asarray(a, np.float32),
-            jax.grad(reference_loss)(jax.tree_util.tree_map(jnp.asarray, master)))
+            jax.grad(reference_loss)(jax.tree_util.tree_map(jnp.asarray, master), cotangent))
         out.update(gradient_errors(got, wanted), grad_tolerance=grad_tolerance)
         out["ok"] = bool(out["ok"] and out["grad_rel_err"] <= grad_tolerance)
     return out
