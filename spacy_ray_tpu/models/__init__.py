@@ -7,3 +7,4 @@ from . import heads  # noqa: F401  (registers spacy.Tagger.v2 etc.)
 from . import parser  # noqa: F401  (registers spacy.TransitionBasedParser.v2)
 from . import transformer  # noqa: F401  (registers spacy_ray_tpu.TransformerEncoder.v1)
 from . import latent_moe  # noqa: F401  (registers spacy_ray_tpu.LatentMoETrunk.v1)
+from . import hybrid_ssm  # noqa: F401  (registers spacy_ray_tpu.HybridSSMTrunk.v1)

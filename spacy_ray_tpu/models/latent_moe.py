@@ -189,7 +189,7 @@ def _permute_bwd(inverse, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-def route(p, h: jnp.ndarray, s: Shape) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def route(p, h: jnp.ndarray, s) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """h [N, D] float32 -> (chosen experts [N, top_k] int32, their weights
     [N, top_k] float32). Scores are sigmoids; the bias moves the SELECTION
     only (no gradient reaches it); the weights are the chosen scores,
@@ -209,7 +209,7 @@ def route(p, h: jnp.ndarray, s: Shape) -> Tuple[jnp.ndarray, jnp.ndarray]:
 BOUND_STEP = 512
 
 
-def live_bound(n_pairs: int, s: Shape) -> int:
+def live_bound(n_pairs: int, s) -> int:
     """Rows of the bounded path's buffer for ``n_pairs`` (word, choice)
     pairs: twice this rank's even share, rounded up to ``BOUND_STEP``. At
     ``n_pairs`` or over it there is no bounded path."""
@@ -224,17 +224,41 @@ def _live_rows(n_live: jnp.ndarray, rows: int) -> jnp.ndarray:
     return (jnp.arange(rows, dtype=jnp.int32) < n_live)[:, None]
 
 
-def _expert_products(rows, live, group_sizes, eg, eu, ed):
-    """rows [R, D] sorted by held expert -> the experts' outputs [R, D]."""
+# an expert's FORM: which stacked matrices it has, in the order the paths take
+# them, and what stands between the first products and the last. Both trunks
+# that route (this one: gated; models/hybrid_ssm.py: relu2) run the one
+# dispatch below and differ in this argument alone
+GATED_SILU = "gated_silu"  # (silu(h Wg) * (h Wu)) Wd
+RELU2 = "relu2"  # relu(h Wu)^2 Wd
+EXPERT_LEAVES = {GATED_SILU: ("eg_W", "eu_W", "ed_W"), RELU2: ("eu_W", "ed_W")}
+
+
+def _expert_products(form, rows, live, group_sizes, experts):
+    """rows [R, D] sorted by held expert -> (the experts' outputs [R, D], the
+    live rows whose expert rightly answered with noughts [R] bool, or None
+    where the form has no such answer). ``experts``: the held experts' stacked
+    matrices, ``EXPERT_LEAVES[form]``. A relu's answer IS a row of noughts
+    where no unit of the expert fires for the word (9 of 850,972 pairs of a
+    run on the chip, PR 34): the first product came back and the activation
+    is nought everywhere, which is not a pair that was dropped."""
     cd = rows.dtype
     grouped = partial(jax.lax.ragged_dot, group_sizes=group_sizes)
-    gate = grouped(rows, eg).astype(jnp.float32)
-    up = grouped(rows, eu).astype(jnp.float32)
-    inner = jnp.where(live, jax.nn.silu(gate) * up, 0).astype(cd)
-    return jnp.where(live, grouped(inner, ed), 0)
+    if form == GATED_SILU:
+        eg, eu, ed = experts
+        gate = grouped(rows, eg).astype(jnp.float32)
+        up = grouped(rows, eu).astype(jnp.float32)
+        inner = jax.nn.silu(gate) * up
+        noughts = None
+    else:
+        eu, ed = experts
+        up = grouped(rows, eu).astype(jnp.float32)
+        inner = jnp.square(jax.nn.relu(up))
+        noughts = (live[:, 0] & jnp.any(up != 0, axis=-1)) & ~jnp.any(inner != 0, axis=-1)
+    inner = jnp.where(live, inner, 0).astype(cd)
+    return jnp.where(live, grouped(inner, ed), 0), noughts
 
 
-def _full_path(h16, w, eg, eu, ed, order, inverse, group_sizes):
+def _full_path(form, h16, w, experts, order, inverse, group_sizes):
     """Every pair moved: a buffer of ``P = N x top_k`` rows. Returns (the
     words' sums [N, D] float32, which pairs' output came back [N, K] bool)."""
     N, D = h16.shape
@@ -244,11 +268,12 @@ def _full_path(h16, w, eg, eu, ed, order, inverse, group_sizes):
         rows = _permute(jnp.repeat(h16, K, axis=0), order, inverse)
         rows = jnp.where(live, rows, 0)
     with jax.named_scope(names.SCOPE_MOE_EXPERTS):
-        out_rows = _expert_products(rows, live, group_sizes, eg, eu, ed)
+        out_rows, noughts = _expert_products(form, rows, live, group_sizes, experts)
     with jax.named_scope(names.SCOPE_MOE_COMBINE):
         pairs = _permute(out_rows, inverse, order).reshape(N, K, D)
         y = jnp.sum(pairs.astype(jnp.float32) * w[..., None], axis=1)
-        return y, jnp.any(pairs != 0, axis=-1)
+        came_back = jnp.any(pairs != 0, axis=-1)
+        return y, came_back if noughts is None else came_back | noughts[inverse].reshape(N, K)
 
 
 def _sum_choices(rows, pos, w=None) -> jnp.ndarray:
@@ -308,7 +333,7 @@ def _rows_out_bwd(res, g):
 _rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
 
 
-def _bounded_path(bound, h16, w, eg, eu, ed, order, inverse, group_sizes):
+def _bounded_path(bound, form, h16, w, experts, order, inverse, group_sizes):
     """``_full_path`` for a routing whose live pairs fit ``bound`` rows."""
     N, K = w.shape
     slot = order[:bound]  # the flat (word, choice) pair of each row of the buffer
@@ -317,48 +342,52 @@ def _bounded_path(bound, h16, w, eg, eu, ed, order, inverse, group_sizes):
         live = _live_rows(jnp.sum(group_sizes), bound)
         rows = jnp.where(live, _rows_in(h16, slot // K, pos), 0)
     with jax.named_scope(names.SCOPE_MOE_EXPERTS):
-        out_rows = _expert_products(rows, live, group_sizes, eg, eu, ed)
+        out_rows, noughts = _expert_products(form, rows, live, group_sizes, experts)
     with jax.named_scope(names.SCOPE_MOE_COMBINE):
         y = _rows_out(out_rows, w, slot, pos)
-        came_back = jnp.any(out_rows != 0, axis=-1).at[pos].get(mode="fill", fill_value=False)
-        return y, came_back
+        came_back = jnp.any(out_rows != 0, axis=-1)
+        if noughts is not None:
+            came_back = came_back | noughts
+        return y, came_back.at[pos].get(mode="fill", fill_value=False)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _by_live_size(bound, fits, h16, w, eg, eu, ed, order, inverse, group_sizes):
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _by_live_size(bound, form, fits, h16, w, experts, order, inverse, group_sizes):
     """The bounded path where the live pairs fit ``bound`` rows (``fits``,
     known on the device), the full path where they do not. Its own
     ``custom_vjp`` so that the backward is a branch too and each branch
     recomputes its forward inside: differentiating the ``cond`` itself would
     hand every residual of BOTH branches across it, the untaken one's as
     noughts, and the bounded branch would write the full one's P-row arrays."""
-    return jax.lax.cond(fits, partial(_bounded_path, bound), _full_path,
-                        h16, w, eg, eu, ed, order, inverse, group_sizes)
+    return jax.lax.cond(fits, partial(_bounded_path, bound, form), partial(_full_path, form),
+                        h16, w, experts, order, inverse, group_sizes)
 
 
-def _by_live_size_fwd(bound, fits, *operands):
-    return _by_live_size(bound, fits, *operands), (fits, operands)
+def _by_live_size_fwd(bound, form, fits, *operands):
+    return _by_live_size(bound, form, fits, *operands), (fits, operands)
 
 
-def _by_live_size_bwd(bound, res, cotangent):
+def _by_live_size_bwd(bound, form, res, cotangent):
     fits, (*floats, order, inverse, group_sizes) = res
 
     def pull(path, g, *floats):
         _, vjp = jax.vjp(lambda *f: path(*f, order, inverse, group_sizes)[0], *floats)
         return vjp(g)
 
-    grads = jax.lax.cond(fits, partial(pull, partial(_bounded_path, bound)),
-                         partial(pull, _full_path), cotangent[0], *floats)
+    grads = jax.lax.cond(fits, partial(pull, partial(_bounded_path, bound, form)),
+                         partial(pull, partial(_full_path, form)), cotangent[0], *floats)
     return (None, *grads, None, None, None)
 
 
 _by_live_size.defvjp(_by_live_size_fwd, _by_live_size_bwd)
 
 
-def routed_experts(p, h: jnp.ndarray, token_mask, idx, weights, s: Shape, cd):
+def routed_experts(p, h: jnp.ndarray, token_mask, idx, weights, s, cd, form: str = GATED_SILU):
     """The held experts' part of ``sum_k w_k Expert_k(h)``. h [N, D] float32,
-    token_mask [N] bool, idx / weights [N, top_k]. Returns ([N, D] float32,
-    counters int32 [N_COUNTERS])."""
+    token_mask [N] bool, idx / weights [N, top_k]; ``s`` gives ``top_k``,
+    ``n_experts``, ``experts_held`` and ``held_from``; ``form`` says which
+    matrices of ``p`` an expert is made of. Returns ([N, D] float32, counters
+    int32 [N_COUNTERS])."""
     K, held = s.top_k, s.experts_held
     P = h.shape[0] * K
     with jax.named_scope(names.SCOPE_MOE_DISPATCH):
@@ -371,15 +400,15 @@ def routed_experts(p, h: jnp.ndarray, token_mask, idx, weights, s: Shape, cd):
         group_sizes = jnp.sum(
             key[:, None] == jnp.arange(held, dtype=key.dtype)[None, :], axis=0, dtype=jnp.int32)
     operands = (h.astype(cd), jnp.where(valid, weights, 0.0),
-                p["eg_W"].astype(cd), p["eu_W"].astype(cd), p["ed_W"].astype(cd),
+                tuple(p[name].astype(cd) for name in EXPERT_LEAVES[form]),
                 order, inverse, group_sizes)
     bound = live_bound(P, s)
     if bound >= P:  # no smaller buffer to be had: one path, no branch
         fits = jnp.bool_(False)
-        y, came_back = _full_path(*operands)
+        y, came_back = _full_path(form, *operands)
     else:
         fits = jnp.sum(group_sizes) <= bound
-        y, came_back = _by_live_size(bound, fits, *operands)
+        y, came_back = _by_live_size(bound, form, fits, *operands)
     counters = jnp.stack([
         jnp.sum(token_mask, dtype=jnp.int32) * K,
         jnp.sum(valid, dtype=jnp.int32),

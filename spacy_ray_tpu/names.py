@@ -59,6 +59,13 @@ SCOPE_MOE_DISPATCH = "moe/dispatch"  # sort the (word, choice) pairs by held exp
 SCOPE_MOE_EXPERTS = "moe/experts"  # the grouped products over the experts held
 SCOPE_MOE_COMBINE = "moe/combine"  # un-sort, weight and sum each word's pairs
 SCOPE_MOE_SHARED = "moe/shared"  # the shared experts, every word
+# inside SCOPE_TRUNK, the trunk built from a layer pattern (models/hybrid_ssm.py):
+# every operation of a layer lies under its kind, so a trace splits the step
+# by kind of layer. The expert layers' parts are SCOPE_MOE_* above
+SCOPE_MAMBA = "mamba"  # a Mamba-2 mixer: projections, convolution, gate, norm
+SCOPE_MAMBA_SCAN = "mamba/scan"  # the chunked selective scan alone
+SCOPE_ATTENTION = "attention"  # grouped-key attention: projections, scores, output
+SCOPE_MOE = "moe"  # an expert layer's pre-norm and residual (its parts: SCOPE_MOE_*)
 
 
 def head_scope(name: str) -> str:
@@ -82,6 +89,9 @@ MOE_COMPUTED = "count_moe_computed"  # of those, the pairs whose expert's output
 MOE_MAX_LOAD = "count_moe_max_load"  # rows of the fullest held expert, summed over layers
 MOE_LAYER_CALLS = "count_moe_layer_calls"  # expert layers run (micro-batches x layers)
 MOE_BOUNDED_CALLS = "count_moe_bounded_calls"  # of those, the calls whose live pairs fit the bound
+# the state-space layers' scan (models/hybrid_ssm.py), from the batch's mask
+SSM_CHUNKS = "count_ssm_chunks"  # (row, chunk) blocks the scan ran: rows x T / chunk x M layers
+SSM_LIVE_CHUNKS = "count_ssm_live_chunks"  # of those, the blocks holding at least one real word
 
 # ---- pallas kernels -------------------------------------------------------------
 KERNEL_FLASH_FWD = "srt_flash_fwd"

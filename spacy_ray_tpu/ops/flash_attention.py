@@ -379,18 +379,30 @@ def xla_attention(
 ) -> jnp.ndarray:
     """Attention as plain XLA operations: q/k [B, T, H, Dqk], v [B, T, H, Dv]
     (the two widths may differ), mask [B, T] bool (key padding), ``causal``
-    adds the lower triangle. Scores and softmax in float32; a query with no
-    visible key (a padded row) gets a finite, uniform row. Returns
-    [B, T, H, Dv] in v.dtype."""
-    T = q.shape[1]
+    adds the lower triangle. Grouped keys: k and v may have fewer heads than
+    q, a whole number of query heads to each (query head j reads key head
+    ``j // (H / Hkv)``); no key is repeated in memory. Scores and softmax in
+    float32; a query with no visible key (a padded row) gets a finite,
+    uniform row. Returns [B, T, H, Dv] in v.dtype."""
+    B, T, H, _ = q.shape
     scale = 1.0 / (q.shape[-1] ** 0.5)
-    scores = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
+    kv_heads = k.shape[2]
+    if kv_heads == H:
+        scores = jnp.einsum(
+            "bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32) * scale
+    else:
+        grouped = q.reshape(B, T, kv_heads, H // kv_heads, q.shape[-1])
+        scores = jnp.einsum(
+            "bqhgd,bkhd->bhgqk", grouped, k, preferred_element_type=jnp.float32) * scale
     keep = mask[:, None, None, :]
     if causal:
         keep = keep & jnp.tril(jnp.ones((T, T), bool))[None, None]
-    p = jax.nn.softmax(jnp.where(keep, scores, NEG), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+    if kv_heads == H:
+        p = jax.nn.softmax(jnp.where(keep, scores, NEG), axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
+    p = jax.nn.softmax(jnp.where(keep[:, :, None], scores, NEG), axis=-1)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v)
+    return out.reshape(B, T, H, v.shape[-1])
 
 
 def attention(
@@ -405,17 +417,23 @@ def attention(
     An armed kernel that gives way says so: each branch notes its path on
     ``GATE``, and ``flash_attention_status`` reports what was taken.
 
-    ``causal`` and a ``v`` whose width differs from ``q``'s (latent
-    attention: 192 against 128) are outside what the kernels compute (one
-    head width, a key-padding bias): such a call goes through
-    :func:`xla_attention` and says so on ``GATE``."""
+    ``causal``, a ``v`` whose width differs from ``q``'s (latent
+    attention: 192 against 128) and grouped keys (``k`` and ``v`` with fewer
+    heads than ``q``: 32 query heads on 2) are outside what the kernels
+    compute (one head width, one key head a query head, a key-padding bias):
+    such a call goes through :func:`xla_attention` and says so on ``GATE``."""
     from ..parallel import context as pctx
 
     B, T, H, Dh = q.shape
-    if causal or v.shape[-1] != Dh:
+    kv_heads = k.shape[2]
+    if kv_heads != H and (H % kv_heads or v.shape[2] != kv_heads):
+        raise ValueError(f"{H} query heads cannot share {kv_heads} key and "
+                         f"{v.shape[2]} value heads evenly")
+    if causal or v.shape[-1] != Dh or kv_heads != H:
         GATE.took(
-            f"xla (causal={causal}, q/k width {Dh}, v width {v.shape[-1]}: "
-            "outside the flash kernels)")
+            f"xla (causal={causal}, q/k width {Dh}, v width {v.shape[-1]}"
+            + (f", {H} query heads on {kv_heads} key heads" if kv_heads != H else "")
+            + ": outside the flash kernels)")
         return xla_attention(q, k, v, mask, causal)
     out = None
     if not flash_attention_enabled():

@@ -1,5 +1,5 @@
 """The benchmark's own tests under the tier-1 suite, every ``test_*.py`` of
-``benchmark/tests`` (eight files): every cell of ``BENCHMARK.json`` finds the files
+``benchmark/tests`` (twelve files since PR 34): every cell of ``BENCHMARK.json`` finds the files
 ``run.py`` will look for by name; the ``kanana2_a3b`` configuration's
 operation count, readers and sizes hold (no JAX); the comparison that decides
 ``correct`` in that cell fails on each planted fault and on the control (CPU,
@@ -16,7 +16,19 @@ entry of ``per_layer`` ("appended: nothing before it moved"), which the next
 PR to append a metric (PR 31, ``update_in_place_share``) cannot keep, and a
 PR that is not a ``benchmark`` PR may edit no file of ``benchmark/``. What the
 line meant is kept: the entry as PR 28 wrote it, at the place PR 28 gave it.
-The next ``benchmark`` PR should say so in that file (PERF.md section 7)."""
+The next ``benchmark`` PR should say so in that file (PERF.md section 7).
+
+PR 34 (``model_config``) restates two more the same way, for the same
+reason. Its cell ``nemotron3_nano_a3b_train`` is a second routed trunk and a
+fourth one-chip cell, and ISSUE 34 has its name appended to the ``workloads``
+lists of the routed trunk's metrics and of ``update_in_place_share``; the two
+cases that pin those lists to the cells of their day
+(``test_moe_bounded_share.py``: "the routed cell alone";
+``test_update_in_place_share.py``: three one-chip cells, and the list equal to
+EVERY one-chip cell, which any new one-chip cell breaks whichever way its PR
+decides) may not be edited by a PR that is not a ``benchmark`` PR. What they
+meant is kept below: the entries as their PRs wrote them, at their places,
+with the new cell's name appended and nothing else moved."""
 
 import importlib.util
 import json
@@ -44,9 +56,25 @@ for _path in sorted(BENCH_TESTS.glob("test_*.py")):
     globals().update(_found)
 
 
+BENCHMARK = json.loads((BENCH_TESTS.parent.parent / "BENCHMARK.json").read_text())
+ROUTED_CELLS = ["kanana2_a3b_train", "nemotron3_nano_a3b_train"]
+
+
 def test_the_metric_is_declared_for_the_routed_cell_alone():
-    per_layer = json.loads((BENCH_TESTS.parent.parent / "BENCHMARK.json").read_text())["per_layer"]
+    """The routed cells, since PR 34 brought a second trunk that routes."""
+    per_layer = BENCHMARK["per_layer"]
     assert per_layer[22] == {"name": "moe_bounded_share", "unit": "%", "better": "higher",
                              "source": "program_counter", "layer": "models", "moves": "train_wps_chip",
-                             "workloads": ["kanana2_a3b_train"]}
+                             "workloads": ROUTED_CELLS}
     assert [m["name"] for m in per_layer].count("moe_bounded_share") == 1
+    for name in ("moe_held_share", "moe_load_imbalance"):  # PR 27's two, read from the same block
+        assert next(m for m in per_layer if m["name"] == name)["workloads"] == ROUTED_CELLS
+
+
+def test_the_metric_is_declared_for_the_one_chip_cells():
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == "update_in_place_share")
+    assert entry == {"name": "update_in_place_share", "unit": "%", "better": "higher",
+                     "source": "program_counter", "layer": "kernels", "moves": "train_wps_chip",
+                     "workloads": ["trf_train", "sm_train"] + ROUTED_CELLS}
+    one_chip = [c["name"] for c in BENCHMARK["workloads"] if c["chips"] == 1]
+    assert entry["workloads"] == one_chip  # on four chips the kernel gives way to XLA

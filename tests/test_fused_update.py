@@ -540,12 +540,17 @@ def test_update_donates_params_opt_state_and_shadow(trf_setup):
     )
 
 
-@pytest.mark.parametrize("config,taken,fell", [
-    ("trf", 18, {}),  # with 27 small ones: 99.4% of the elements
-    ("kanana2_a3b", 29, {}),  # with 23 small ones: 98.2%
+@pytest.mark.parametrize("config,taken,fell,floor", [
+    ("trf", 18, {}, 0.98),  # with 27 small ones: 99.4% of the elements
+    ("kanana2_a3b", 29, {}, 0.98),  # with 23 small ones: 98.2%
+    # the pattern trunk: the convolution's taps [4, channels], the 64-element
+    # A_log / D / dt_bias and the gains are small here (and, but for the taps,
+    # at the published widths too: 39 leaves taken, 42 small, 99.99%); with 52
+    # small ones the rehearsal widths come to 97.9%, so this case has its own floor
+    ("nemotron3_nano_a3b", 33, {}, 0.975),
 ])
 def test_train_reports_which_leaves_the_kernel_took_in_place(
-    config, taken, fell, tmp_path, monkeypatch
+    config, taken, fell, floor, tmp_path, monkeypatch
 ):
     """``train`` at the benchmark's rehearsal widths with the kernel armed
     (interpreter): ``resolved`` carries ``fused_update_in_place`` beside
@@ -580,7 +585,7 @@ def test_train_reports_which_leaves_the_kernel_took_in_place(
     tally = result.resolved["fused_update_in_place"]
     assert set(tally) == {"share", "leaves", "small", "xla"}
     assert (tally["leaves"], tally["xla"]) == (taken, fell), tally
-    assert 0.98 < tally["share"] < 1.0 and tally["small"] > 0
+    assert floor < tally["share"] < 1.0 and tally["small"] > 0
 
 
 def test_avg_step_donates_accumulator():

@@ -471,6 +471,70 @@ def test_accumulated_steps_are_scoped_and_named(mixed_dir, monkeypatch):
     assert re.search(rf'loc\("[^"]*transpose\(jvp\({names.SCOPE_TRUNK}\)\)/', text)
 
 
+# the trf shape over the trunk built from a layer pattern (models/hybrid_ssm.py):
+# one layer of each kind and a second state-space one
+HYBRID_TRUNK = """[components.transformer]
+factory = "transformer"
+
+[components.transformer.model]
+@architectures = "spacy_ray_tpu.HybridSSMTrunk.v1"
+pattern = "ME*M"
+width = 32
+ssm_heads = 4
+ssm_head_dim = 8
+ssm_groups = 2
+ssm_state = 8
+chunk = 8
+n_heads = 4
+n_kv_heads = 2
+head_dim = 8
+expert_ffn = 16
+shared_ffn = 24
+n_experts = 8
+experts_held = 4
+top_k = 2
+vocab_rows = 97
+"""
+HYBRID_CFG = re.sub(
+    r"\[components\.tok2vec\]\n.*?embed_size = 256\n", HYBRID_TRUNK, SM_CFG, flags=re.S,
+).replace('pipeline = ["tok2vec",', 'pipeline = ["transformer",')
+
+
+def test_a_pattern_trunk_splits_the_step_by_kind_of_layer(mixed_dir, monkeypatch):
+    """Inside ``trunk`` every layer's operations lie under its kind
+    (``mamba``, the scan alone under ``mamba/scan``, ``attention``, ``moe``
+    and its parts), forward and backward, each layer rematerialised."""
+    text = _lowered_step(HYBRID_CFG, mixed_dir, monkeypatch)
+    assert f"module @jit_{names.PROGRAM_TRAIN_STEP} " in text
+    trunk = re.escape(names.SCOPE_TRUNK)
+    for scope in (names.SCOPE_MAMBA, names.SCOPE_MAMBA_SCAN, names.SCOPE_ATTENTION, names.SCOPE_MOE,
+                  names.SCOPE_MOE_ROUTER, names.SCOPE_MOE_DISPATCH, names.SCOPE_MOE_EXPERTS,
+                  names.SCOPE_MOE_COMBINE, names.SCOPE_MOE_SHARED):
+        for side in (rf"jvp\({trunk}\)", rf"transpose\(jvp\({trunk}\)\)"):
+            assert re.search(rf'loc\("[^"]*{side}/[^"]*\b{re.escape(scope)}[)/"]', text), (scope, side)
+    # a part's name starts with its whole's, so a reader by prefix finds both
+    assert names.SCOPE_MAMBA_SCAN.startswith(names.SCOPE_MAMBA + "/")
+    assert names.SCOPE_MOE_ROUTER.startswith(names.SCOPE_MOE + "/")
+
+
+def test_the_scans_counters_leave_the_device_and_reach_the_report(mixed_dir):
+    """``count_ssm_chunks`` / ``count_ssm_live_chunks`` are device counters
+    like the routed trunk's six: summed over the run, and the trunk's own
+    summary turns them into the ``ssm`` block of the ``runtime`` report."""
+    for key in (names.SSM_CHUNKS, names.SSM_LIVE_CHUNKS, names.MOE_ASSIGNMENTS,
+                names.MOE_BOUNDED_CALLS):
+        assert key.startswith(names.COUNTER_PREFIX)
+    _, result = train(_config(HYBRID_CFG, mixed_dir, **{"training.max_steps": 3}),
+                      n_workers=1, stdout_log=False)
+    resolved = result.resolved
+    assert resolved["layer_pattern"] == "ME*M" and resolved["ssm_scan"] == "chunked 8, xla"
+    ssm = resolved["ssm"]
+    assert ssm["layers"] == 2 and ssm["chunk"] == 8
+    assert 0 < ssm["live_chunks"] <= ssm["chunks"] and ssm["chunks"] % 2 == 0
+    assert resolved["moe"]["layer_calls"] == 3 and resolved["moe_dropped"] == "0"
+    assert resolved["moe_dispatch"].startswith("sorted, ragged_dot, 4 of 8 held")
+
+
 def _pallas_names(fn, *args):
     found = []
 

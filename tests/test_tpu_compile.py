@@ -78,7 +78,8 @@ SINGLE_CHIP_CASES = [
 ] + [
     # the fused update takes each leaf where it lies: trf's widths, the
     # routed trunk's (three dimensions merged; a table whose rows are no
-    # multiple of a block; 576 = 4.5 x 128 lanes), sm's width 96; a
+    # multiple of a block; 576 = 4.5 x 128 lanes), sm's width 96, the
+    # pattern trunk's; a
     # shadowed leaf takes its gradient as bf16 and writes its shadow
     (f"fused_update_{'x'.join(map(str, shape))}_{jnp.dtype(g_dtype).name}"
      + ("_shadow" if shadow else ""),
@@ -95,6 +96,14 @@ SINGLE_CHIP_CASES = [
         ((2048, 576), jnp.bfloat16, jnp.bfloat16, fu.BLOCK_BYTES),
         ((512, 8192), jnp.bfloat16, jnp.bfloat16, fu.BLOCK_BYTES),
         ((2000, 96), jnp.float32, None, fu.BLOCK_BYTES),
+        # the pattern trunk's (models/hybrid_ssm.py): the convolution's four
+        # taps (rows under a tile), the input projection and the step's 64
+        # columns of it (half a lane tile), experts of 1856 = 14.5 x 128
+        ((4, 6144), jnp.float32, None, fu.BLOCK_BYTES),
+        ((2688, 10240), jnp.bfloat16, jnp.bfloat16, fu.BLOCK_BYTES),
+        ((2688, 64), jnp.float32, None, fu.BLOCK_BYTES),
+        ((8, 2688, 1856), jnp.bfloat16, jnp.bfloat16, fu.BLOCK_BYTES),
+        ((8, 1856, 2688), jnp.bfloat16, jnp.bfloat16, fu.BLOCK_BYTES),
         # the probe's own leaf, both forms: what a chip compiles first
         ((3, 16, 160), jnp.float32, None, 32 * 1024),
         ((3, 16, 160), jnp.bfloat16, jnp.bfloat16, 32 * 1024),
