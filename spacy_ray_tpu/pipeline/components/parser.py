@@ -19,6 +19,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ... import native
 from ...registry import registry
 from ...models.core import Context, Params
 from ...models.parser import decode_parser, decode_parser_beam
@@ -40,7 +41,14 @@ class ParserComponent(Component):
         # CLI train summary; the reference's spaCy stack handles these docs
         # via pseudo-projective lifting, nonproj.pyx — silent drops capped
         # LAS with no diagnostic, VERDICT r1 #5)
-        self.oracle_stats = {"docs": 0, "projectivized": 0, "skipped": 0}
+        # "native" / "python": the documents whose targets each form of
+        # transition.gold_oracle worked out (a memo hit counts as neither)
+        self.oracle_stats = {
+            "docs": 0, "projectivized": 0, "skipped": 0, "native": 0, "python": 0,
+        }
+        # the library is built and loaded here, while the pipeline is set
+        # up, not by the first batch of a timed window
+        native.load()
         # make_targets may run concurrently on collation-pool workers
         # ([training] collate_workers): counter merges must be atomic
         import threading
@@ -87,7 +95,8 @@ class ParserComponent(Component):
         step_mask = np.zeros((B, S), dtype=bool)
         # per-call counters, merged under the lock at the end: this method
         # runs concurrently on collation-pool worker threads
-        batch_stats = {"docs": 0, "projectivized": 0, "skipped": 0}
+        batch_stats = dict.fromkeys(self.oracle_stats, 0)
+        oracle_ran = "python" if native.load() is None else "native"
         labels_sig = tuple(self.labels)
         for i, eg in enumerate(examples):
             ref = eg.reference
@@ -100,26 +109,41 @@ class ParserComponent(Component):
             memo_key = (labels_sig, hash((tuple(ref.heads), tuple(ref.deps))))
             cached = getattr(eg, "_oracle_cache", None)
             if cached is not None and cached[0] == memo_key:
-                out, lifted = cached[1]
+                kept, lifted = cached[1]
+                out = kept
+                if isinstance(kept, np.ndarray):
+                    out = (kept, *T.replay(kept, len(ref), len(self.labels)))
             else:
                 res = nonproj.projectivize(ref.heads, ref.deps)
                 if res is None:  # malformed tree (cycle / bad head index)
                     out, lifted = None, 0
                 else:
                     proj_heads, deco_deps, lifted = res
-                    # a decorated combo outside the label-sample window falls
-                    # back to its undecorated base label (still supervises
-                    # the arc; the decoration just isn't recoverable) rather
-                    # than training against an arbitrary id
-                    ids = [
-                        label_ids.get(
-                            d, label_ids.get(nonproj.decompose_label(d)[0], 0)
-                        )
-                        for d in deco_deps
-                    ]
+                    ids = [label_ids.get(d) for d in deco_deps]
+                    if None in ids:
+                        # a decorated combo outside the label-sample window
+                        # falls back to its undecorated base label (still
+                        # supervises the arc; the decoration just isn't
+                        # recoverable) rather than training against an
+                        # arbitrary id
+                        ids = [
+                            label_ids.get(nonproj.decompose_label(d)[0], 0)
+                            if label_id is None else label_id
+                            for label_id, d in zip(ids, deco_deps)
+                        ]
                     out = T.gold_oracle(proj_heads, ids, len(self.labels))
+                    batch_stats[oracle_ran] += 1
+                # of the native oracle's answer a memo keeps the actions
+                # (1.3 kB a document) and replays the rows they determine
+                # (44 kB) on a hit, natively: a corpus's worth of rows is
+                # gigabytes that the first epoch has to find, which costs
+                # more than working them out again. The Python's answer is
+                # kept whole: its replay would be the Python state machine
+                kept = out
+                if out is not None and oracle_ran == "native":
+                    kept = out[0].astype(np.int32)
                 try:
-                    eg._oracle_cache = (memo_key, (out, lifted))
+                    eg._oracle_cache = (memo_key, (kept, lifted))
                 except AttributeError:
                     pass
             batch_stats["docs"] += 1
@@ -153,6 +177,14 @@ class ParserComponent(Component):
             "valid": valid,
             "step_mask": step_mask,
         }
+
+    def oracle_report(self) -> Dict[str, Any]:
+        """Which form of the oracle this process runs, and how many
+        documents' targets each has worked out so far (the loop's
+        ``resolved["parser_oracle"]``, docs/OBSERVABILITY.md)."""
+        with self._stats_lock:
+            counts = {key: self.oracle_stats[key] for key in ("native", "python")}
+        return {"path": T.oracle_path(), **counts}
 
     # ------------------------------------------------------------------
     def loss(self, params: Params, inputs: Any, targets: Dict[str, Any], ctx: Context):

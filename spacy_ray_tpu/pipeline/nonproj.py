@@ -39,7 +39,7 @@ def is_decorated(label: str) -> bool:
 
 def _valid_heads(heads: Sequence[int]) -> bool:
     n = len(heads)
-    return all(0 <= h < n for h in heads)
+    return n == 0 or (min(heads) >= 0 and max(heads) < n)
 
 
 def _is_nonproj_arc(d: int, heads: Sequence[int]) -> bool:
@@ -57,14 +57,24 @@ def _is_nonproj_arc(d: int, heads: Sequence[int]) -> bool:
 
 
 def _smallest_nonproj_arc(heads: Sequence[int]) -> Optional[int]:
-    best, best_size = None, None
+    """The first dependent, in sentence order, among the shortest arcs that
+    ``_is_nonproj_arc`` names. That test written out in the loop (this runs
+    for every document collated, two or three times for a lifted one): an
+    arc between neighbours has nothing inside it, and one no shorter than
+    the best so far cannot replace it."""
+    best, best_size = None, len(heads) + 1
     for d, h in enumerate(heads):
         if h == d:
             continue
-        if _is_nonproj_arc(d, heads):
-            size = abs(h - d)
-            if best is None or size < best_size:
+        lo, hi = (h, d) if h < d else (d, h)
+        size = hi - lo
+        if size < 2 or size >= best_size:
+            continue
+        for k in range(lo + 1, hi):
+            hk = heads[k]
+            if hk == k or hk < lo or hk > hi:
                 best, best_size = d, size
+                break
     return best
 
 

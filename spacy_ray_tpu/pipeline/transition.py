@@ -31,6 +31,8 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import native
+
 N_FEATURES = 12
 
 SHIFT = 0
@@ -190,8 +192,75 @@ def gold_oracle(
 
     ``heads[i] == i`` marks the root token (attached to virtual ROOT via the
     final REDUCE escape).
+
+    Runs natively (native/oracle.cpp) where the library loaded, and as
+    ``gold_oracle_python`` — the statement of the semantics, to which the
+    tests hold the native code element for element — where it did not.
     """
+    lib = native.load()
+    if lib is not None:
+        out = native.arc_eager_oracle(lib, heads, label_ids, n_labels)
+        if out is not native.DECLINED:
+            return out
+    return gold_oracle_python(heads, label_ids, n_labels)
+
+
+def oracle_path() -> str:
+    """Which of the two ``gold_oracle`` runs in this process, for the
+    reports: "native", or "python (<why the library is missing>)"."""
+    if native.load() is not None:
+        return "native"
+    return f"python (native library missing: {native.why_missing()})"
+
+
+def replay(
+    actions: Sequence[int], n_words: int, n_labels: int
+) -> Tuple[np.ndarray, np.ndarray]:
+    """The other two arrays of ``gold_oracle``'s answer from its first: the
+    state features [S, N_FEATURES] and valid rows [S, n_actions] before each
+    of a document's ``actions``. They are a function of the actions alone, so
+    a memo keeps the actions (a small integer a step) and not the 40 kB a
+    document of the rows. Native where the library loaded."""
+    lib = native.load()
+    out = (
+        replay_python(actions, n_words, n_labels) if lib is None
+        else native.arc_eager_replay(lib, actions, n_words, n_labels)
+    )
+    if out is None:
+        raise ValueError(
+            f"{len(actions)} actions are not a run of the arc-eager machine "
+            f"over {n_words} words and {n_labels} labels"
+        )
+    return out
+
+
+def replay_python(
+    actions: Sequence[int], n_words: int, n_labels: int
+) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+    """``replay`` on ``ParseState``: the reference, and the fallback. None
+    where the actions are not a whole run of the machine."""
+    state = ParseState(n_words)
+    feats: List[np.ndarray] = []
+    valids: List[np.ndarray] = []
+    for action in actions:
+        valids.append(state.valid_mask(n_labels))
+        if state.is_terminal() or not 0 <= action < valids[-1].size or not valids[-1][action]:
+            return None
+        feats.append(state.features())
+        state.apply(int(action))
+    if not feats or not state.is_terminal():
+        return None
+    return np.stack(feats).astype(np.int64), np.stack(valids)
+
+
+def gold_oracle_python(
+    heads: Sequence[int], label_ids: Sequence[int], n_labels: int
+) -> Optional[Tuple[np.ndarray, np.ndarray, np.ndarray]]:
+    """``gold_oracle`` on the Python state machine above (``ParseState``,
+    ``_oracle_action``): the reference, and the fallback."""
     n = len(heads)
+    if n == 0:
+        return None  # no step to stack
     gold_heads = [(-1 if heads[i] == i else heads[i]) for i in range(n)]
     if not is_projective(heads):
         return None

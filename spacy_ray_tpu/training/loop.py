@@ -1684,6 +1684,16 @@ def train(
     if in_place is not None:
         # which leaves the kernel updated where they lay, from their shapes
         result.resolved["fused_update_in_place"] = in_place
+    oracles = [
+        comp.oracle_report() for comp in nlp.components.values()
+        if hasattr(comp, "oracle_report")
+    ]
+    if oracles:
+        # native, or python and why, with the documents each worked out
+        result.resolved["parser_oracle"] = {
+            "path": oracles[0]["path"],
+            **{key: sum(o[key] for o in oracles) for key in ("native", "python")},
+        }
     if pending_metrics:
         drain_metrics()  # the steps since the last evaluation
     if counter_totals:

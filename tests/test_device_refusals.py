@@ -347,6 +347,10 @@ def test_peak_flops_matches_the_exact_device_kind(monkeypatch, kind, peak):
     assert kind in why
 
 
+NATIVE_SOURCES = tuple(
+    REPO / "spacy_ray_tpu" / "native" / name for name in ("murmur.cpp", "oracle.cpp"))
+
+
 def test_failed_native_build_is_reported_and_leaves_no_half_written_file(
     tmp_path, monkeypatch, caplog
 ):
@@ -354,16 +358,81 @@ def test_failed_native_build_is_reported_and_leaves_no_half_written_file(
 
     (tmp_path / "bad.cpp").write_text("this is not C++\n")
     monkeypatch.setattr(native, "_HERE", tmp_path)
-    monkeypatch.setattr(native, "_SRC", tmp_path / "bad.cpp")
+    monkeypatch.setattr(native, "_SOURCES", (tmp_path / "bad.cpp",))
     monkeypatch.setattr(native, "_SO", tmp_path / "libsrt_native.so")
+    monkeypatch.setattr(native, "_WHY_MISSING", "")
     with caplog.at_level(logging.WARNING, logger="spacy_ray_tpu.native"):
         assert native._build() is False
     assert "did not build" in caplog.text and "error" in caplog.text  # g++'s words
+    assert native.why_missing() == "g++ failed: CalledProcessError"
     assert sorted(p.name for p in tmp_path.iterdir()) == ["bad.cpp"]
     # and a good build lands by rename, whole
-    monkeypatch.setattr(native, "_SRC", REPO / "spacy_ray_tpu" / "native" / "murmur.cpp")
+    monkeypatch.setattr(native, "_SOURCES", NATIVE_SOURCES)
     assert native._build() is True
     assert sorted(p.name for p in tmp_path.iterdir()) == ["bad.cpp", "libsrt_native.so"]
+
+
+@pytest.fixture
+def native_in(tmp_path, monkeypatch):
+    """The loader over a copy of the two sources in ``tmp_path``, not yet
+    tried, with its builds counted; the process's own library comes back
+    when the test ends."""
+    from spacy_ray_tpu import native
+
+    sources = tuple(tmp_path / src.name for src in NATIVE_SOURCES)
+    for src, copy in zip(NATIVE_SOURCES, sources):
+        copy.write_bytes(src.read_bytes())
+    monkeypatch.setattr(native, "_HERE", tmp_path)
+    monkeypatch.setattr(native, "_SOURCES", sources)
+    monkeypatch.setattr(native, "_SO", tmp_path / "libsrt_native.so")
+    monkeypatch.setattr(native, "_LIB", None)
+    monkeypatch.setattr(native, "_TRIED", False)
+    monkeypatch.setattr(native, "_WHY_MISSING", "")
+    builds = []
+    real_build = native._build
+    monkeypatch.setattr(native, "_build", lambda: builds.append(1) or real_build())
+    return native, builds
+
+
+@pytest.mark.parametrize("newer", ["murmur.cpp", "oracle.cpp"])
+def test_a_library_older_than_either_source_is_rebuilt(native_in, newer):
+    native, builds = native_in
+    assert native._build() is True
+    built = native._SO.stat().st_mtime
+    for src in native._SOURCES:
+        os.utime(src, (built - 10, built - 10))
+    assert not native._stale()
+    os.utime(native._HERE / newer, (built + 10, built + 10))
+    assert native._stale()
+    inode = native._SO.stat().st_ino
+    del builds[:]
+    assert native.load() is not None and builds == [1]
+    assert native._SO.stat().st_ino != inode  # a new file, renamed onto the path
+    assert native.load() is not None and builds == [1]  # and not again
+
+
+def test_a_library_without_the_oracles_symbol_is_rebuilt_once_and_then_used(native_in):
+    """An ignored .so of an older tree, copied with the tree and so newer
+    than both sources: neither an AttributeError nor a silent fallback."""
+    from spacy_ray_tpu.pipeline import transition
+
+    native, builds = native_in
+    subprocess.run(
+        ["g++", "-O3", "-shared", "-fPIC", "-o", str(native._SO), str(native._SOURCES[0])],
+        check=True, capture_output=True, timeout=120,
+    )
+    newest = max(src.stat().st_mtime for src in native._SOURCES)
+    assert native._SO.stat().st_mtime >= newest and native._stale()
+    lib = native.load()
+    assert builds == [1] and lib is not None and hasattr(lib, "arc_eager_gold_oracle")
+    assert transition.oracle_path() == "native"
+    got = transition.gold_oracle([1, 1, 1], [0, 0, 1], 2)
+    want = transition.gold_oracle_python([1, 1, 1], [0, 0, 1], 2)
+    assert all((g == w).all() for g, w in zip(got, want))
+    from spacy_ray_tpu.ops.hashing import hash_string_u64
+
+    assert native.hash_strings_u64(["norm=the"])[0] == hash_string_u64("norm=the")
+    assert native.load() is lib and builds == [1]
 
 
 # ----------------------------------------------------------------------

@@ -24,6 +24,35 @@ NONPROJ_HEADS = [1, 1, 3, 1, 1, 3]
 NONPROJ_DEPS = ["nsubj", "ROOT", "det", "obj", "advmod", "relcl"]
 
 
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_smallest_nonproj_arc_is_the_first_shortest_arc_the_arc_test_names(seed):
+    """The scan that ``projectivize`` and ``is_projective`` run has the arc
+    test written into its loop, and skips arcs that cannot win: it names
+    the arc that the test applied arc by arc names, on any heads in range."""
+    import random
+
+    def arc_by_arc(heads):
+        best, best_size = None, None
+        for d, h in enumerate(heads):
+            if h != d and nonproj._is_nonproj_arc(d, heads):
+                if best is None or abs(h - d) < best_size:
+                    best, best_size = d, abs(h - d)
+        return best
+
+    rng = random.Random(seed)
+    found = 0
+    for _ in range(4000):
+        n = rng.randint(1, 30)
+        heads = [rng.randrange(n) for _ in range(n)]
+        if rng.random() < 0.5:  # mostly short arcs, as a treebank has them
+            heads = [min(n - 1, max(0, i + rng.choice([-2, -1, -1, 0, 1, 1, 3])))
+                     for i in range(n)]
+        want = arc_by_arc(heads)
+        assert nonproj._smallest_nonproj_arc(heads) == want, heads
+        found += want is not None
+    assert 400 < found < 3900
+
+
 def test_projectivize_round_trip():
     assert not nonproj.is_projective(NONPROJ_HEADS)
     res = nonproj.projectivize(NONPROJ_HEADS, NONPROJ_DEPS)
