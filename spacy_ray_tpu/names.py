@@ -66,6 +66,9 @@ SCOPE_MAMBA = "mamba"  # a Mamba-2 mixer: projections, convolution, gate, norm
 SCOPE_MAMBA_SCAN = "mamba/scan"  # the chunked selective scan alone
 SCOPE_ATTENTION = "attention"  # grouped-key attention: projections, scores, output
 SCOPE_MOE = "moe"  # an expert layer's pre-norm and residual (its parts: SCOPE_MOE_*)
+SCOPE_KDA = "kda"  # a delta-rule mixer: projections, convolutions, gates, norm
+SCOPE_KDA_SCAN = "kda/scan"  # the chunked delta-rule recurrence alone (models/delta_attention.py)
+SCOPE_GATED_ATTENTION = "gated_attention"  # grouped-key attention with an output gate
 
 
 def head_scope(name: str) -> str:
@@ -92,6 +95,9 @@ MOE_BOUNDED_CALLS = "count_moe_bounded_calls"  # of those, the calls whose live 
 # the state-space layers' scan (models/hybrid_ssm.py), from the batch's mask
 SSM_CHUNKS = "count_ssm_chunks"  # (row, chunk) blocks the scan ran: rows x T / chunk x M layers
 SSM_LIVE_CHUNKS = "count_ssm_live_chunks"  # of those, the blocks holding at least one real word
+# the delta-rule layers' recurrence (the same trunk's K layers), likewise
+KDA_CHUNKS = "count_kda_chunks"  # (row, chunk) blocks it ran: rows x T / chunk x K layers
+KDA_LIVE_CHUNKS = "count_kda_live_chunks"  # of those, the blocks holding at least one real word
 
 # ---- pallas kernels -------------------------------------------------------------
 KERNEL_FLASH_FWD = "srt_flash_fwd"

@@ -535,6 +535,80 @@ def test_the_scans_counters_leave_the_device_and_reach_the_report(mixed_dir):
     assert resolved["moe_dispatch"].startswith("sorted, ragged_dot, 4 of 8 held")
 
 
+# the same shape over the other family's two mixers (one published period cut to a
+# softmax layer and one linear layer, each with its expert block), the heads shared
+DELTA_TRUNK = """[components.transformer]
+factory = "transformer"
+
+[components.transformer.model]
+@architectures = "spacy_ray_tpu.HybridSSMTrunk.v1"
+pattern = "GEKE"
+width = 32
+chunk = 8
+n_heads = 4
+n_kv_heads = 2
+head_dim = 8
+heads_held = 2
+kda_heads = 4
+kda_head_dim = 8
+kda_gate_rank = 8
+kda_heads_held = 2
+expert_form = "gated_silu"
+route_bias = false
+expert_ffn = 16
+shared_ffn = 16
+n_experts = 8
+experts_held = 4
+top_k = 2
+vocab_rows = 97
+"""
+DELTA_CFG = re.sub(
+    r"\[components\.tok2vec\]\n.*?embed_size = 256\n", DELTA_TRUNK, SM_CFG, flags=re.S,
+).replace('pipeline = ["tok2vec",', 'pipeline = ["transformer",')
+
+
+@pytest.fixture(scope="module")
+def delta_step(mixed_dir):
+    with pytest.MonkeyPatch.context() as patch:  # one lowering for the six cases
+        return _lowered_step(DELTA_CFG, mixed_dir, patch)
+
+
+@pytest.mark.parametrize("scope", [
+    names.SCOPE_KDA, names.SCOPE_KDA_SCAN, names.SCOPE_GATED_ATTENTION, names.SCOPE_MOE,
+    names.SCOPE_MOE_EXPERTS, names.SCOPE_MOE_SHARED])
+def test_the_delta_rule_and_gated_layers_have_their_scopes_forward_and_backward(delta_step, scope):
+    """``kda`` (projections, convolutions, gates, norm), the chunked
+    recurrence alone under ``kda/scan`` and ``gated_attention`` lie inside
+    ``trunk`` in the lowered step, forward and backward, beside the expert
+    blocks' own."""
+    text = delta_step
+    trunk = re.escape(names.SCOPE_TRUNK)
+    for side in (rf"jvp\({trunk}\)", rf"transpose\(jvp\({trunk}\)\)"):
+        assert re.search(rf'loc\("[^"]*{side}/[^"]*\b{re.escape(scope)}[)/"]', text), (scope, side)
+    assert names.SCOPE_KDA_SCAN.startswith(names.SCOPE_KDA + "/")
+    # no operation of this trunk is named for a layer it does not have
+    assert not re.search(rf'loc\("[^"]*/{names.SCOPE_MAMBA}[)/"]', text)
+
+
+def test_the_delta_rules_counters_leave_the_device_and_reach_the_report(mixed_dir):
+    """``count_kda_chunks`` / ``count_kda_live_chunks`` are device counters
+    like the scan's two: summed over the run, and the trunk's own summary
+    turns them into the ``kda`` block of the ``runtime`` report, beside the
+    share of the heads."""
+    for key in (names.KDA_CHUNKS, names.KDA_LIVE_CHUNKS):
+        assert key.startswith(names.COUNTER_PREFIX)
+    _, result = train(_config(DELTA_CFG, mixed_dir, **{"training.max_steps": 3}),
+                      n_workers=1, stdout_log=False)
+    resolved = result.resolved
+    assert resolved["layer_pattern"] == "GEKE" and resolved["kda_scan"] == "chunked 8, xla"
+    assert resolved["head_share"] == "2 of 4 query, 1 of 2 key, 2 of 4 linear heads, rank 0"
+    kda = resolved["kda"]
+    assert kda["layers"] == 1 and kda["chunk"] == 8 and 0 < kda["live_chunks"] <= kda["chunks"]
+    assert "ssm" not in resolved
+    assert resolved["moe"]["layer_calls"] == 6 and resolved["moe_dropped"] == "0"
+    assert resolved["moe_dispatch"].startswith("sorted, ragged_dot, 4 of 8 held")
+
+
 def _pallas_names(fn, *args):
     found = []
 
