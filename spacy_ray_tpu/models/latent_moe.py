@@ -24,7 +24,7 @@ in that order, and ONE grouped product (``jax.lax.ragged_dot``) runs over the
 experts held: group sizes vary from step to step, shapes do not. No pair is
 ever dropped.
 
-**The live prefix and its bound.** Only the pairs that land on a held expert
+**The live prefix and its bounds.** Only the pairs that land on a held expert
 produce anything, and after the sort they are the first ``n_live`` rows. A
 rank that holds ``experts_held`` of ``n_experts`` is sent ``P * held /
 n_experts`` of the ``P = N x top_k`` pairs by an even router, so the rows are
@@ -34,18 +34,22 @@ are gathered straight from the words (``order[:C] // top_k``), the grouped
 products run on ``[C, .]``, and each word's sum is taken from the ``C`` output
 rows by position, choice by choice (``_rows_in`` / ``_rows_out``: gathers in
 both directions, no array of ``P`` rows forward or backward, where a
-scatter-add of rows would serialise on the chip). The bound is NOT a capacity
-and drops nothing: ``n_live`` is known on the device after the sort, and a
-routing that sends more than ``C`` pairs here takes the full path
-(``lax.cond``, no host round trip), whose buffer has the static size ``P``
-that no routing exceeds; there sorting is a permutation, so dispatch and
-combine are gathers by it and by its inverse (``_permute``). The full path
-stays because the router is free: with its selection bias frozen it has sent
-this rank anything from 1% to 31% of a run's pairs by seed, and single layers
-over half of a batch's (0-15% of a run's layer calls pass the bound: PERF.md
-section 6, PR 28); nothing holds it to twice its share. Where ``C >= P`` (a layer that holds every expert, or a
-batch under ``BOUND_STEP`` pairs) there is one path, the full one, and no
-branch.
+scatter-add of rows would serialise on the chip). Below ``C`` lies a tier of
+``C_q`` rows, a quarter of ``C`` rounded up to ``TIER_STEP`` (``tier_bound``,
+from the shapes alone; none where that is not smaller than ``C``): a frozen
+router sends a rank far less than its even share, and the rows past
+``n_live`` are noughts that the products multiply all the same. The bounds are
+NOT capacities and drop nothing: ``n_live`` is known on the device after the
+sort, and the layer takes the smallest buffer it fits (``lax.switch``, no host
+round trip): ``C_q``, else ``C``, else the full path, whose buffer has the
+static size ``P`` that no routing exceeds; there sorting is a permutation, so
+dispatch and combine are gathers by it and by its inverse (``_permute``). The
+full path stays because the router is free: with its selection bias frozen it
+has sent this rank anything from 1% to 31% of a run's pairs by seed, and
+single layers over half of a batch's (0-15% of a run's layer calls pass the
+bound: PERF.md section 6); nothing holds it to twice its share. Where
+``C >= P`` (a layer that holds every expert, or a batch under ``BOUND_STEP``
+pairs) there is one path, the full one, and no branch.
 
 Float32 where it decides something: the residual stream, every RMSNorm, the
 router (``h W_r`` at precision ``highest``, sigmoid, top-k, weights), the
@@ -90,6 +94,7 @@ EMBED_SEED = hash_string_u64("latent-moe-embed-NORM") & 0x7FFFFFFF
 COUNTER_KEYS = (
     names.MOE_ASSIGNMENTS, names.MOE_ASSIGNMENTS_HELD, names.MOE_COMPUTED,
     names.MOE_MAX_LOAD, names.MOE_LAYER_CALLS, names.MOE_BOUNDED_CALLS,
+    names.MOE_BUFFER_ROWS, names.MOE_TIER_CALLS,
 )
 N_COUNTERS = len(COUNTER_KEYS)
 
@@ -207,14 +212,36 @@ def route(p, h: jnp.ndarray, s) -> Tuple[jnp.ndarray, jnp.ndarray]:
 # compiler gives the grouped product); a batch whose doubled share is under one
 # step has no smaller buffer to gain and takes the full path alone
 BOUND_STEP = 512
+# the tier under it moves in steps of two 128-row MXU tiles, the row tile the
+# TPU compiler gives ``ragged_dot`` up to 768 rows (512 from 1,024)
+TIER_STEP = 256
 
 
 def live_bound(n_pairs: int, s) -> int:
     """Rows of the bounded path's buffer for ``n_pairs`` (word, choice)
     pairs: twice this rank's even share, rounded up to ``BOUND_STEP``. At
-    ``n_pairs`` or over it there is no bounded path."""
+    ``n_pairs`` or over it there is no bounded path. Under it lies the
+    quarter tier, ``tier_bound`` of it: the two are ``buffer_bounds``."""
     share = -(-2 * n_pairs * s.experts_held // s.n_experts)
     return -(-share // BOUND_STEP) * BOUND_STEP
+
+
+def tier_bound(bound: int) -> int:
+    """Rows of the tier under a bounded buffer of ``bound`` rows: a quarter
+    of it, rounded up to ``TIER_STEP``. At ``bound`` or over it there is no
+    tier."""
+    return -(-bound // (4 * TIER_STEP)) * TIER_STEP
+
+
+def buffer_bounds(n_pairs: int, s) -> Tuple[int, ...]:
+    """The bounded buffers' rows for ``n_pairs`` pairs, largest first:
+    ``(C, C_q)``, ``(C,)`` where the quarter is no smaller, ``()`` where
+    ``C`` is no smaller than ``n_pairs`` (one path)."""
+    bound = live_bound(n_pairs, s)
+    if bound >= n_pairs:
+        return ()
+    tier = tier_bound(bound)
+    return (bound, tier) if tier < bound else (bound,)
 
 
 def _live_rows(n_live: jnp.ndarray, rows: int) -> jnp.ndarray:
@@ -351,31 +378,38 @@ def _bounded_path(bound, form, h16, w, experts, order, inverse, group_sizes):
         return y, came_back.at[pos].get(mode="fill", fill_value=False)
 
 
+def _paths(bounds, form):
+    """The branches by how many of ``bounds`` (largest first) the live pairs
+    fit: the full path, then each bounded buffer, the smallest last."""
+    return [partial(_full_path, form)] + [partial(_bounded_path, b, form) for b in bounds]
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _by_live_size(bound, form, fits, h16, w, experts, order, inverse, group_sizes):
-    """The bounded path where the live pairs fit ``bound`` rows (``fits``,
-    known on the device), the full path where they do not. Its own
-    ``custom_vjp`` so that the backward is a branch too and each branch
-    recomputes its forward inside: differentiating the ``cond`` itself would
-    hand every residual of BOTH branches across it, the untaken one's as
-    noughts, and the bounded branch would write the full one's P-row arrays."""
-    return jax.lax.cond(fits, partial(_bounded_path, bound, form), partial(_full_path, form),
-                        h16, w, experts, order, inverse, group_sizes)
+def _by_live_size(bounds, form, index, h16, w, experts, order, inverse, group_sizes):
+    """The smallest of ``bounds`` that the live pairs fit, the full path where
+    they fit none (``index``: how many of ``bounds`` they fit, known on the
+    device). Its own ``custom_vjp`` so that the backward is a branch too and
+    each branch recomputes its forward inside: differentiating the ``switch``
+    itself would hand every residual of EVERY branch across it, the untaken
+    ones' as noughts, and a bounded branch would write the full one's P-row
+    arrays."""
+    return jax.lax.switch(index, _paths(bounds, form),
+                          h16, w, experts, order, inverse, group_sizes)
 
 
-def _by_live_size_fwd(bound, form, fits, *operands):
-    return _by_live_size(bound, form, fits, *operands), (fits, operands)
+def _by_live_size_fwd(bounds, form, index, *operands):
+    return _by_live_size(bounds, form, index, *operands), (index, operands)
 
 
-def _by_live_size_bwd(bound, form, res, cotangent):
-    fits, (*floats, order, inverse, group_sizes) = res
+def _by_live_size_bwd(bounds, form, res, cotangent):
+    index, (*floats, order, inverse, group_sizes) = res
 
     def pull(path, g, *floats):
         _, vjp = jax.vjp(lambda *f: path(*f, order, inverse, group_sizes)[0], *floats)
         return vjp(g)
 
-    grads = jax.lax.cond(fits, partial(pull, partial(_bounded_path, bound, form)),
-                         partial(pull, partial(_full_path, form)), cotangent[0], *floats)
+    grads = jax.lax.switch(index, [partial(pull, path) for path in _paths(bounds, form)],
+                           cotangent[0], *floats)
     return (None, *grads, None, None, None)
 
 
@@ -402,13 +436,14 @@ def routed_experts(p, h: jnp.ndarray, token_mask, idx, weights, s, cd, form: str
     operands = (h.astype(cd), jnp.where(valid, weights, 0.0),
                 tuple(p[name].astype(cd) for name in EXPERT_LEAVES[form]),
                 order, inverse, group_sizes)
-    bound = live_bound(P, s)
-    if bound >= P:  # no smaller buffer to be had: one path, no branch
-        fits = jnp.bool_(False)
+    bounds = buffer_bounds(P, s)
+    if not bounds:  # no smaller buffer to be had: one path, no branch
+        index = jnp.int32(0)
         y, came_back = _full_path(form, *operands)
     else:
-        fits = jnp.sum(group_sizes) <= bound
-        y, came_back = _by_live_size(bound, form, fits, *operands)
+        n_live = jnp.sum(group_sizes)
+        index = sum((n_live <= b).astype(jnp.int32) for b in bounds)
+        y, came_back = _by_live_size(bounds, form, index, *operands)
     counters = jnp.stack([
         jnp.sum(token_mask, dtype=jnp.int32) * K,
         jnp.sum(valid, dtype=jnp.int32),
@@ -417,7 +452,9 @@ def routed_experts(p, h: jnp.ndarray, token_mask, idx, weights, s, cd, form: str
         jnp.sum(valid & came_back, dtype=jnp.int32),
         jnp.max(group_sizes),
         jnp.int32(1),
-        fits.astype(jnp.int32),
+        (index > 0).astype(jnp.int32),  # either bounded buffer
+        jnp.asarray((P, *bounds), jnp.int32)[index],  # the rows of the buffer taken
+        (index == 2).astype(jnp.int32),  # the tier
     ])
     return y, counters
 
@@ -622,8 +659,10 @@ def LatentMoETrunk(
 def moe_summary(totals: Dict[str, float], experts_held: int, n_experts: int) -> Dict[str, Any]:
     """What a run's summed counters come to, for ``TrainResult.resolved``:
     the ``moe`` block (``dropped`` is the pairs that landed on a held expert
-    and whose output did not come back; the loads are rows a step and layer)
-    and two flat keys a record's expectations can be held to."""
+    and whose output did not come back; the loads are rows a step and layer;
+    ``bounded_calls`` the calls on either bounded buffer, ``tier_calls`` those
+    on the smaller; ``buffer_rows`` the rows of the buffers the calls took,
+    summed) and two flat keys a record's expectations can be held to."""
     calls = max(int(totals.get(names.MOE_LAYER_CALLS, 0)), 1)
     held = int(totals.get(names.MOE_ASSIGNMENTS_HELD, 0))
     moe = {
@@ -634,9 +673,12 @@ def moe_summary(totals: Dict[str, float], experts_held: int, n_experts: int) -> 
         "mean_expert_load": held / experts_held / calls,
         "layer_calls": calls,
         "bounded_calls": int(totals.get(names.MOE_BOUNDED_CALLS, 0)),
+        "tier_calls": int(totals.get(names.MOE_TIER_CALLS, 0)),
+        "buffer_rows": int(totals.get(names.MOE_BUFFER_ROWS, 0)),
     }
     bound = ("one path: every expert held" if experts_held == n_experts else
              f"live rows bounded at 2 x {experts_held}/{n_experts} of the pairs "
-             f"(steps of {BOUND_STEP}), the full path past it")
+             f"(steps of {BOUND_STEP}) and at a quarter of that (steps of {TIER_STEP}), "
+             f"the smallest they fit, the full path past both")
     return {"moe": moe, "moe_dropped": str(moe["dropped"]),
             "moe_dispatch": f"sorted, ragged_dot, {experts_held} of {n_experts} held; {bound}"}

@@ -110,6 +110,8 @@ MOE_COMPUTED = "count_moe_computed"  # of those, the pairs whose expert's output
 MOE_MAX_LOAD = "count_moe_max_load"  # rows of the fullest held expert, summed over layers
 MOE_LAYER_CALLS = "count_moe_layer_calls"  # expert layers run (micro-batches x layers)
 MOE_BOUNDED_CALLS = "count_moe_bounded_calls"  # of those, the calls whose live pairs fit the bound
+MOE_BUFFER_ROWS = "count_moe_buffer_rows"  # rows of the buffer each call moved (C_q, C or N x top_k)
+MOE_TIER_CALLS = "count_moe_tier_calls"  # of the bounded calls, those on the quarter tier C_q
 # the state-space layers' scan (models/hybrid_ssm.py), from the batch's mask
 SSM_CHUNKS = "count_ssm_chunks"  # (row, chunk) blocks the scan ran: rows x T / chunk x M layers
 SSM_LIVE_CHUNKS = "count_ssm_live_chunks"  # of those, the blocks holding at least one real word

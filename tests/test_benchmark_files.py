@@ -1,5 +1,5 @@
 """The benchmark's own tests under the tier-1 suite, every ``test_*.py`` of
-``benchmark/tests`` (fifteen files, two of them through ``test_benchmark_files_solar_open2.py``): every cell of ``BENCHMARK.json`` finds the files
+``benchmark/tests`` (sixteen files, two of them through ``test_benchmark_files_solar_open2.py``): every cell of ``BENCHMARK.json`` finds the files
 ``run.py`` will look for by name; the ``kanana2_a3b`` configuration's
 operation count, readers and sizes hold (no JAX); the comparison that decides
 ``correct`` in that cell fails on each planted fault and on the control (CPU,

@@ -347,6 +347,47 @@ def test_e_an_expert_no_unit_of_which_fires_answers_noughts_and_is_not_a_dropped
     assert int(masked[1]) == int(masked[2]) == (B * T // 2) * s.top_k
 
 
+def test_e_relu2_experts_on_the_quarter_tier_agree_with_the_full_path(monkeypatch):
+    """The routed dispatch's smallest buffer through this trunk: 8 x 64 words
+    x top 3 = 1,536 pairs, a bound of 1,024 rows and a tier of 256, the held
+    experts' selection bias lowered until each layer's live pairs fit the
+    tier. Output, counters and every gradient leaf against the one-path
+    program (the bound patched to every pair)."""
+    s = replace(TINY, pattern="EE")
+    rng = np.random.default_rng(6)
+    ids = jnp.asarray(rng.integers(0, s.vocab_rows, (8, 64)))
+    mask = jnp.asarray(np.arange(64)[None] < rng.integers(30, 65, (8, 1)))
+    p = init_params(jax.random.PRNGKey(3), s)
+    for name in ("layer_0", "layer_1"):
+        p[name] = dict(p[name], router_b=p[name]["router_b"].at[
+            s.held_from:s.held_from + s.experts_held].add(-0.05))
+    cot = jnp.asarray(rng.standard_normal((8, 64, s.width)), jnp.float32) * mask[..., None]
+
+    def run():
+        def loss(p):
+            X, moe, _, choices = trunk_forward(p, ids, mask, s, remat=True)
+            return jnp.sum(X * cot), (X, moe, choices)
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(p)
+
+    assert latent_moe.buffer_bounds(8 * 64 * 3, s) == (1024, 256)
+    (_, (X, moe, choices)), grads = run()
+    monkeypatch.setattr(latent_moe, "live_bound", lambda n_pairs, s: n_pairs)
+    (_, (X_full, moe_full, _)), grads_full = run()
+    lo, hi = held(s)
+    chosen = np.asarray(choices)[:, np.asarray(mask)]
+    live = [int(((c >= lo) & (c < hi)).sum()) for c in chosen]
+    assert all(0 < n <= 256 for n in live), live
+    assert [int(c) for c in moe[5:]] == [2, 2 * 256, 2]  # bounded, rows, tier
+    assert [int(c) for c in moe_full[5:]] == [0, 2 * 8 * 64 * 3, 0]
+    np.testing.assert_array_equal(np.asarray(moe[:5]), np.asarray(moe_full[:5]))
+    assert int(moe[1]) == int(moe[2])  # nothing dropped
+    for got, want in ((X, X_full), (grads, grads_full)):
+        for (path, w), g in zip(jax.tree_util.tree_leaves_with_path(want),
+                                jax.tree_util.tree_leaves(got)):
+            err = float(jnp.max(jnp.abs(g - w))) / max(float(jnp.max(jnp.abs(w))), 1e-30)
+            assert err <= 2e-5, (jax.tree_util.keystr(path), err)
+
+
 # ---- grouped keys through the attention entry point -----------------------------------------------
 
 

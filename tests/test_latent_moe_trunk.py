@@ -97,6 +97,18 @@ def every_word_on_held_experts(params, s=TINY):
     return forced
 
 
+def fewer_words_on_held_experts(params, s=TINY, by: float = 0.02):
+    """The same parameters with the held experts' selection bias lowered by
+    ``by``: on the wide batch every expert layer's live pairs (165 and 71)
+    then fit the quarter tier of 256 rows."""
+    lowered = dict(params)
+    for i in range(s.first_dense, s.depth):
+        layer = params[f"layer_{i}"]
+        lowered[f"layer_{i}"] = dict(
+            layer, router_b=layer["router_b"].at[s.held_from:s.held_from + s.experts_held].add(-by))
+    return lowered
+
+
 def system(p, ids, mask, positions, s=TINY, **kw):
     return jax.jit(lambda p: trunk_forward(p, ids, mask, positions, s, **kw))(p)
 
@@ -206,10 +218,12 @@ def test_d_no_pair_is_dropped_when_every_word_lands_on_the_same_experts(
     lo, _ = held(TINY)
     X, counters, choices = system(forced, *batch)
     words, layers = int(np.asarray(batch[1]).sum()), TINY.depth - TINY.first_dense
-    assignments, on_held, computed, max_load, calls, bounded_calls = (int(c) for c in counters)
+    (assignments, on_held, computed, max_load, calls, bounded_calls, buffer_rows,
+     tier_calls) = (int(c) for c in counters)
     assert assignments == on_held == computed == words * TINY.top_k * layers
     assert max_load == words * layers and calls == layers  # one expert holds every word
-    assert bounded_calls == bounded
+    assert bounded_calls == bounded == tier_calls
+    assert buffer_rows == batch[0].size * TINY.top_k * layers  # every pair's row, each layer
     assert set(np.asarray(choices)[:, np.asarray(batch[1])].reshape(-1)) == set(
         range(lo, lo + TINY.top_k))
     want = reference(forced, *batch, np.asarray(choices))
@@ -220,6 +234,7 @@ def test_d_no_pair_is_dropped_when_every_word_lands_on_the_same_experts(
     assert summary["moe"]["dropped"] == 0 and summary["moe_dropped"] == "0"
     assert summary["moe"]["max_expert_load"] == words
     assert summary["moe"]["bounded_calls"] == bounded and summary["moe"]["layer_calls"] == layers
+    assert summary["moe"]["tier_calls"] == 0 and summary["moe"]["buffer_rows"] == buffer_rows
     # the counter reads what the product gave back: one of the three experts
     # returning nothing is a third of the pairs dropped, in every layer
     for i in range(TINY.first_dense, TINY.depth):
@@ -248,6 +263,24 @@ def test_e_a_padded_position_reaches_no_expert_and_moves_no_output(
 
 
 # ---- (j) the bounded live prefix and its fall-back (ISSUE 28) ---------------------------------
+
+
+# the wide batch's two bounded buffers, taken before any test patches the bound
+WIDE_BOUNDS = latent_moe.buffer_bounds(WIDE_PAIRS, TINY)
+
+
+def rows_taken(live: int) -> int:
+    """The rows of the buffer a call with ``live`` pairs on the wide batch
+    takes: the quarter tier, the bound, or every pair."""
+    bound, tier = WIDE_BOUNDS
+    return tier if live <= tier else bound if live <= bound else WIDE_PAIRS
+
+
+def live_per_layer(choices, mask):
+    """Each expert layer's pairs on a held expert, from the experts chosen."""
+    chosen = np.asarray(choices)[:, np.asarray(mask)]
+    return [int(((c >= TINY.held_from) & (c < TINY.held_from + TINY.experts_held)).sum())
+            for c in chosen]
 
 
 def full_path_only(monkeypatch):
@@ -283,36 +316,45 @@ def branches_of_the_conds(fn, *args):
 
 
 @pytest.mark.parametrize("scan,remat", [(True, True), (True, False), (False, True), (False, False)])
-@pytest.mark.parametrize("routing", ["under_the_bound", "over_the_bound"])
+@pytest.mark.parametrize("routing", ["under_the_bound", "over_the_bound", "under_the_tier"])
 def test_j_the_bounded_path_agrees_with_the_full_path(
         params, wide_batch, monkeypatch, routing, scan, remat):
-    """The same parameters and batch down the program with the bound and down
+    """The same parameters and batch down the program with the bounds and down
     the parent's (one path, every pair moved): outputs, counters and every
     gradient leaf. Over the bound both take the full path, one of them through
-    the branch."""
+    the branch; under the tier every layer takes the quarter buffer, and under
+    the bound the seeded routing sends one layer through each bounded buffer."""
     ids, mask, positions = wide_batch
-    p = params if routing == "under_the_bound" else every_word_on_held_experts(params)
+    p = {"under_the_bound": params, "over_the_bound": every_word_on_held_experts(params),
+         "under_the_tier": fewer_words_on_held_experts(params)}[routing]
     cot = jnp.asarray(np.random.default_rng(8).standard_normal((WIDE_B, WIDE_T, TINY.width)),
                       jnp.float32) * mask[..., None]
 
     def run():
         def loss(p):
-            X, counters, _ = trunk_forward(
+            X, counters, choices = trunk_forward(
                 p, ids, mask, positions, TINY, scan_layers=scan, remat=remat)
-            return jnp.sum(X * cot), (X, counters)
+            return jnp.sum(X * cot), (X, counters, choices)
         return jax.jit(jax.value_and_grad(loss, has_aux=True))(p)
 
     bound = latent_moe.live_bound(WIDE_PAIRS, TINY)
-    (_, (X, counters)), grads = run()
+    tier = latent_moe.tier_bound(bound)
+    (_, (X, counters, choices)), grads = run()
     full_path_only(monkeypatch)
-    (_, (X_full, counters_full)), grads_full = run()
+    (_, (X_full, counters_full, _)), grads_full = run()
     layers = TINY.depth - TINY.first_dense
-    live = int(counters[1]) // layers
-    if routing == "under_the_bound":
-        assert 0 < live < bound and int(counters[5]) == layers
+    live = live_per_layer(choices, mask)
+    assert sum(live) == int(counters[1])
+    bounded_calls, buffer_rows, tier_calls = (int(c) for c in counters[5:])
+    if routing == "under_the_tier":
+        assert all(0 < n <= tier for n in live) and tier_calls == bounded_calls == layers
+    elif routing == "under_the_bound":
+        assert min(live) <= tier < max(live) < bound
+        assert bounded_calls == layers and tier_calls == 1
     else:
-        assert live > bound and int(counters[5]) == 0
-    assert int(counters_full[5]) == 0
+        assert min(live) > bound and bounded_calls == tier_calls == 0
+    assert buffer_rows == sum(map(rows_taken, live))
+    assert [int(c) for c in counters_full[5:]] == [0, WIDE_PAIRS * layers, 0]
     np.testing.assert_array_equal(np.asarray(counters[:5]), np.asarray(counters_full[:5]))
     assert int(counters[1]) == int(counters[2])  # nothing dropped on either path
     assert_leaves_close(X, X_full, "output")
@@ -333,11 +375,19 @@ def one_expert_layer(n_live: int):
     return layer, h, jnp.ones((n,), bool), jnp.asarray(idx.reshape(n, TINY.top_k), jnp.int32), weights
 
 
-@pytest.mark.parametrize("past", [0, 1])
-def test_j_at_the_bound_and_one_pair_past_it(monkeypatch, past):
+@pytest.mark.parametrize("buffer,past", [
+    pytest.param("bound", 0, id="0"), pytest.param("bound", 1, id="1"),
+    pytest.param("tier", 0, id="tier-0"), pytest.param("tier", 1, id="tier-1")])
+def test_j_at_the_bound_and_one_pair_past_it(monkeypatch, buffer, past):
+    """``n_live`` at each bounded buffer's rows and one pair past them: the
+    quarter tier (256), the bound (1,024); one pair past the tier is the
+    bound's, one past the bound the full path's."""
     bound = latent_moe.live_bound(WIDE_PAIRS, TINY)
-    assert bound == 1024 < WIDE_PAIRS
-    layer, h, real, idx, weights = one_expert_layer(bound + past)
+    tier = latent_moe.tier_bound(bound)
+    assert latent_moe.buffer_bounds(WIDE_PAIRS, TINY) == (bound, tier) == (1024, 256)
+    assert bound < WIDE_PAIRS
+    n_live = (bound if buffer == "bound" else tier) + past
+    layer, h, real, idx, weights = one_expert_layer(n_live)
     cot = jnp.asarray(np.random.default_rng(10).standard_normal(h.shape), jnp.float32)
 
     def run():
@@ -349,8 +399,11 @@ def test_j_at_the_bound_and_one_pair_past_it(monkeypatch, past):
     (_, (y, counters)), grads = run()
     full_path_only(monkeypatch)
     (_, (y_full, counters_full)), grads_full = run()
-    assert int(counters[1]) == int(counters[2]) == bound + past
-    assert int(counters[5]) == 1 - past and int(counters_full[5]) == 0
+    assert int(counters[1]) == int(counters[2]) == n_live
+    bounded_calls, buffer_rows, tier_calls = (int(c) for c in counters[5:])
+    assert buffer_rows == rows_taken(n_live)
+    assert bounded_calls == int(n_live <= bound) and tier_calls == int(n_live <= tier)
+    assert [int(c) for c in counters_full[5:]] == [0, WIDE_PAIRS, 0]
     np.testing.assert_array_equal(np.asarray(counters[:5]), np.asarray(counters_full[:5]))
     assert float(jnp.max(jnp.abs(grads_full[2]))) > 0  # the weights' gradient is there to compare
     assert_leaves_close(y, y_full, "output")
@@ -358,23 +411,28 @@ def test_j_at_the_bound_and_one_pair_past_it(monkeypatch, past):
 
 
 def test_j_the_bounded_branch_makes_no_array_of_every_pair(params, wide_batch):
-    """Forward and backward: inside the branch taken under the bound no array
-    has a row for each of the N x top_k pairs (index vectors have: they stay)."""
+    """Forward and backward: inside the branches taken under the bound no
+    array has a row for each of the N x top_k pairs, and inside the quarter
+    tier's none has a row for each of the bound's (index vectors have: they
+    stay)."""
     ids, mask, positions = wide_batch
+    bound = latent_moe.live_bound(WIDE_PAIRS, TINY)
 
     def loss(p):
         return jnp.sum(trunk_forward(p, ids, mask, positions, TINY, remat=True)[0])
 
-    def a_row_for_every_pair(branch):
+    def a_row_for_each(rows, branch):
         return {v.aval.shape for sub in sub_jaxprs(branch.jaxpr) for eqn in sub.eqns
                 for v in eqn.outvars
-                if len(v.aval.shape) >= 2 and v.aval.shape[0] == WIDE_PAIRS}
+                if len(v.aval.shape) >= 2 and v.aval.shape[0] == rows}
 
     conds = branches_of_the_conds(jax.grad(loss), params)
     assert len(conds) >= 2  # the forward's and the backward's
-    for full, bounded in conds:
-        assert a_row_for_every_pair(bounded) == set()
-        assert a_row_for_every_pair(full)  # the same walk does find the full path's
+    for full, bounded, tier in conds:
+        assert a_row_for_each(WIDE_PAIRS, bounded) == a_row_for_each(WIDE_PAIRS, tier) == set()
+        assert a_row_for_each(bound, tier) == set()
+        assert a_row_for_each(WIDE_PAIRS, full)  # the same walk does find the full path's
+        assert a_row_for_each(bound, bounded)  # and the bound's
 
 
 def test_j_a_layer_that_holds_every_expert_has_no_branch(params, wide_batch):
@@ -394,16 +452,17 @@ def test_j_a_layer_that_holds_every_expert_has_no_branch(params, wide_batch):
 
 
 def test_j_a_live_prefix_cut_one_row_short_reads_as_a_dropped_pair(params, wide_batch, monkeypatch):
-    """A fault planted in the bounded path: its live rows end one before the
-    last pair that landed here. ``moe_dropped`` reads the rows that came back."""
+    """A fault planted in both bounded buffers: their live rows end one before
+    the last pair that landed here. ``moe_dropped`` reads the rows that came
+    back (the seeded routing sends one layer through each buffer)."""
     real = latent_moe._live_rows
-    bound = latent_moe.live_bound(WIDE_PAIRS, TINY)
+    bounds = latent_moe.buffer_bounds(WIDE_PAIRS, TINY)
     monkeypatch.setattr(
         latent_moe, "_live_rows",
-        lambda n_live, rows: real(n_live - 1 if rows == bound else n_live, rows))
+        lambda n_live, rows: real(n_live - 1 if rows in bounds else n_live, rows))
     _, counters, _ = system(params, *wide_batch)
     layers = TINY.depth - TINY.first_dense
-    assert int(counters[5]) == layers  # the bounded path did run
+    assert int(counters[5]) == layers and int(counters[7]) == 1  # both bounded paths did run
     summary = latent_moe.moe_summary(
         dict(zip(latent_moe.COUNTER_KEYS, map(int, counters))),
         experts_held=TINY.experts_held, n_experts=TINY.n_experts)

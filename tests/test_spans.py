@@ -584,10 +584,10 @@ def test_a_pattern_trunk_splits_the_step_by_kind_of_layer(mixed_dir, monkeypatch
 
 def test_the_scans_counters_leave_the_device_and_reach_the_report(mixed_dir):
     """``count_ssm_chunks`` / ``count_ssm_live_chunks`` are device counters
-    like the routed trunk's six: summed over the run, and the trunk's own
+    like the routed trunk's eight: summed over the run, and the trunk's own
     summary turns them into the ``ssm`` block of the ``runtime`` report."""
     for key in (names.SSM_CHUNKS, names.SSM_LIVE_CHUNKS, names.MOE_ASSIGNMENTS,
-                names.MOE_BOUNDED_CALLS):
+                names.MOE_BOUNDED_CALLS, names.MOE_BUFFER_ROWS, names.MOE_TIER_CALLS):
         assert key.startswith(names.COUNTER_PREFIX)
     _, result = train(_config(HYBRID_CFG, mixed_dir, **{"training.max_steps": 3}),
                       n_workers=1, stdout_log=False)
@@ -597,6 +597,8 @@ def test_the_scans_counters_leave_the_device_and_reach_the_report(mixed_dir):
     assert ssm["layers"] == 2 and ssm["chunk"] == 8
     assert 0 < ssm["live_chunks"] <= ssm["chunks"] and ssm["chunks"] % 2 == 0
     assert resolved["moe"]["layer_calls"] == 3 and resolved["moe_dropped"] == "0"
+    moe = resolved["moe"]  # a call takes the tier, the bound or every pair: at least one row
+    assert moe["tier_calls"] <= moe["bounded_calls"] <= 3 <= moe["buffer_rows"]
     assert resolved["moe_dispatch"].startswith("sorted, ragged_dot, 4 of 8 held")
 
 
