@@ -1,7 +1,8 @@
 """A trunk whose layers follow a published STRING: state-space (Mamba-2)
 mixers, delta-rule linear attention, grouped-key attention with or without an
-output gate and routed experts in one stack, the third architecture the trunk
-slot takes (``spacy_ray_tpu.HybridSSMTrunk.v1``).
+output gate, latent attention, a dense FFN and routed experts in one stack,
+the third architecture the trunk slot takes
+(``spacy_ray_tpu.HybridSSMTrunk.v1``).
 
 The Nemotron-H family (arXiv:2504.03624; ``model_type`` ``nemotron_h``)
 publishes its depth as ``hybrid_override_pattern``, one character a layer:
@@ -66,6 +67,20 @@ of a running sum, as ``dt_W`` does in ``M``). ``G``: the ``*`` layer with
 ``sigmoid(h W_gate)`` multiplied into the heads' outputs before the output
 projection.
 
+**``L`` and ``D``: a third family's two** (``model_type`` ``kimi_linear``,
+Kimi Linear, arXiv:2510.26692; ``benchmark/reference/kimi_linear_48b_a3b.py``
+is their plain form). ``L``: the multi-head latent attention of
+``latent_moe`` (``latent_attention``: the query projected whole, a latent of
+``kv_rank`` channels and one shared key of ``qk_rope`` channels, the latent
+normed and expanded to each head's key and value) with NO positional term
+(``mla_use_nope``: the shared key's channels stay in the product unrotated).
+``D``: a dense gated FFN, ``W_d (silu(h W_g) * h W_u)``, where a family's
+leading layers have no experts (``first_k_dense_replace``). That family
+publishes its depth as two lists of layer indices and a count of leading
+dense layers, not as a string: ``pattern_from_layer_lists`` maps them to one,
+a mixer and an FFN a published layer (``KDKEKELE...``), and a configuration
+calls it. ``L`` holds every head: its share of the heads is not written.
+
 **The chip's share of the HEADS.** ``*``, ``G`` and ``K`` are told how many
 heads they hold (``heads_held`` query heads with their key/value heads,
 ``kda_heads_held`` linear heads) and of which rank (``head_rank``): the leaves
@@ -93,19 +108,24 @@ from ..types import Padded, TokenBatch
 from . import latent_moe
 from .core import Context, Model, normal_init
 from .delta_attention import chunked_delta_rule, l2norm
-from .latent_moe import rms_norm, word_rows
+from .latent_moe import latent_attention, rms_norm, word_rows
 from .shadow import _resolve_compute_dtype, register_trunk_leaves
 
 # a layer's kind is the name of its device scope: a trace splits by it
 MAMBA, ATTENTION, MOE = names.SCOPE_MAMBA, names.SCOPE_ATTENTION, names.SCOPE_MOE
 KDA, GATED_ATTENTION = names.SCOPE_KDA, names.SCOPE_GATED_ATTENTION
-# the characters: nemotron_h's own three, and K / G for the two mixers of solar_open2
-KINDS = {"M": MAMBA, "*": ATTENTION, "E": MOE, "K": KDA, "G": GATED_ATTENTION}
+LATENT, DENSE = names.SCOPE_ATTN, names.SCOPE_DENSE_FFN
+# the characters: nemotron_h's own three, K / G for the two mixers of solar_open2,
+# L / D for kimi_linear's latent attention and leading dense FFN
+KINDS = {"M": MAMBA, "*": ATTENTION, "E": MOE, "K": KDA, "G": GATED_ATTENTION,
+         "L": LATENT, "D": DENSE}
 
 MATMUL_LEAVES = (
     "in_W", "out_W",  # M: the two projections
-    "q_W", "k_W", "v_W", "ao_W",  # *, G, K: the held heads' projections
+    "q_W", "k_W", "v_W", "ao_W",  # *, G, K: the held heads' projections (q_W, ao_W: L too)
     "gate_W",  # G: the output gate
+    "kva_W", "kvb_W",  # L: the latent and the shared key, the latent to keys and values
+    "g_W", "u_W", "d_W",  # D: the dense gated FFN
     "eg_W", "eu_W", "ed_W",  # E: the experts held, stacked [held, ., .] (eg_W: gated_silu only)
     "sg_W", "su_W", "sd_W",  # E: the shared expert
 )
@@ -117,12 +137,13 @@ register_trunk_leaves(
     # operations
     f32=("norm_g", "gate_norm_g", "conv_W", "conv_b", "A_log", "D", "dt_bias", "dt_W",
          "router_W", "router_b",
-         "fa_W", "fb_W", "ga_W", "gb_W", "beta_W", "o_norm_g"),
+         "fa_W", "fb_W", "ga_W", "gb_W", "beta_W", "o_norm_g", "rmskv_g"),
     int8_unsupported=MATMUL_LEAVES,
 )
 
 COUNTER_KEYS = (names.SSM_CHUNKS, names.SSM_LIVE_CHUNKS)
 KDA_COUNTER_KEYS = (names.KDA_CHUNKS, names.KDA_LIVE_CHUNKS)
+MLA_COUNTER_KEYS = (names.MLA_SCORES, names.MLA_LIVE_SCORES)
 
 
 @dataclass(frozen=True)
@@ -167,6 +188,14 @@ class Shape:
     heads_held: int = 0
     kda_heads_held: int = 0
     head_rank: int = 0
+    # L: a head's key of qk_nope + qk_rope channels (the second part from the
+    # one key all heads share), its value of v_head, the latent of kv_rank;
+    # D: the dense FFN's width. L reads n_heads and holds every head
+    qk_nope: int = 0
+    qk_rope: int = 0
+    v_head: int = 0
+    kv_rank: int = 0
+    dense_ffn: int = 0
 
     @property
     def depth(self) -> int:
@@ -203,10 +232,34 @@ def parse_pattern(pattern: str) -> Tuple[str, ...]:
     unknown = sorted(set(pattern) - set(KINDS))
     if not pattern or unknown:
         raise ValueError(
-            f"a layer pattern is a string of {sorted(KINDS)} (attention, routed experts, gated "
-            f"attention, delta-rule mixer, Mamba-2 mixer), one character a layer; got {pattern!r}"
+            f"a layer pattern is a string of {sorted(KINDS)} (attention, dense FFN, routed experts, "
+            f"gated attention, delta-rule mixer, latent attention, Mamba-2 mixer), one character a "
+            f"layer; got {pattern!r}"
             + (f" with {unknown}" if unknown else ""))
     return tuple(KINDS[c] for c in pattern)
+
+
+@registry.misc("spacy_ray_tpu.pattern_from_layer_lists.v1")
+def pattern_from_layer_lists(
+    kda_layers, full_attn_layers, first_k_dense_replace: int, num_hidden_layers: int,
+    keep_layers: int = 0,
+) -> str:
+    """The pattern of a depth published as lists (``kimi_linear``'s
+    ``linear_attn_config``): layer ``i`` (1-indexed) is a delta-rule mixer
+    ``K`` where ``kda_layers`` names it, latent attention ``L`` where
+    ``full_attn_layers`` does, then its FFN, dense ``D`` for the first
+    ``first_k_dense_replace`` layers and routed ``E`` after them: two
+    characters a published layer. ``keep_layers`` (nought: all) keeps the
+    first that many layers. Every layer must be named by exactly one list."""
+    kda, full = set(kda_layers), set(full_attn_layers)
+    every = set(range(1, num_hidden_layers + 1))
+    if kda & full or kda | full != every or not 0 <= keep_layers <= num_hidden_layers:
+        raise ValueError(
+            f"kda_layers and full_attn_layers must name each of layers 1..{num_hidden_layers} once "
+            f"(both: {sorted(kda & full)}, neither: {sorted(every - kda - full)}, past the end: "
+            f"{sorted((kda | full) - every)}), and keep_layers {keep_layers} be at most that")
+    return "".join(("K" if i in kda else "L") + ("D" if i <= first_k_dense_replace else "E")
+                   for i in range(1, (keep_layers or num_hidden_layers) + 1))
 
 
 def _f32_dot(a, w) -> jnp.ndarray:
@@ -378,6 +431,28 @@ def kda_mixer(p, h: jnp.ndarray, s: Shape, cd) -> jnp.ndarray:
         return (o.reshape(B, T, H * K).astype(cd) @ p["ao_W"].astype(cd)).astype(jnp.float32)
 
 
+# ---- L and D: latent attention without positions, a dense FFN ----------------------------
+
+
+def latent_mixer(p, h: jnp.ndarray, mask, s: Shape, cd) -> jnp.ndarray:
+    """h [B, T, D] float32 (normed) -> [B, T, D] float32: ``latent_moe``'s
+    latent attention, every head, no positional term."""
+    with jax.named_scope(LATENT):
+        return latent_attention(p, h, mask, None, s, cd, rotary=False)
+
+
+def score_counters(mask: jnp.ndarray) -> jnp.ndarray:
+    """int32 [2]: the (query, key) score entries ONE HEAD of one L layer's
+    attention computes over this batch (``xla_attention``: every pair of
+    positions of every row) and those of them whose query and key are both
+    real words, the key at or before the query. Heads and layers multiply in
+    ``summary``, in Python's integers: B x T x T alone stays far inside int32
+    where the product with them would not (64 x 1024 x 1024 x 32 heads)."""
+    B, T = mask.shape
+    seen = jnp.cumsum(mask.astype(jnp.int32), axis=-1)  # real keys at or before each position
+    return jnp.stack([jnp.int32(B * T * T), jnp.sum(jnp.where(mask, seen, 0), dtype=jnp.int32)])
+
+
 # ---- E: routed experts --------------------------------------------------------------
 
 
@@ -420,6 +495,11 @@ def apply_layer(p, x, mask, *, kind: str, s: Shape, cd):
         y = kda_mixer(p, h, s, cd)
     elif kind in (ATTENTION, GATED_ATTENTION):
         y = grouped_attention(p, h, mask, s, cd, kind)
+    elif kind == LATENT:
+        y = latent_mixer(p, h, mask, s, cd)
+    elif kind == DENSE:
+        with jax.named_scope(DENSE):
+            y = latent_moe._gated(h.astype(cd), p["g_W"], p["u_W"], p["d_W"], cd)
     else:
         y, counted, chosen = expert_mixer(p, h, mask, s, cd)
     with jax.named_scope(kind):
@@ -520,6 +600,21 @@ def init_params(rng, s: Shape, std: float = 0.02):
             )
             if kind == GATED_ATTENTION:
                 layer["gate_W"] = normal_init(r[4], (s.width, s.q_heads_here * s.head_dim), std)
+        elif kind == LATENT:
+            H = s.n_heads
+            layer.update(
+                q_W=normal_init(r[0], (s.width, H * (s.qk_nope + s.qk_rope)), std),
+                kva_W=normal_init(r[1], (s.width, s.kv_rank + s.qk_rope), std),
+                kvb_W=normal_init(r[2], (s.kv_rank, H * (s.qk_nope + s.v_head)), std),
+                ao_W=normal_init(r[3], (H * s.v_head, s.width), std),
+                rmskv_g=jnp.ones((s.kv_rank,)),
+            )
+        elif kind == DENSE:
+            layer.update(
+                g_W=normal_init(r[0], (s.width, s.dense_ffn), std),
+                u_W=normal_init(r[1], (s.width, s.dense_ffn), std),
+                d_W=normal_init(r[2], (s.dense_ffn, s.width), std),
+            )
         else:
             layer.update(
                 router_W=normal_init(r[0], (s.width, s.n_experts), std),
@@ -594,14 +689,21 @@ def HybridSSMTrunk(
     heads_held: int = 0,
     kda_heads_held: int = 0,
     head_rank: int = 0,
+    qk_nope: int = 0,
+    qk_rope: int = 0,
+    v_head: int = 0,
+    kv_rank: int = 0,
+    dense_ffn: int = 0,
 ) -> Model:
     """tok2vec-compatible trunk (module docstring). ``pattern`` is the layer
     string as published, or a cut of it; ``experts_held`` of the ``n_experts``
     live here, those of rank ``expert_rank``; ``heads_held`` of the
     ``n_heads`` query heads (whole groups of them, with their key/value heads)
     and ``kda_heads_held`` of the ``kda_heads`` linear heads, those of rank
-    ``head_rank`` (nought: all heads, the one rank). ``remat`` keeps only each
-    layer's input for the backward pass."""
+    ``head_rank`` (nought: all heads, the one rank); an ``L`` layer has
+    ``n_heads`` heads of ``qk_nope + qk_rope`` / ``v_head`` over a latent of
+    ``kv_rank``, all held; a ``D`` layer is ``dense_ffn`` wide. ``remat``
+    keeps only each layer's input for the backward pass."""
     kinds = parse_pattern(pattern)
     if n_experts % experts_held or not 0 <= expert_rank < n_experts // experts_held:
         raise ValueError(
@@ -614,6 +716,10 @@ def HybridSSMTrunk(
         raise ValueError(f"expert_form is one of {sorted(latent_moe.EXPERT_LEAVES)}, got {expert_form!r}")
     if KDA in kinds and not (kda_heads and kda_head_dim and kda_gate_rank):
         raise ValueError("a pattern with K needs kda_heads, kda_head_dim and kda_gate_rank")
+    if LATENT in kinds and not (qk_nope and v_head and kv_rank) or DENSE in kinds and not dense_ffn:
+        raise ValueError("a pattern with L needs qk_nope, v_head and kv_rank, one with D dense_ffn")
+    if LATENT in kinds and heads_held:
+        raise ValueError(f"heads_held {heads_held}: an L layer holds every one of its heads")
     # how many ranks share each kind of head that the pattern has (all heads held:
     # one rank); one head_rank names a chip among all of them, so they have to agree
     head_ranks = set()
@@ -638,7 +744,8 @@ def HybridSSMTrunk(
         vocab_rows=vocab_rows, expert_form=expert_form, route_bias=route_bias,
         kda_heads=kda_heads, kda_head_dim=kda_head_dim, kda_gate_rank=kda_gate_rank,
         kda_neg_eigval=kda_neg_eigval, heads_held=heads_held, kda_heads_held=kda_heads_held,
-        head_rank=head_rank)
+        head_rank=head_rank, qk_nope=qk_nope, qk_rope=qk_rope, v_head=v_head, kv_rank=kv_rank,
+        dense_ffn=dense_ffn)
 
     def rows(batch: TokenBatch) -> jnp.ndarray:
         return word_rows(batch.attr_keys, vocab_rows)
@@ -657,6 +764,8 @@ def HybridSSMTrunk(
         if KDA in kinds:
             ctx.add_metrics(dict(zip(
                 KDA_COUNTER_KEYS, chunk_counters(batch.mask, s.chunk) * kinds.count(KDA))))
+        if LATENT in kinds:
+            ctx.add_metrics(dict(zip(MLA_COUNTER_KEYS, score_counters(batch.mask))))
         return Padded(X=X, mask=batch.mask)
 
     return Model(
@@ -689,14 +798,19 @@ def _chunk_block(totals: Dict[str, float], keys, chunk: int, layers: int) -> Dic
 def summary(totals: Dict[str, float], s: Shape) -> Dict[str, Any]:
     """What a run's summed counters come to, for ``TrainResult.resolved``:
     the pattern the stack was built from, how each chunked recurrence ran and
-    its block (``ssm`` for M, ``kda`` for K), the share of the heads where
-    the chip holds one and, where the pattern has expert layers,
-    ``latent_moe``'s ``moe`` block and flat keys."""
+    its block (``ssm`` for M, ``kda`` for K), the L layers' score entries
+    (``mla``: computed, and live of them), the share of the heads where the
+    chip holds one and, where the pattern has expert layers, ``latent_moe``'s
+    ``moe`` block and flat keys."""
     kinds = parse_pattern(s.pattern)
     out: Dict[str, Any] = {"layer_pattern": s.pattern}
     if KDA in kinds:
         out["kda_scan"] = f"chunked {s.chunk}, xla"
         out["kda"] = _chunk_block(totals, KDA_COUNTER_KEYS, s.chunk, kinds.count(KDA))
+    if LATENT in kinds:
+        layers = kinds.count(LATENT)
+        scores, live = (int(totals.get(key, 0)) * s.n_heads * layers for key in MLA_COUNTER_KEYS)
+        out["mla"] = {"scores": scores, "live_scores": live, "layers": layers}
     if s.heads_held or s.kda_heads_held:
         parts = []
         if ATTENTION in kinds or GATED_ATTENTION in kinds:

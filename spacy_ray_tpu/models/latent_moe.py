@@ -153,9 +153,14 @@ def _gated(h16: jnp.ndarray, wg, wu, wd, cd) -> jnp.ndarray:
     return ((jax.nn.silu(gate) * up).astype(cd) @ wd.astype(cd)).astype(jnp.float32)
 
 
-def latent_attention(p, h: jnp.ndarray, mask, positions, s: Shape, cd) -> jnp.ndarray:
+def latent_attention(p, h: jnp.ndarray, mask, positions, s, cd, rotary: bool = True) -> jnp.ndarray:
     """h [B, T, D] float32 (normed) -> [B, T, D] float32. Causal, as
-    published; ONE rotary key shared by all heads."""
+    published; ONE rotary key shared by all heads. ``rotary`` False: no
+    positional term (``mla_use_nope``), the ``qk_rope`` channels of the query
+    and of the shared key stay in the product unrotated, the scale is that of
+    the whole head all the same; ``positions`` is then not read. ``s`` gives
+    ``n_heads``, ``qk_nope``, ``qk_rope``, ``v_head``, ``kv_rank``,
+    ``rms_eps`` (and ``rope_theta`` where ``rotary``)."""
     from ..ops.flash_attention import attention
 
     B, T, _ = h.shape
@@ -165,9 +170,12 @@ def latent_attention(p, h: jnp.ndarray, mask, positions, s: Shape, cd) -> jnp.nd
     kva = h16 @ p["kva_W"].astype(cd)
     c = rms_norm(kva[..., : s.kv_rank], p["rmskv_g"], s.rms_eps)
     kv = (c.astype(cd) @ p["kvb_W"].astype(cd)).reshape(B, T, H, s.qk_nope + s.v_head)
-    with jax.named_scope(names.SCOPE_ATTN_ROPE):
-        q_pe = rope(q[..., s.qk_nope:], positions, s.rope_theta).astype(cd)
-        k_pe = rope(kva[..., None, s.kv_rank:], positions, s.rope_theta).astype(cd)
+    if rotary:
+        with jax.named_scope(names.SCOPE_ATTN_ROPE):
+            q_pe = rope(q[..., s.qk_nope:], positions, s.rope_theta).astype(cd)
+            k_pe = rope(kva[..., None, s.kv_rank:], positions, s.rope_theta).astype(cd)
+    else:
+        q_pe, k_pe = q[..., s.qk_nope:], kva[..., None, s.kv_rank:]
     q = jnp.concatenate([q[..., : s.qk_nope], q_pe], axis=-1)
     k = jnp.concatenate(
         [kv[..., : s.qk_nope], jnp.broadcast_to(k_pe, (B, T, H, s.qk_rope))], axis=-1)

@@ -69,9 +69,9 @@ SCOPE_UPDATE = "update"  # optimizer apply (fused or not), shadow refresh, grad 
 SCOPE_GRAD_ACCUM = "grad_accum"  # the scan over micro-batches
 # inside SCOPE_TRUNK, the latent-attention / routed-expert trunk
 # (models/latent_moe.py); "/" separates a part from its whole
-SCOPE_ATTN = "attn"  # latent attention: projections, scores, output
+SCOPE_ATTN = "attn"  # latent attention: projections, scores, output (also the pattern trunk's L layers)
 SCOPE_ATTN_ROPE = "attn/rope"  # rotary positions on q_pe and the shared k_pe
-SCOPE_DENSE_FFN = "dense_ffn"  # the leading dense layers' gated FFN
+SCOPE_DENSE_FFN = "dense_ffn"  # the leading dense layers' gated FFN (also the pattern trunk's D layers)
 SCOPE_MOE_ROUTER = "moe/router"  # float32 scores, top-k, weights
 SCOPE_MOE_DISPATCH = "moe/dispatch"  # sort the (word, choice) pairs by held expert, gather rows
 SCOPE_MOE_EXPERTS = "moe/experts"  # the grouped products over the experts held
@@ -118,6 +118,11 @@ SSM_LIVE_CHUNKS = "count_ssm_live_chunks"  # of those, the blocks holding at lea
 # the delta-rule layers' recurrence (the same trunk's K layers), likewise
 KDA_CHUNKS = "count_kda_chunks"  # (row, chunk) blocks it ran: rows x T / chunk x K layers
 KDA_LIVE_CHUNKS = "count_kda_live_chunks"  # of those, the blocks holding at least one real word
+# the latent-attention layers of the same trunk (its L layers), likewise: what the
+# attention path computes, as (query, key) score entries of one head of one layer
+# (the summary multiplies by heads and layers in Python's integers)
+MLA_SCORES = "count_mla_scores"  # entries ONE head of one L layer computes: rows x T x T on the XLA path
+MLA_LIVE_SCORES = "count_mla_live_scores"  # of those, query and key real words, key at or before query
 
 # ---- pallas kernels -------------------------------------------------------------
 KERNEL_FLASH_FWD = "srt_flash_fwd"

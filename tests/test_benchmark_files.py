@@ -37,7 +37,18 @@ PR 34's for the reason PR 34 restated PR 28's:
 configuration to being the LAST entries of their lists, which the next PR to
 append one cannot keep. What the lines meant is kept: the entries as PR 34
 wrote them, at the places PR 34 gave them (``test_solar_open2_250b.py`` holds
-the new last entries, and will want the same from the PR after it)."""
+the new last entries, and will want the same from the PR after it).
+
+The configuration ``kimi_linear_48b_a3b`` (``model_config``) appends a fourth
+routed cell and a sixth one-chip cell, ``kimi_linear_48b_a3b_train``, to the
+same lists and to ``moe_buffer_fill_share``'s, whose case
+(``test_moe_buffer_fill_share.py``) pins the list to the three routed cells of
+its day and is restated below the same way: the entry as it was written, at
+its place, with the new cell's name appended. Its own two files of
+``benchmark/tests`` are imported by two files of their own
+(``test_kimi_linear_benchmark.py`` and ``test_kimi_linear_benchmark_faults.py``),
+each a worker's, named so that they are handed out midway through the suite
+and not beside this file and the solar one, whose rehearsals run first."""
 
 import importlib.util
 import json
@@ -63,9 +74,10 @@ def _cases(path: Path):
 # (``test_benchmark_files_solar_open2.py``): one file is one worker's, and this
 # one was already the suite's longest (PR 36)
 ELSEWHERE = ("test_solar_open2_250b.py", "test_solar_open2_250b_faults.py")
+KIMI_LINEAR = ("test_kimi_linear_48b_a3b.py", "test_kimi_linear_48b_a3b_faults.py")
 
 for _path in sorted(BENCH_TESTS.glob("test_*.py")):
-    if _path.name in ELSEWHERE:
+    if _path.name in ELSEWHERE + KIMI_LINEAR:
         continue
     _found = _cases(_path)
     assert not _found.keys() & globals().keys(), (_path.name, sorted(_found.keys() & globals().keys()))
@@ -73,7 +85,8 @@ for _path in sorted(BENCH_TESTS.glob("test_*.py")):
 
 
 BENCHMARK = json.loads((BENCH_TESTS.parent.parent / "BENCHMARK.json").read_text())
-ROUTED_CELLS = ["kanana2_a3b_train", "nemotron3_nano_a3b_train", "solar_open2_250b_train"]
+ROUTED_CELLS = ["kanana2_a3b_train", "nemotron3_nano_a3b_train", "solar_open2_250b_train",
+                "kimi_linear_48b_a3b_train"]
 
 
 def test_the_metric_is_declared_for_the_routed_cell_alone():
@@ -85,6 +98,18 @@ def test_the_metric_is_declared_for_the_routed_cell_alone():
     assert [m["name"] for m in per_layer].count("moe_bounded_share") == 1
     for name in ("moe_held_share", "moe_load_imbalance"):  # PR 27's two, read from the same block
         assert next(m for m in per_layer if m["name"] == name)["workloads"] == ROUTED_CELLS
+
+
+def test_the_fill_share_is_declared_for_the_routed_cells():
+    """``test_moe_buffer_fill_share.py``'s case with the fourth routed cell appended."""
+    entry = next(m for m in BENCHMARK["per_layer"] if m["name"] == "moe_buffer_fill_share")
+    assert entry == {"name": "moe_buffer_fill_share", "unit": "%", "better": "higher",
+                     "source": "program_counter", "layer": "models", "moves": "train_wps_chip",
+                     "workloads": ROUTED_CELLS}
+    assert [m["name"] for m in BENCHMARK["per_layer"]].count("moe_buffer_fill_share") == 1
+    # appended after the set-up metrics; held to its place, not to being last
+    assert BENCHMARK["per_layer"][30] == entry
+    assert {c["name"] for c in BENCHMARK["workloads"]} >= set(ROUTED_CELLS)
 
 
 def test_the_metric_is_declared_for_the_one_chip_cells():

@@ -14,7 +14,11 @@ which stops holding once the four metrics of set-up (``setup_program_s``,
 after it, and the files of ``benchmark/`` are edited only by a change to the
 benchmark itself. What the line meant is kept: the entry as it was written,
 at the place it was given (``per_layer[25]``), everything else as it
-stands."""
+stands. Since the configuration ``kimi_linear_48b_a3b`` appended its cell
+after this one, to ``kda_live_chunk_share``'s list among others, the case
+holds this cell, its configuration and that entry to the places they were
+given, the new cell's name appended to the entry's list, and no longer to
+being the last or to the number of cells."""
 
 from test_benchmark_files import BENCH_TESTS, BENCHMARK, ELSEWHERE, _cases
 
@@ -40,14 +44,13 @@ def test_the_delta_rule_cell_and_what_it_reports():
     entry = BENCHMARK["per_layer"][25]
     assert entry == {"name": "kda_live_chunk_share", "unit": "%", "better": "higher",
                      "source": "program_counter", "layer": "models", "moves": "train_wps_chip",
-                     "workloads": [CELL]}
-    assert BENCHMARK["workloads"][-1]["name"] == CELL and BENCHMARK["configs"][-1]["name"] == cell["config"]
-    assert len(BENCHMARK["workloads"]) == 6 and len(BENCHMARK["configs"]) == 5
+                     "workloads": [CELL, "kimi_linear_48b_a3b_train"]}
+    assert BENCHMARK["workloads"][5]["name"] == CELL and BENCHMARK["configs"][4]["name"] == cell["config"]
     assert [c["name"] for c in BENCHMARK["workloads"] if c["chips"] == 4] == ["trf_train_dp4"]
     expected = cell["config_file"]["expect_runtime"]["1"]
     assert expected["layer_pattern"] == "GEKEKEKE" and expected["moe_dropped"] == "0"
     assert expected["moe_dispatch"] == "sorted, ragged_dot, 8 of 320 held"
     assert expected["head_share"] == "8 of 64 query, 1 of 8 key, 8 of 64 linear heads, rank 0"
     assert expected["flash_attention"][0].endswith("8 query heads on 1 key heads")
-    assert 1 <= len(BENCHMARK["workloads"][-1]["why"]) <= 200 and "\n" not in BENCHMARK["workloads"][-1]["why"]
-    assert 1 <= len(BENCHMARK["configs"][-1]["why"]) <= 200
+    assert 1 <= len(BENCHMARK["workloads"][5]["why"]) <= 200 and "\n" not in BENCHMARK["workloads"][5]["why"]
+    assert 1 <= len(BENCHMARK["configs"][4]["why"]) <= 200
